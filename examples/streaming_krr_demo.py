@@ -1,7 +1,7 @@
 """Streaming kernel-ridge demo (rows AND features streamed).
 
 The single-chip machinery behind the 10M×4096 north-star
-(BASELINE.md): ``streaming_kernel_ridge`` never holds X or a feature
+(``BASELINE.json``): ``streaming_kernel_ridge`` never holds X or a feature
 chunk — ``block_fn`` yields row panels (here sliced from a small
 in-memory X; at scale, counter-generated or IO-backed), features are
 regenerated per panel, and only one panel plus the (n, t) residual is

@@ -27,9 +27,10 @@ def add_perf_args(p) -> None:
     """The shared compilation/plan observability flags (every driver)."""
     p.add_argument(
         "--xla-cache-dir", default=None,
-        help="persistent XLA compilation cache directory: executables "
-             "compiled in one run (plans included) are reloaded in the "
-             "next instead of recompiled",
+        help="persistent XLA compilation cache directory (default: "
+             ".jax_cache in the checkout; JAX_COMPILATION_CACHE_DIR, "
+             "when set, wins over both): executables compiled in one "
+             "run (plans included) are reloaded in the next",
     )
     p.add_argument(
         "--plan-stats", action="store_true",
@@ -39,27 +40,13 @@ def add_perf_args(p) -> None:
 
 
 def setup_perf(args) -> None:
-    """Apply --xla-cache-dir before the first compilation.  Best-effort:
-    jax versions without the persistent-cache knobs just warn."""
-    if not getattr(args, "xla_cache_dir", None):
-        return
-    import warnings
+    """Place the persistent compilation cache before the first
+    compilation (``utils.compile_cache.place``: the environment's
+    ``JAX_COMPILATION_CACHE_DIR`` wins, then ``--xla-cache-dir``, then
+    the fixed directory inside the checkout)."""
+    from ..utils import compile_cache
 
-    import jax
-
-    try:
-        jax.config.update("jax_compilation_cache_dir", args.xla_cache_dir)
-        # Cache everything: plans are often millisecond-compile but
-        # high-count, exactly what the default thresholds would skip.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # noqa: BLE001 — cache is an optimization
-        warnings.warn(
-            f"--xla-cache-dir not applied ({e!r}); continuing without "
-            "the persistent compilation cache",
-            RuntimeWarning,
-            stacklevel=2,
-        )
+    compile_cache.place(getattr(args, "xla_cache_dir", None))
 
 
 def print_perf_report(args) -> None:
@@ -104,8 +91,8 @@ def add_policy_args(p) -> None:
 
 def setup_policy(args) -> None:
     """Apply the policy flags and warm-start the process.  Call AFTER
-    :func:`setup_perf` so an explicit ``--xla-cache-dir`` wins over the
-    profile store's remembered one."""
+    :func:`setup_perf`, which places the compilation cache the replayed
+    plans compile into."""
     import os
 
     from .. import policy

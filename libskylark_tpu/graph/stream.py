@@ -271,8 +271,7 @@ def chained_adjacency_sketch(
             "chained_adjacency_sketch needs a SimpleGraph"
         )
     if mesh is None:
-        # 1-D mesh over all visible devices, built directly so the route
-        # works regardless of the installed JAX's AxisType support.
+        # 1-D mesh over all visible devices.
         import jax
         from jax.sharding import Mesh
 

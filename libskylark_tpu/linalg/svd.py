@@ -373,7 +373,7 @@ def streaming_approximate_svd(
     params = params or SVDParams(num_iterations=1)
     if mesh is not None and any(
         t == jax.sharding.AxisType.Explicit
-        for t in getattr(mesh, "axis_types", ())
+        for t in mesh.axis_types
     ):
         raise ValueError(
             "streaming_approximate_svd needs an Auto-axes mesh "

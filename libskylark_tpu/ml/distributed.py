@@ -76,6 +76,7 @@ from ..streaming.elastic import (
     host_dir,
 )
 from ..streaming.repartition import resolve_resume
+from ..utils import compile_cache
 from ..utils.exceptions import InvalidParameters
 from ..utils.timer import PhaseTimer
 from .admm import ADMMParams
@@ -570,6 +571,7 @@ class DistributedBlockADMMTrainer:
         ``registry``/``register_as`` land the trained model in a serve
         registry at end of training.
         """
+        compile_cache.place()
         p, ep = self.params, self.elastic
         kind = KIND
         ni_p = validate_train_partition(partition, p.data_partitions)

@@ -37,21 +37,34 @@ __all__ = [
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src", "skylark_native.cpp")
-_SO = os.path.join(_DIR, "libskylark_native.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """The built library's path, keyed on a hash of the source: only a
+    build from the source that sits beside it ever loads.  (A
+    ``libskylark_native.so`` built elsewhere from other source may lie
+    git-ignored in a copied tree; an mtime comparison would load it.)"""
+    import hashlib
+
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libskylark_native-{digest}.so")
+
+
+def _build(so: str) -> bool:
+    if os.path.exists(so):
+        return True
+    tmp = f"{so}.{os.getpid()}.tmp.so"
+    cmd = [
+        "g++", "-O3", "-shared", "-fPIC", "-fopenmp", "-std=c++17",
+        _SRC, "-o", tmp,
+    ]
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-            return True
-        cmd = [
-            "g++", "-O3", "-shared", "-fPIC", "-fopenmp", "-std=c++17",
-            _SRC, "-o", _SO,
-        ]
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
+        os.replace(tmp, so)  # atomic: concurrent builders never see half
         return True
     except (OSError, subprocess.SubprocessError):
         return False
@@ -64,21 +77,25 @@ def lib():
         if _tried:
             return _lib
         _tried = True
-        if not _build():
+        try:
+            so = _so_path()
+        except OSError:
+            return None
+        if not _build(so):
             return None
         try:
-            L = ctypes.CDLL(_SO)
+            L = ctypes.CDLL(so)
         except OSError:
-            # Corrupt/stale/incompatible cached .so: rebuild once, then
+            # Corrupt/incompatible cached build: rebuild once, then
             # degrade gracefully.
             try:
-                os.remove(_SO)
+                os.remove(so)
             except OSError:
                 pass
-            if not _build():
+            if not _build(so):
                 return None
             try:
-                L = ctypes.CDLL(_SO)
+                L = ctypes.CDLL(so)
             except OSError:
                 return None
         L.sl_create_context.restype = ctypes.c_void_p

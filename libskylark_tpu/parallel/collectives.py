@@ -266,16 +266,6 @@ def _coerce_float(A):
     return A
 
 
-def _shard_map_fn():
-    # jax < 0.5 keeps shard_map under jax.experimental
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
 def cross_host_psum(
     tree,
     mesh: Mesh | None = None,
@@ -360,7 +350,7 @@ def cross_host_psum(
             (nd,) + x.shape, NamedSharding(mesh, spec), _cb
         )
         summed = jax.jit(
-            _shard_map_fn()(
+            jax.shard_map(
                 lambda a: jax.lax.psum(a, axes),
                 mesh=mesh,
                 in_specs=spec,
@@ -389,7 +379,7 @@ def rowwise_sharded(S, A, mesh: Mesh):
     def local(a):
         return S.apply(a, Dimension.ROWWISE)
 
-    return _shard_map_fn()(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=P(axes, None),
@@ -417,15 +407,15 @@ def batch_sharded_program(local, mesh: Mesh):
     schedule just runs.
     """
     axes = tuple(mesh.axis_names)
-    # check_rep=False: the sketch applies trace counter-stream
+    # check_vma=False: the sketch applies trace counter-stream
     # primitives that carry no replication rule; nothing here relies on
     # replication inference (every spec is explicit).
-    return _shard_map_fn()(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=P(None, axes),
         out_specs=P(None, axes),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -482,7 +472,7 @@ def columnwise_sharded(S: DenseSketch, A, mesh: Mesh, scatter: bool = False):
         return jax.lax.psum(partial_out, axes)
 
     out_spec = P(axes, None) if scatter else P(None, None)
-    return _shard_map_fn()(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=P(axes, None), out_specs=out_spec
     )(A)
 
@@ -593,7 +583,7 @@ def _columnwise_sparse_program(S, m: int, block: int, mesh: Mesh,
         return jax.lax.psum(out, axes)
 
     out_spec = P(axes, None) if scatter else P(None, None)
-    return _shard_map_fn()(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), P(axes, None)),
@@ -696,7 +686,7 @@ def _columnwise_sparse_2d_program(S, rblock: int, cblock: int, mesh: Mesh):
         out = acc.reshape(S.s, cblock)
         return jax.lax.psum(out, ax_r)
 
-    return _shard_map_fn()(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -744,7 +734,7 @@ def _rowwise_sparse_program(S, block: int, mesh: Mesh):
             ).astype(dtype)
         return acc.reshape(block, S.s)
 
-    return _shard_map_fn()(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), P(axes, None)),
@@ -1051,7 +1041,7 @@ def _columnwise_sparse_out_program(S, block: int, out_block: int, cap: int,
             rc.reshape(flat),
         )
 
-    return _shard_map_fn()(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), P(axes, None)),
@@ -1137,7 +1127,7 @@ def _columnwise_sparse_out_2d_program(S, rblock: int, out_rblock: int,
             rc.reshape(flat),
         )
 
-    return _shard_map_fn()(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -1194,7 +1184,7 @@ def _rowwise_sparse_out_program(S, mesh: Mesh):
             jnp.concatenate(cols).reshape(flat),
         )
 
-    return _shard_map_fn()(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), P(axes, None)),

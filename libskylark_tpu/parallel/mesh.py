@@ -118,7 +118,7 @@ def constrain_rows(x, mesh: Mesh):
     s = row_sharding(mesh, np.ndim(x))
     if any(
         t == jax.sharding.AxisType.Explicit
-        for t in getattr(mesh, "axis_types", ())
+        for t in mesh.axis_types
     ):
         return jax.sharding.reshard(x, s)
     return jax.lax.with_sharding_constraint(x, s)

@@ -26,7 +26,7 @@ Entry schema (one per :func:`profile_key`):
 
 plus a store-level ``plans`` list of hot plan-cache keys (sketch JSON +
 abstract input signature — enough to replay the trace at warm start) and
-a ``meta`` block (``xla_cache_dir``, plan-cache compile totals).
+a ``meta`` block (plan-cache compile totals).
 """
 
 from __future__ import annotations

@@ -222,9 +222,7 @@ def flush(name: str | None = None, info: dict | None = None) -> str | None:
     """Persist pending observations (the ``run_summary``-time write).
 
     Called by ``telemetry.run_summary`` before its own enabled gate, so
-    profiles persist even with telemetry off.  Also records the active
-    XLA compilation-cache directory (if one is configured) so
-    :func:`~libskylark_tpu.policy.warm_start` can re-apply it, and the
+    profiles persist even with telemetry off.  Also records the
     plan-cache compile totals for the cold-vs-warm accounting."""
     if not recording_active():
         return None
@@ -232,17 +230,10 @@ def flush(name: str | None = None, info: dict | None = None) -> str | None:
         if _STATE["pending"] == 0:
             return None
         store = _store()
-        try:
-            import jax
-
-            cache_dir = jax.config.jax_compilation_cache_dir
-        except Exception:  # noqa: BLE001 — knob absent on old jax
-            cache_dir = None
         from .. import plans
 
         st = plans.stats()
         store.set_meta(
-            xla_cache_dir=cache_dir,
             plan_compiles=st["compiles"],
             plan_compile_seconds=st["compile_seconds"],
         )

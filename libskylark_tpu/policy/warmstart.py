@@ -3,19 +3,14 @@
 A fresh process pays the full trace + XLA-compile cost for every plan
 its predecessor already measured (``plans.stats()["compile_seconds"]``
 — recorded in the profile store's meta block).  ``warm_start()``
-collapses that cold start twice over:
-
-1. **XLA compilation cache** — re-applies the persisted compilation
-   cache directory (the one recorded at ``run_summary`` time, or the
-   store's own ``xla-cache/`` subdirectory) so XLA reloads executables
-   instead of recompiling them.  An explicitly configured cache dir
-   (``--xla-cache-dir``) always wins — warm start only fills the knob
-   when it is unset.
-2. **Plan replay** — reconstructs the store's hottest (sketch,
-   signature) keys (``SketchTransform.from_json`` + a zeros array of
-   the recorded abstract shape) and pushes them through the live plan
-   entry points, so the process-wide ``PlanCache`` holds the traced
-   executables before the first real request arrives.
+collapses that cold start by **plan replay**: it reconstructs the
+store's hottest (sketch, signature) keys (``SketchTransform.from_json``
++ a zeros array of the recorded abstract shape) and pushes them through
+the live plan entry points, so the process-wide ``PlanCache`` holds the
+traced executables before the first real request arrives.  The replayed
+plans compile through whatever persistent XLA cache the process has
+(``utils.compile_cache.place`` — warm start never moves it; it only
+reports the directory in its summary).
 
 Replays are firewalled per key: a stale record (sketch type renamed,
 shape no longer valid) is skipped and counted, never fatal.
@@ -29,36 +24,6 @@ from . import config
 from .profile import load_entries
 
 __all__ = ["warm_start"]
-
-
-def _apply_xla_cache_dir(meta: dict, directory: str) -> str | None:
-    import os
-    import warnings
-
-    import jax
-
-    try:
-        current = jax.config.jax_compilation_cache_dir
-    except Exception:  # noqa: BLE001 — knob absent on old jax
-        return None
-    if current:
-        return str(current)  # explicit configuration wins
-    cache_dir = meta.get("xla_cache_dir") or os.path.join(
-        directory, "xla-cache"
-    )
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        return cache_dir
-    except Exception as e:  # noqa: BLE001 — cache is an optimization
-        warnings.warn(
-            f"policy warm start could not apply the XLA cache dir ({e!r})",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return None
 
 
 def _replay_one(rec: dict) -> bool:
@@ -108,16 +73,14 @@ def warm_start(
         return summary
     view = load_entries(directory)
     if view is None or not view.get("files"):
-        # No predecessor left a store here: nothing to apply.  Returning
-        # early also keeps the XLA cache knob untouched (filling it from
-        # a store that does not exist would be pure side effect).
+        # No predecessor left a store here: nothing to replay.
         return summary
     t0 = time.perf_counter()
     summary["enabled"] = True
     summary["profile_keys"] = len(view.get("entries", {}))
-    summary["xla_cache_dir"] = _apply_xla_cache_dir(
-        view.get("meta") or {}, directory
-    )
+    import jax
+
+    summary["xla_cache_dir"] = jax.config.jax_compilation_cache_dir
     budget = config.warm_plans() if max_plans is None else max(0, max_plans)
     for rec in (view.get("plans") or [])[:budget]:
         try:

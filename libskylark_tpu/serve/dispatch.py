@@ -57,10 +57,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
-from ..parallel.collectives import _shard_map_fn, batch_sharded_program
+from ..parallel.collectives import batch_sharded_program
 from ..sketch.base import Dimension
-
-_shard_map = _shard_map_fn()
 
 __all__ = [
     "supported",
@@ -236,9 +234,9 @@ def maybe_feature_sharded(model, Xp, true_rows: int, entries=None,
                 blocks.append(Z)
             return jnp.concatenate(blocks, axis=-1)
 
-        return _shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=P(axes, None),
-            out_specs=P(axes, None), check_rep=False,
+            out_specs=P(axes, None), check_vma=False,
         )
 
     return _dispatch_sharded(
@@ -266,9 +264,9 @@ def maybe_kernel_sharded(model, Xp, true_rows: int, entries=None,
         def local(x):
             return model.kernel.gram(x, model.X_train) @ model.A
 
-        return _shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=P(axes, None),
-            out_specs=P(axes, None), check_rep=False,
+            out_specs=P(axes, None), check_vma=False,
         )
 
     return _dispatch_sharded(

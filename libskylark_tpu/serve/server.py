@@ -36,6 +36,7 @@ import numpy as np
 
 from .. import telemetry
 from ..core.context import SketchContext
+from ..utils import compile_cache
 from ..utils.exceptions import (
     DeadlineExceededError,
     InvalidParameters,
@@ -260,6 +261,7 @@ class Server:
     def start(self) -> "Server":
         if self._thread is not None:
             return self
+        compile_cache.place()
         if self.params.warm_start:
             from .. import policy
 

@@ -35,6 +35,9 @@ import enum
 import json
 from typing import Any, Callable, ClassVar
 
+import jax
+import jax.numpy as jnp
+
 from ..core.context import SketchContext
 
 __all__ = [
@@ -234,6 +237,21 @@ class SketchTransform(abc.ABC):
         return acc
 
     # -- loop-invariant operand hoisting ------------------------------------
+
+    def _memoized_operand(self, key: str, build):
+        """``build()`` memoized under ``key`` (sketches are immutable).
+        Mid-trace calls skip the cache both ways: an operand built under
+        a trace holds tracers and must not outlive it, and a cached
+        concrete operand returned into a trace would be baked into the
+        caller's executable as a constant.  Any array op under a trace
+        yields a Tracer, so a scalar probe answers "am I being traced"."""
+        if isinstance(jnp.zeros((), jnp.bool_), jax.core.Tracer):
+            return build()
+        cache = self.__dict__.setdefault("_hoist_cache", {})
+        hit = cache.get(key)
+        if hit is None:
+            hit = cache[key] = build()
+        return hit
 
     def hoistable_operands(self, dtype):
         """Counter-derived arrays the apply realizes that do NOT depend

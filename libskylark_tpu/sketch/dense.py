@@ -142,13 +142,9 @@ class DenseSketch(SketchTransform):
         dtype = jnp.dtype(dtype)
         if not jnp.issubdtype(dtype, jnp.floating):
             dtype = jnp.dtype(jnp.float32)
-        if not jax.core.trace_state_clean():
-            return self.realize(dtype)
-        cache = self.__dict__.setdefault("_hoist_cache", {})
-        hit = cache.get(dtype.name)
-        if hit is None:
-            hit = cache[dtype.name] = self.realize(dtype)
-        return hit
+        return self._memoized_operand(
+            dtype.name, lambda: self.realize(dtype)
+        )
 
     def apply_with_operands(
         self, ops, A, dim: Dimension | str = Dimension.COLUMNWISE
@@ -184,7 +180,12 @@ class DenseSketch(SketchTransform):
                         f"sketch (CWT/SJLT) at this scale"
                     )
                 return self._apply_blocked(A, dim, dtype)
-            omega = self.realize(dtype)
+            # The barrier keeps the counter-stream generator out of the
+            # matmul's operand fusion: fused, the v5e compiler took
+            # 70-90 s per shape on realize + matmul + an elementwise
+            # epilogue (4 s with Omega materialized first, as the eager
+            # apply does anyway).
+            omega = jax.lax.optimization_barrier(self.realize(dtype))
         elif omega.dtype != dtype:
             # Dtype-mismatched hoist: re-realize rather than astype — a
             # value-converted Omega (e.g. bf16-rounded then upcast) would

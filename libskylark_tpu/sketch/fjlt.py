@@ -38,64 +38,12 @@ def _use_pallas() -> bool:
     )
 
 
-_GATHER_COMPILES: bool | None = None
-
-
-def _gather_compiles() -> bool:
-    """One-time compiled self-test of the scaled-row-gather kernel
-    (:func:`pallas_window.self_check_gather`) on the default backend —
-    the ``hash._window_compiles`` probe pattern: scalar-indexed sublane
-    addressing is the piece Mosaic may refuse, the verdict is cached
-    unconditionally (it bakes into callers' jit executables either way),
-    and transient device errors get two bounded retries."""
-    global _GATHER_COMPILES
-    for attempt in range(3):
-        if _GATHER_COMPILES is not None:
-            break
-        import warnings
-
-        try:
-            with jax.ensure_compile_time_eval():
-                err = pallas_window.self_check_gather()
-            # Pure selection + identical multiply: the kernel is bitwise
-            # equal to the XLA gather, so any nonzero error means the
-            # dynamic addressing mis-resolved.
-            _GATHER_COMPILES = err == 0.0
-            if not _GATHER_COMPILES:
-                warnings.warn(
-                    "Pallas gather kernel compiled but miscomputed "
-                    f"(rel err {err:g} vs XLA gather); falling back to "
-                    "the XLA sampled gather for this process",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
-        except Exception as e:  # noqa: BLE001 — any lowering failure → XLA
-            msg = repr(e)
-            transient = any(
-                tok in msg
-                for tok in ("UNAVAILABLE", "DEADLINE", "RESOURCE_EXHAUSTED")
-            )
-            if transient and attempt < 2:
-                import time
-
-                time.sleep(3.0)
-                continue
-            warnings.warn(
-                "Pallas gather kernel probe failed; falling back to the "
-                f"XLA sampled gather for this process: {msg[:300]}",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            _GATHER_COMPILES = False
-    return _GATHER_COMPILES
-
-
 def _gather_mode(nrows: int, s: int, m: int, dtype) -> str:
     """STATIC routing for the sampled-transform epilogue gather — shape,
-    dtype, env, and the one-time probe only, never values (the
-    ``hash._window_mode`` discipline, so planned≡eager holds by
-    construction).  f32 only: the full-source VMEM tile is padded to the
-    f32 (8, 128) grain.  ``SKYLARK_PALLAS_GATHER=1`` forces the kernel,
+    dtype, env and backend only, never values (the ``hash._window_mode``
+    discipline: planned≡eager holds by construction, and on a TPU a
+    chosen route compiles or raises).  f32 only: the full-source VMEM
+    tile is padded to the f32 (8, 128) grain.  ``SKYLARK_PALLAS_GATHER=1`` forces the kernel,
     ``=interpret`` runs it in interpret mode (CPU tests), ``=0`` forces
     XLA."""
     mode = os.environ.get("SKYLARK_PALLAS_GATHER", "")
@@ -111,86 +59,9 @@ def _gather_mode(nrows: int, s: int, m: int, dtype) -> str:
     if (
         jax.default_backend() == "tpu"
         and pallas_window.worthwhile_gather(nrows, s, m)
-        and _gather_compiles()
     ):
         return "kernel"
     return "xla"
-
-
-_SAMPLED_KERNEL_OK: dict = {}
-
-
-def _sampled_kernel_compiles(
-    dtype=jnp.float32, nb: int = 512, s: int = 128, tm: int = 8
-) -> bool:
-    """Compiled self-test of the fused sampled-FJLT kernel at the REAL
-    call's (dtype, NB, S, tile) — Mosaic lowering of the lane gather can
-    vary with vector layout, and the layout depends on the block's
-    sublane count too, so the probe runs at m = the production tile
-    (``_tile_rows(tm, nb) == tm`` for any tile the caller selected).
-    Verdict cached per configuration; transient device errors get two
-    bounded retries — same pattern and rationale as
-    ``hash._kernel_compiles``."""
-    key = (jnp.dtype(dtype).name, nb, s, tm)
-    for attempt in range(3):
-        if key in _SAMPLED_KERNEL_OK:
-            break
-        import warnings
-
-        from . import pallas_fut
-
-        try:
-            with jax.ensure_compile_time_eval():
-                rng = np.random.default_rng(0)
-                x = jnp.asarray(
-                    rng.standard_normal((tm, nb)).astype(np.float32)
-                ).astype(dtype)
-                d = jnp.asarray(
-                    rng.choice([-1.0, 1.0], nb).astype(np.float32)
-                ).astype(dtype)
-                idx = rng.integers(0, nb, s).astype(np.int32)
-                out = pallas_fut.rfut_rowwise_sampled(x, d, nb, idx)
-                ref = pallas_fut.rfut_rowwise(x, d, nb)[:, idx] * jnp.asarray(
-                    np.sqrt(nb / s), dtype
-                )
-                jax.block_until_ready((out, ref))
-                err = float(
-                    jnp.max(jnp.abs(out.astype(jnp.float32) - ref))
-                )
-                scale = float(jnp.max(jnp.abs(ref))) or 1.0
-            # f32 threshold matches the hardware guard's 1e-5 bar (the
-            # fused and two-step paths run identical ops modulo the
-            # scale-multiply order, so real error is ~1 ulp).
-            ok = err < 1e-2 * scale if dtype == jnp.bfloat16 else (
-                err < 1e-5 * scale
-            )
-            _SAMPLED_KERNEL_OK[key] = ok
-            if not ok:
-                warnings.warn(
-                    "fused sampled-FJLT kernel compiled but miscomputed "
-                    f"at {key} (err {err:g}); using the two-step WHT + "
-                    "gather path",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
-        except Exception as e:  # noqa: BLE001 — lowering failure → 2-step
-            msg = repr(e)
-            if attempt < 2 and any(
-                tok in msg
-                for tok in ("UNAVAILABLE", "DEADLINE", "RESOURCE_EXHAUSTED")
-            ):
-                import time
-
-                time.sleep(3.0)
-                continue
-            warnings.warn(
-                "fused sampled-FJLT kernel probe failed at "
-                f"{key}; using the two-step WHT + gather path: {msg[:300]}",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            _SAMPLED_KERNEL_OK[key] = False
-    return _SAMPLED_KERNEL_OK[key]
 
 
 # Effective MXU flops-per-HBM-byte at which the explicit subsampled-
@@ -404,12 +275,13 @@ class FJLT(SketchTransform):
 
     def _apply_pallas(self, A, interpret: bool = False):
         """Fused one-pass D·x → WHT kernel (natural order, matching the
-        XLA path).  When the sampled-epilogue variant is supported (and
-        its compiled probe passes on this backend), the S-sample
-        selection + rescale happen IN the kernel and only (m, S) ever
-        reaches HBM — the f32 large-S fix (VERDICT r4 item 5); otherwise
-        the full (m, NB) transform is written and the usual XLA sampled
-        gather follows."""
+        XLA path): the full (m, NB) transform is written and the usual
+        XLA sampled gather follows.  The sampled-epilogue variant, which
+        selects and rescales IN the kernel so that only (m, S) reaches
+        HBM, is not a default route — the v5e compiler has no lane
+        gather across vregs (see ``pallas_fut._sampled_epilogue``); it
+        runs in interpret mode (CPU tests) and compiled only when
+        ``SKYLARK_PALLAS_FJLT_SAMPLED=1`` forces it (``=0`` closes both)."""
         from . import pallas_fut
 
         if not jnp.issubdtype(A.dtype, jnp.floating):
@@ -418,18 +290,9 @@ class FJLT(SketchTransform):
         mode = os.environ.get("SKYLARK_PALLAS_FJLT_SAMPLED", "")
         if (
             mode != "0"
+            and (interpret or mode == "1")
             and pallas_fut.supported_sampled(
                 A.shape[0], self.n, self._nb, self.s
-            )
-            and (
-                interpret
-                or mode == "1"
-                or _sampled_kernel_compiles(
-                    A.dtype,
-                    self._nb,
-                    self.s,
-                    pallas_fut._tile_rows(A.shape[0], self._nb),
-                )
             )
         ):
             with jax.ensure_compile_time_eval():

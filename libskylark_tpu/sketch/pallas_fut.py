@@ -44,13 +44,17 @@ _F2 = 256  # minor factor (lane-multiple; 256² H keeps the MXU busy)
 _TILE_CANDIDATES = (512, 256, 128, 64, 32, 16, 8)
 
 
-def _tile_rows(m: int, nb: int) -> int | None:
-    """Largest tile that divides m and keeps ~4 f32 working buffers of
-    (tm, nb) within the 16 MB VMEM budget."""
-    # The butterfly keeps ~log2(f1) live (tm, nb) f32 intermediates on the
-    # Mosaic stack; ~2 MB per buffer fits the measured sweet spot
-    # (tm=128 at nb=4096 with F2=256: 5.5 ms / 388 GB/s on v5e).
-    budget = (2 << 20) // (nb * 4)
+def _tile_rows(m: int, nb: int, dtype=jnp.float32) -> int | None:
+    """Largest tile that divides m and keeps the kernel's (tm, nb)
+    working buffers inside Mosaic's 16 MB scoped-VMEM limit."""
+    # The butterfly keeps ~log2(f1) live (tm, nb) f32 intermediates on
+    # the Mosaic stack; 2 MB per buffer fits for bf16 inputs (tm=128 at
+    # nb=4096).  f32 inputs carry the HIGHEST-precision contraction's
+    # operand splits on top — at 2 MB the chip's compiler refuses the
+    # kernel ("Scoped allocation with size 17.29M and limit 16.00M
+    # exceeded scoped vmem limit") — so they get half the rows.
+    per_buffer = (2 << 20) if jnp.dtype(dtype) == jnp.bfloat16 else (1 << 20)
+    budget = per_buffer // (nb * 4)
     for t in _TILE_CANDIDATES:
         if t <= max(budget, 8) and m % t == 0:
             return t
@@ -98,10 +102,12 @@ def _sampled_epilogue(z, idx_row):
 
     ``idx_row`` is a (1, S) int32 VMEM block (pallas_call rejects
     captured constant arrays, so the host-static samples arrive as an
-    input), making this a lane gather.  Whether Mosaic lowers it is
-    TPU-generation-dependent — callers gate the kernel behind a
-    compiled probe (``fjlt._sampled_kernel_compiles``) and fall back to
-    the two-step WHT + XLA gather when it doesn't."""
+    input), making this a lane gather.  The v5e compiler does not lower
+    it: as written, "Shape mismatch in input, indices and output"; as a
+    same-shape ``take_along_axis``, Mosaic's "Not implemented: Multiple
+    source vregs along gather dimension" (NB ≥ 512 spans ≥ 4 vregs).
+    So no default route reaches this kernel (``fjlt._apply_pallas``);
+    interpret mode keeps its numerics tested."""
     return jnp.take(z, idx_row[0], axis=1)
 
 
@@ -157,7 +163,7 @@ def rfut_rowwise_sampled(x, diag, nb: int, idx, interpret: bool = False):
     idx = np.asarray(idx, np.int32)
     s = int(idx.shape[0])
     m, n = x.shape
-    tm = _tile_rows(m, nb)
+    tm = _tile_rows(m, nb, x.dtype)
     if tm is None:
         raise ValueError(
             f"shape unsupported; check supported_sampled: no VMEM-fitting "
@@ -199,7 +205,7 @@ def rfut_rowwise(x, diag, nb: int, interpret: bool = False):
     from jax.experimental.pallas import tpu as pltpu
 
     m, n = x.shape
-    tm = _tile_rows(m, nb)
+    tm = _tile_rows(m, nb, x.dtype)
     if tm is None:
         raise ValueError(
             f"shape unsupported; check supported: no VMEM-fitting row "

@@ -17,8 +17,10 @@ the scalar-loop cost amortizes over the row width instead of per
 element.
 
 Layout: grid ``(Tm, Kc)`` with the entry-chunk axis Kc fastest.  Each
-grid step owns a (ck, TM) tile of A and the (1, ck) bucket/value rows
-for that chunk; a persistent f32 VMEM scratch of shape (S_pad, TM) is
+grid step owns a (ck, TM) tile of A in VMEM and that chunk's (nnz, ck)
+bucket and value tables in SMEM (they are read a scalar at a time at a
+dynamic position; VMEM serves only vector loads at 128-aligned lanes);
+a persistent f32 VMEM scratch of shape (S_pad, TM) is
 the accumulator for the current lane tile, zeroed at the first chunk and
 emitted at the last.  The optional ``acc`` operand is folded into the
 emit (``out = acc + scratch``) — a single IEEE f32 add of the same
@@ -45,11 +47,12 @@ scalar-indexed vector COPY instead of an RMW, same sublane-dynamic
 addressing, bitwise equal to the XLA ``scale * T[idx, :]`` gather (pure
 selection + the same elementwise multiply in the same dtype).
 
-Fallback: anything unsupported (gate below) keeps the XLA path;
-``SKYLARK_NO_PALLAS=1`` forces it.  ``hash._window_compiles`` runs
-:func:`self_check` once per process before the TPU-default route
-engages (the ``_kernel_compiles`` probe pattern);
-``fjlt._gather_compiles`` does the same with :func:`self_check_gather`.
+Routing: anything the static gates below exclude keeps the XLA path;
+``SKYLARK_NO_PALLAS=1`` forces it.  On a TPU a shape the gates admit
+compiles or raises — nothing probes and falls back.
+``tests/test_tpu_compile.py`` compiles both kernels for a described v5e
+chip at the bench shapes; ``chip_smoke.py`` runs :func:`self_check` and
+:func:`self_check_gather` on the chip.
 """
 
 from __future__ import annotations
@@ -143,22 +146,31 @@ def _window_kernel(with_acc: bool, *refs):
 
     nnz, ck = b_ref.shape
 
-    def entry(i, c):
-        # One scalar-indexed VECTOR accumulate per (hash, entry): dynamic
-        # sublane addressing only (pl.ds on the second-minor axis —
-        # the same RMW shape Mosaic lowers in pallas_scatter's
-        # lane-masked mode); the full TM-lane row rides the VPU.  The
-        # hash axis is a STATIC unroll — the A row loads once per entry
-        # and feeds all nnz accumulates.
-        row = a_ref[pl.ds(i, 1), :].astype(jnp.float32)
-        for h in range(nnz):
-            r = b_ref[h, i]
-            sc_ref[pl.ds(r, 1), :] = (
-                sc_ref[pl.ds(r, 1), :] + v_ref[h, i] * row
-            )
+    # Rows of A are read one aligned sublane group at a time (8 rows of
+    # f32, 16 of bf16 — a packed dtype has no dynamic single-row load)
+    # and split by STATIC slices; ck is a multiple of 128, so groups
+    # never straddle the tile.
+    group = 8 * (4 // a_ref.dtype.itemsize)
+
+    def entries(g, c):
+        # One scalar-indexed VECTOR accumulate per (hash, entry): the
+        # bucket and value are SMEM scalars, the accumulator row is a
+        # dynamic SUBLANE address (pl.ds on the second-minor axis), and
+        # the full TM-lane row rides the VPU.  Entries stay in ascending
+        # order, so the f32 sum order is the row order.  The hash axis
+        # is a static unroll — each A row feeds all nnz accumulates.
+        i0 = pl.multiple_of(g * jnp.int32(group), group)
+        rows = a_ref[pl.ds(i0, group), :].astype(jnp.float32)
+        for j in range(group):
+            row = rows[j:j + 1, :]
+            for h in range(nnz):
+                r = b_ref[h, i0 + j]
+                sc_ref[pl.ds(r, 1), :] = (
+                    sc_ref[pl.ds(r, 1), :] + v_ref[h, i0 + j] * row
+                )
         return c
 
-    jax.lax.fori_loop(0, ck, entry, 0)
+    jax.lax.fori_loop(0, ck // group, entries, 0)
 
     @pl.when(kc == pl.num_programs(1) - 1)
     def _emit():
@@ -182,22 +194,25 @@ def _scatter_rows_impl(A, b, v, acc, num_segments, interpret, with_acc):
         A = A.astype(jnp.float32)
     kp, mp = Kc * ck - k, Tm * TM - m
     A_p = jnp.pad(A, ((0, kp), (0, mp)))
-    # Stacked-hash layout: chunk-major rows, (nnz, ck) per chunk, so one
-    # (nnz, ck) block per grid step lands contiguously at block index kc.
+    # Per-chunk scalar tables: (Kc, nnz, ck) with the chunk axis squeezed
+    # out of the block, so each grid step sees one whole (nnz, ck) table.
+    # They live in SMEM — the kernel reads them one scalar at a time at a
+    # dynamic position, which VMEM (vector loads, 128-aligned lanes)
+    # cannot serve.
     b_p = (
         jnp.pad(b.astype(jnp.int32), ((0, 0), (0, kp)))
-        .reshape(nnz, Kc, ck).transpose(1, 0, 2).reshape(Kc * nnz, ck)
+        .reshape(nnz, Kc, ck).transpose(1, 0, 2)
     )
     v_p = (
         jnp.pad(v.astype(jnp.float32), ((0, 0), (0, kp)))
-        .reshape(nnz, Kc, ck).transpose(1, 0, 2).reshape(Kc * nnz, ck)
+        .reshape(nnz, Kc, ck).transpose(1, 0, 2)
     )
 
     in_specs = [
-        pl.BlockSpec((nnz, ck), lambda tm, kc: (kc, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((nnz, ck), lambda tm, kc: (kc, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, nnz, ck), lambda tm, kc: (kc, 0, 0),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec((None, nnz, ck), lambda tm, kc: (kc, 0, 0),
+                     memory_space=pltpu.SMEM),
         pl.BlockSpec((ck, TM), lambda tm, kc: (kc, tm),
                      memory_space=pltpu.VMEM),
     ]
@@ -255,9 +270,9 @@ def self_check(
     interpret: bool = False, nnz: int = 1,
 ) -> float:
     """Max *relative* error of the window kernel vs the XLA reference on
-    random buckets/values — the ONE validator shared by the TPU-default
-    probe (``hash._window_compiles``) and the hardware guard
-    (``tests/_hw_guards.py``), so the two cannot drift.  The off-tile
+    random buckets/values — the ONE validator shared by
+    ``chip_smoke.py`` and the hardware guard (``tests/_hw_guards.py``),
+    so the two cannot drift.  The off-tile
     shape (S=1000, m=320) exercises every padding seam.  ``nnz > 1``
     validates the stacked-hash layout.  Raises on lowering failure;
     callers decide the tolerance (1e-5 is the established hardware
@@ -312,14 +327,14 @@ def worthwhile_gather(nrows: int, s: int, m: int) -> bool:
 def _gather_kernel(idx_ref, t_ref, scale_ref, out_ref):
     from jax.experimental import pallas as pl
 
-    _, cs = idx_ref.shape
+    (cs,) = idx_ref.shape
     scale = scale_ref[0, 0]
 
     def entry(i, c):
         # Scalar-indexed vector COPY: pure selection plus the same
         # elementwise multiply XLA's ``scale * T[idx, :]`` performs, in
         # the same dtype — bitwise equal to the gather composite.
-        r = idx_ref[0, i]
+        r = idx_ref[i]
         out_ref[pl.ds(i, 1), :] = t_ref[pl.ds(r, 1), :] * scale
         return c
 
@@ -337,15 +352,17 @@ def _gather_rows_impl(T, idx, scale, interpret):
     sp, mp = Sc * cs - s, Tm * TM - m
     T_p = jnp.pad(T, ((0, R_pad - nrows), (0, mp)))
     # Padded indices select row 0 of T; those rows are cropped below.
-    idx_p = jnp.pad(idx.astype(jnp.int32), (0, sp)).reshape(Sc, cs)
+    # SMEM table, one whole (cs,) chunk per grid step (the kernel reads
+    # it a scalar at a time at a dynamic position).
+    idx_p = jnp.pad(idx.astype(jnp.int32), (0, sp)).reshape(Sc, 1, cs)
     scale_arr = jnp.asarray(scale, T.dtype).reshape(1, 1)
 
     out = pl.pallas_call(
         _gather_kernel,
         grid=(Tm, Sc),
         in_specs=[
-            pl.BlockSpec((1, cs), lambda tm, sc: (sc, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, None, cs), lambda tm, sc: (sc, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((R_pad, TM), lambda tm, sc: (0, tm),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1), lambda tm, sc: (0, 0),

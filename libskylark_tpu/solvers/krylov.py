@@ -83,10 +83,26 @@ def _as2d(b):
 def _chunk_stepper(body, iter_lim: int, done_of=None):
     """Jitted ≤ num_iters while-loop segment over carry dicts holding a
     global ``it`` counter.  ``done_of(state)`` adds the solver's on-device
-    convergence predicate to the loop condition."""
+    convergence predicate to the loop condition.
+
+    The arrays ``body`` closes over — the operator A, the
+    preconditioner's factors — are lifted out (the body is traced once
+    to a jaxpr, whose constants they are) and passed to the jitted
+    segment as real arguments.  Closed over by the jit they are baked
+    into the executable as literals: at 262144x1024 f32 that was a 2 GB
+    program that took minutes to compile on the chip and was too large
+    for the persistent cache to hold."""
+    lifted: list = []  # [closed jaxpr of body, output tree]
+
+    def body_of(st, operands):
+        closed, out_tree = lifted
+        out = jax.core.eval_jaxpr(
+            closed.jaxpr, operands, *jax.tree.leaves(st)
+        )
+        return jax.tree.unflatten(out_tree, out)
 
     @partial(jax.jit, static_argnames=("num_iters",))
-    def step_chunk(s, num_iters: int):
+    def run(s, operands, num_iters: int):
         stop = jnp.minimum(s["it"] + num_iters, iter_lim)
 
         def cond(st):
@@ -95,7 +111,13 @@ def _chunk_stepper(body, iter_lim: int, done_of=None):
                 go = go & ~done_of(st)
             return go
 
-        return lax.while_loop(cond, body, s)
+        return lax.while_loop(cond, lambda st: body_of(st, operands), s)
+
+    def step_chunk(s, num_iters: int):
+        if not lifted:
+            closed, out_shape = jax.make_jaxpr(body, return_shape=True)(s)
+            lifted.extend((closed, jax.tree.structure(out_shape)))
+        return run(s, lifted[0].consts, num_iters=num_iters)
 
     return step_chunk
 

@@ -73,9 +73,7 @@ def _random_bcoo(rng, shape, density=0.1):
 def test_capacity_suggestion_rejects_2d_mesh(rng):
     """The 1-D capacity helper's n/p row blocks don't match the 2-D
     grid's row-axis exchange — a silently wrong capacity would drop
-    entries, so multi-axis meshes must be refused loudly.  Meshes are
-    built directly (no make_mesh) so this runs tier-1 regardless of the
-    installed JAX's AxisType support."""
+    entries, so multi-axis meshes must be refused loudly."""
     from jax.sharding import Mesh
 
     from libskylark_tpu.parallel import suggest_sparse_out_capacity

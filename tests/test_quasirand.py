@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from libskylark_tpu.core.quasirand import LeapedHaltonSequence, primes
 from libskylark_tpu.utils.exceptions import InvalidParameters

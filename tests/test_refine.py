@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from libskylark_tpu import plans, policy, serve
 from libskylark_tpu.core.context import SketchContext

@@ -55,37 +55,36 @@ def test_cli_sparse_path(tmp_path, rng):
     assert np.load(tmp_path / "o.S.npy").shape == (3,)
 
 
-def test_kernel_probe_runs_inside_jit_trace(monkeypatch):
-    """The one-time Pallas-scatter probe must execute eagerly even when
-    its first caller is mid-trace: under omnistaging the probe's ops
-    would otherwise be staged into the caller's trace and the float()
-    readback would raise ConcretizationTypeError — which the blanket
-    except would latch as a permanent (and wrong) kernel-broken verdict."""
+def test_hoisted_operand_cache_skipped_both_ways_under_trace():
+    """``hoistable_operands`` memoizes per sketch — except mid-trace,
+    where an operand built from tracers must not be cached and a cached
+    concrete operand must not be returned (it would be baked into the
+    caller's executable as a constant)."""
     import jax
 
-    from libskylark_tpu.sketch import hash as hash_mod
-    from libskylark_tpu.sketch import pallas_scatter
+    from libskylark_tpu import SketchContext
+    from libskylark_tpu.sketch import CWT, JLT
 
-    # Stand-in validator: same jnp-op + float() shape as the real
-    # self_check, minus the Pallas call (not lowerable on CPU compiled
-    # mode); what is under test is the trace-escape, not the kernel.
-    def fake_self_check():
-        x = jnp.arange(8.0)
-        return float(jnp.max(x) - jnp.max(x))
+    for S in (JLT(64, 16, SketchContext(seed=1)),
+              CWT(64, 16, SketchContext(seed=2))):
+        seen = {}
 
-    monkeypatch.setattr(pallas_scatter, "self_check", fake_self_check)
-    monkeypatch.setattr(hash_mod, "_KERNEL_COMPILES", None)
+        @jax.jit
+        def traced(v):
+            ops = S.hoistable_operands(jnp.float32)
+            seen["traced"] = any(
+                isinstance(leaf, jax.core.Tracer)
+                for leaf in jax.tree.leaves(ops)
+            )
+            return v * 2
 
-    result = {}
-
-    @jax.jit
-    def traced(v):
-        result["ok"] = hash_mod._kernel_compiles()
-        return v * 2
-
-    traced(jnp.ones(4))
-    assert result["ok"] is True
-    assert hash_mod._KERNEL_COMPILES is True
+        traced(jnp.ones(4))
+        assert seen["traced"] and "_hoist_cache" not in S.__dict__
+        eager = S.hoistable_operands(jnp.float32)
+        assert S.hoistable_operands(jnp.float32) is eager  # memoized
+        traced.clear_cache()
+        traced(jnp.ones(4))
+        assert seen["traced"]  # the cached concrete operand stayed out
 
 
 def test_halton_window_tiered_digits_bit_identical():

@@ -1,0 +1,246 @@
+"""Compile the main path's kernels and programs for a described v5e chip.
+
+No chip is attached: the TPU compiler installed here compiles for a
+topology that is described (``v5e:2x2``, first device), and raises what
+the chip's compiler would raise — block shapes off the (8, 128) grain,
+scoped-VMEM overflow, loads Mosaic cannot prove aligned.  Interpret-mode
+tests cannot see any of that.  Nothing runs, so nothing here says
+anything about results or times.
+
+Every Pallas kernel a default route can pick on a TPU is compiled at its
+bench shape, and the text must hold a ``tpu_custom_call``; the two
+kernels no default route reaches are pinned as refused, so a compiler
+that starts accepting them shows up here.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file), compiles run in the test's own
+process, and the persistent compilation cache is off around them.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from libskylark_tpu import SketchContext
+from libskylark_tpu.sketch import CWT, FJLT, JLT, SJLT
+from libskylark_tpu.sketch import fjlt as fjlt_mod
+from libskylark_tpu.sketch import hash as hash_mod
+from libskylark_tpu.sketch import pallas_fut, pallas_scatter, pallas_window
+
+K, M, S = 131_072, 4096, 1024  # the bench's sketch shape
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Static gates ask ``jax.default_backend()``; steer them to the
+    branch they take on the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for k in ("SKYLARK_PALLAS_WINDOW", "SKYLARK_PALLAS_GATHER",
+              "SKYLARK_PALLAS_SCATTER", "SKYLARK_NO_PALLAS"):
+        monkeypatch.delenv(k, raising=False)
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower and compile for the described chip, x64 OFF as on the chip
+    (the suite's conftest turns it on; under it index maps and loop
+    counters trace as i64, which is not the program users run)."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    with jax.enable_x64(False):
+        return jax.jit(fn).lower(*args).compile()
+
+
+def _text(fn, one_chip, *shapes):
+    return _compile(fn, one_chip, *shapes).as_text()
+
+
+# -- window scatter (CWT/MMT/SJLT columnwise slices) --------------------------
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("with_acc", [False, True], ids=["plain", "acc"])
+def test_window_scatter(one_chip, dtype, with_acc):
+    if with_acc:
+        def fn(A, b, v, acc):
+            return pallas_window.scatter_rows(A, b, v, S, acc=acc)
+        extra = [((S, M), F32)]
+    else:
+        def fn(A, b, v):
+            return pallas_window.scatter_rows(A, b, v, S)
+        extra = []
+    assert pallas_window.supported(K, S, M)
+    text = _text(fn, one_chip, ((K, M), dtype), ((K,), I32), ((K,), F32),
+                 *extra)
+    assert "tpu_custom_call" in text
+
+
+def test_window_scatter_sjlt_nnz4(one_chip):
+    assert pallas_window.supported(K, S, M, 4)
+    text = _text(
+        lambda A, b, v: pallas_window.scatter_rows(A, b, v, S),
+        one_chip, ((K, M), F32), ((4, K), I32), ((4, K), F32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "k,s,m,nnz", [(16_384, 1000, 320, 1), (16_384, 1000, 320, 4),
+                  (65_536, 1024, 256, 1)],
+    ids=["self_check", "self_check_nnz4", "hw_guard"],
+)
+def test_window_scatter_check_shapes(one_chip, k, s, m, nnz):
+    """The off-tile shapes ``chip_smoke.py``'s self-checks run."""
+    text = _text(
+        lambda A, b, v: pallas_window.scatter_rows(A, b, v, s),
+        one_chip, ((k, m), F32), ((nnz, k), I32), ((nnz, k), F32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("cls,nnz", [(CWT, 1), (SJLT, 4)],
+                         ids=["CWT", "SJLT4"])
+def test_fused_stream_chunk_step_routes_to_kernel(one_chip, as_tpu, cls, nnz):
+    """The streaming chunk step as the plan layer traces it
+    (``apply_slice_kernel_acc``, traced start): on a TPU the static gate
+    says kernel, and the compiled step holds it."""
+    assert hash_mod._window_mode(K, M, S, F32, nnz) == "kernel"
+    kw = {"nnz": nnz} if nnz > 1 else {}
+    sk = cls(4 * K, S, SketchContext(seed=3), **kw)
+    text = _text(
+        lambda acc, blk, start: sk.apply_slice_kernel_acc(acc, blk, start),
+        one_chip, ((S, M), F32), ((K, M), F32), ((), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+# -- flat scatter: refused, and on no default route ---------------------------
+
+
+def test_flat_scatter_is_refused_and_not_default(one_chip, as_tpu):
+    nnz, segs = 10_000_000, 1 << 17
+    assert pallas_scatter.supported(nnz, segs)
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        _text(
+            lambda v, k: pallas_scatter.segment_sum_flat(v, k, segs),
+            one_chip, ((nnz,), F32), ((nnz,), I32),
+        )
+    text = _text(
+        lambda v, k: hash_mod._segment_sum(v, k, segs),
+        one_chip, ((nnz,), F32), ((nnz,), I32),
+    )
+    assert "tpu_custom_call" not in text
+
+
+# -- FUT / FJLT ---------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_rfut_rowwise(one_chip, dtype):
+    assert pallas_fut.supported(K, M, M)
+    text = _text(
+        lambda x, d: pallas_fut.rfut_rowwise(x, d, M),
+        one_chip, ((K, M), dtype), ((M,), dtype),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_rfut_rowwise_guard_shape(one_chip):
+    text = _text(
+        lambda x, d: pallas_fut.rfut_rowwise(x, d, 512),
+        one_chip, ((256, 512), F32), ((512,), F32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_rfut_sampled_is_refused_and_not_default(one_chip, as_tpu, dtype):
+    idx = np.random.default_rng(0).integers(0, M, S).astype(np.int32)
+    assert pallas_fut.supported_sampled(K, M, M, S)
+    with pytest.raises(ValueError, match="Shape mismatch"):
+        _text(
+            lambda x, d: pallas_fut.rfut_rowwise_sampled(x, d, M, idx),
+            one_chip, ((K, M), dtype), ((M,), dtype),
+        )
+    # The default route of the kernel branch is the two-step form: the
+    # fused kernel, then XLA's gather.
+    sk = FJLT(M, S, SketchContext(seed=5))
+    text = _text(sk._apply_pallas, one_chip, ((256, M), dtype))
+    assert "tpu_custom_call" in text
+
+
+def test_gather_scaled_rows(one_chip, as_tpu):
+    nrows, s, m = 2048, 1024, M
+    assert pallas_window.supported_gather(nrows, s, m)
+    assert fjlt_mod._gather_mode(nrows, s, m, F32) == "kernel"
+    text = _text(
+        lambda T, i: pallas_window.gather_scaled_rows(T, i, 0.5),
+        one_chip, ((nrows, m), F32), ((s,), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_gather_self_check_shape(one_chip):
+    text = _text(
+        lambda T, i: pallas_window.gather_scaled_rows(T, i, 0.3125),
+        one_chip, ((3000, 320), F32), ((4096,), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+# -- whole programs at full size ----------------------------------------------
+
+
+def test_jlt_apply_full_size(one_chip):
+    sk = JLT(M, S, SketchContext(seed=7))
+    text = _text(lambda A: sk.apply(A, "rowwise"), one_chip,
+                    ((262_144, M), BF16))
+    assert "tpu_custom_call" not in text  # a plain MXU matmul
+
+
+def test_streaming_krr_sweep_step_full_size(one_chip):
+    """One sweep step (``zr``) of the streaming-KRR chunk programs at
+    the north-star panel: 131072x4096 -> 2048 bf16, 8 panels."""
+    from libskylark_tpu.ml import GaussianKernel
+    from libskylark_tpu.ml.krr import streaming_krr_chunk_programs
+
+    D, SZ, NB, BR = 4096, 2048, 8, 131_072
+    maps = [GaussianKernel(D, sigma=8.0).create_rft(
+        SZ, "regular", SketchContext(seed=9))]
+
+    def block_fn(start, rows, X0):
+        return jnp.roll(X0, start // rows, axis=0)
+
+    _, zr, _ = streaming_krr_chunk_programs(
+        maps, 0, SZ, NB, BR, 1, 0.1, block_fn, BF16
+    )
+    compiled = _compile(zr, one_chip, ((NB, BR, 1), F32), ((SZ, 1), F32),
+                        ((BR, D), BF16))
+    assert compiled.memory_analysis().temp_size_in_bytes < 12 << 30
