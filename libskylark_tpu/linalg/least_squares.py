@@ -159,201 +159,210 @@ def approximate_least_squares(
     from .. import policy
     from ..policy.decide import LS_ROUTES
 
-    if route is not None and route not in LS_ROUTES:
-        raise ValueError(
-            f"unknown least-squares route {route!r}; one of {LS_ROUTES}"
+    with telemetry.span("sketch_solve"):
+        if route is not None and route not in LS_ROUTES:
+            raise ValueError(
+                f"unknown least-squares route {route!r}; one of {LS_ROUTES}"
+            )
+        params = params or LeastSquaresParams()
+        is_sparse = hasattr(A, "todense")
+        if not is_sparse:
+            A = jnp.asarray(A)
+        B = jnp.asarray(B)
+        squeeze = B.ndim == 1
+        if squeeze:
+            B = B[:, None]
+        m, n = A.shape
+        guard_on = guard.enabled() and not guard.is_traced(A, B)
+        decision = policy.consult(
+            "ls",
+            m=m,
+            n=n,
+            targets=B.shape[1],
+            dtype=(A.data.dtype.name if is_sparse else A.dtype.name),
+            sparse=is_sparse,
+            route=route,
+            sketch_type=params.sketch_type,
+            sketch_size=params.sketch_size,
+            guard_on=guard_on,
         )
-    params = params or LeastSquaresParams()
-    is_sparse = hasattr(A, "todense")
-    if not is_sparse:
-        A = jnp.asarray(A)
-    B = jnp.asarray(B)
-    squeeze = B.ndim == 1
-    if squeeze:
-        B = B[:, None]
-    m, n = A.shape
-    guard_on = guard.enabled() and not guard.is_traced(A, B)
-    decision = policy.consult(
-        "ls",
-        m=m,
-        n=n,
-        targets=B.shape[1],
-        dtype=(A.data.dtype.name if is_sparse else A.dtype.name),
-        sparse=is_sparse,
-        route=route,
-        sketch_type=params.sketch_type,
-        sketch_size=params.sketch_size,
-        guard_on=guard_on,
-    )
-    s = decision.sketch_size
-    stype = decision.sketch_type
-    default_size = min(4 * n, m)
+        s = decision.sketch_size
+        stype = decision.sketch_type
+        default_size = min(4 * n, m)
 
-    # -- profile-learned reroutes (never taken on an empty store) ------------
-    if decision.route == "exact":
-        A_dense = A.todense() if is_sparse else A
-        X = exact_least_squares(A_dense, B, alg="svd")
-        report = (
-            guard.RecoveryReport(stage="sketch_and_solve_ls")
-            if guard_on
-            else guard.RecoveryReport.disabled("sketch_and_solve_ls")
-        )
-        if guard_on:
-            guard.check_finite(X, "exact_ls", report=report)
+        # -- profile-learned reroutes (never taken on an empty store) ------------
+        if decision.route == "exact":
+            A_dense = A.todense() if is_sparse else A
+            X = exact_least_squares(A_dense, B, alg="svd")
+            report = (
+                guard.RecoveryReport(stage="sketch_and_solve_ls")
+                if guard_on
+                else guard.RecoveryReport.disabled("sketch_and_solve_ls")
+            )
+            if guard_on:
+                guard.check_finite(X, "exact_ls", report=report)
+            out = X[:, 0] if squeeze else X
+            info = {"recovery": report.to_dict(), "policy": decision.to_dict()}
+            policy.observe(decision, info, default_size=default_size)
+            telemetry.run_summary("sketch_and_solve_ls", info)
+            return (out, info) if return_info else out
+        if decision.route in ("blendenpik", "lsrn"):
+            from ..solvers.accelerated import (
+                FasterLeastSquaresParams,
+                faster_least_squares,
+                lsrn_least_squares,
+            )
+
+            fls = FasterLeastSquaresParams(sketch_type=params.sketch_type)
+            solver = (
+                faster_least_squares
+                if decision.route == "blendenpik"
+                else lsrn_least_squares
+            )
+            X, rinfo = solver(A, B, context, fls)
+            out = X[:, 0] if squeeze else X
+            info = dict(rinfo)
+            info["policy"] = decision.to_dict()
+            policy.observe(decision, info, default_size=default_size)
+            telemetry.run_summary("sketch_and_solve_ls", info)
+            return (out, info) if return_info else out
+        if decision.route == "refine":
+            from ..solvers.refine import RefineParams, refine_least_squares
+
+            rp = RefineParams(
+                sketch_type=decision.sketch_type,
+                sketch_size=decision.sketch_size,
+            )
+            X, rinfo = refine_least_squares(
+                A, B, context, rp, fault_plan=fault_plan
+            )
+            out = X[:, 0] if squeeze else X
+            info = dict(rinfo)
+            info["policy"] = decision.to_dict()
+            policy.observe(
+                decision, info, default_size=default_size,
+                refine=rinfo.get("refine"),
+            )
+            telemetry.run_summary("sketch_and_solve_ls", info)
+            return (out, info) if return_info else out
+
+        # Under an enclosing jit trace the host-side certificate reads and
+        # ladder control flow cannot run — emit the plain unguarded graph.
+        if not guard_on:
+            S = create_sketch(stype, m, s, context)
+            # Plan-cached applies: repeated sketch-and-solve calls at the same
+            # shape (parameter sweeps, restarts) reuse one fused executable.
+            SA = plans.apply(S, A, Dimension.COLUMNWISE)
+            SB = plans.apply(S, B, Dimension.COLUMNWISE)
+            if fault_plan is not None:
+                SA = fault_plan.corrupt_sketch(0, SA)
+            with telemetry.span("sketch_solve.small"):
+                X = exact_least_squares(SA, SB, alg=alg)
+            out = X[:, 0] if squeeze else X
+            if return_info:
+                report = guard.RecoveryReport.disabled("sketch_and_solve_ls")
+                info = {
+                    "recovery": report.to_dict(),
+                    "policy": decision.to_dict(),
+                }
+                telemetry.run_summary("sketch_and_solve_ls", info)
+                return out, info
+            return out
+
+        def run_guarded(A_in, cast_solve):
+            """One trip up the guard ladder; ``cast_solve`` lifts the (narrow)
+            sketch output back to B's dtype before certification + solve (the
+            small s×n problem always solves at full precision)."""
+
+            def attempt(ctx, s_i, i):
+                S = create_sketch(stype, m, s_i, ctx)
+                SA = plans.apply(S, A_in, Dimension.COLUMNWISE)
+                SB = plans.apply(S, B, Dimension.COLUMNWISE)
+                if cast_solve:
+                    SA = SA.astype(B.dtype)
+                if fault_plan is not None:
+                    SA = fault_plan.corrupt_sketch(i, SA)
+                # the certificate and its cond_est: host waits on the sketch
+                with telemetry.span("guard.certify"):
+                    cert = guard.certify_sketch(
+                        SA, stage="sketch_and_solve_ls"
+                    )
+                if not cert.ok:
+                    return None, cert
+                with telemetry.span("sketch_solve.small"):
+                    X = exact_least_squares(SA, SB, alg=alg)
+                with telemetry.span("guard.check"):  # waits for the solve
+                    finite = guard.tree_all_finite(X)
+                if not finite:
+                    cert = replace(
+                        cert,
+                        verdict=guard.RESKETCH,
+                        detail="non-finite small-problem solution",
+                    )
+                    return None, cert
+                return X, cert
+
+            def fallback():
+                A_dense = A.todense() if is_sparse else A
+                return exact_least_squares(A_dense, B, alg="svd")
+
+            return guard.run_ladder(
+                "sketch_and_solve_ls", context, s, m, attempt, fallback
+            )
+
+        def _ok0(report):
+            attempts = report.to_dict().get("attempts") or []
+            return bool(attempts) and attempts[0].get("verdict") == guard.OK
+
+        bf16_note = None
+        fp8_note = None
+        if decision.compute_dtype == "float8_e4m3fn":
+            # fp8-first (one rung below bf16, reached only through a clean
+            # bf16 history): the sketch OPERAND is rounded to e4m3 — the
+            # rung's precision semantics — then lifted to bf16 so the apply
+            # reuses the proven f32-accumulating machinery (on fp8-MXU
+            # hardware XLA folds the f8→bf16 convert into the matmul).  The
+            # guard certificate checks the lifted sketch; a non-OK attempt 0
+            # — or a backend that cannot lower f8 at all — escalates to the
+            # input dtype and records ``fp8: fail`` so the policy retires
+            # the rung for this key.
+            from ..core.precision import fp8_dtype
+
+            X = report = None
+            f8 = fp8_dtype()
+            if f8 is not None:
+                try:
+                    X, report = run_guarded(
+                        A.astype(f8).astype(jnp.bfloat16), True
+                    )
+                except Exception:  # noqa: BLE001 — f8 lowering failure → f32
+                    X = report = None
+            if report is None or not _ok0(report):
+                decision.escalated = True
+                fp8_note = "fail"
+                X, report = run_guarded(A, False)
+        elif decision.compute_dtype == "bfloat16":
+            # bf16-first: the MXU-heavy sketch runs at bf16 (the
+            # f32-accumulable kernel entry points make it nearly free); the
+            # guard certificate checks the lifted sketch and a non-OK attempt
+            # 0 escalates the whole solve back to the input dtype.
+            X, report = run_guarded(A.astype(jnp.bfloat16), True)
+            if not _ok0(report):
+                decision.escalated = True
+                bf16_note = "fail"
+                X, report = run_guarded(A, False)
+        else:
+            X, report = run_guarded(A, False)
         out = X[:, 0] if squeeze else X
         info = {"recovery": report.to_dict(), "policy": decision.to_dict()}
-        policy.observe(decision, info, default_size=default_size)
-        telemetry.run_summary("sketch_and_solve_ls", info)
-        return (out, info) if return_info else out
-    if decision.route in ("blendenpik", "lsrn"):
-        from ..solvers.accelerated import (
-            FasterLeastSquaresParams,
-            faster_least_squares,
-            lsrn_least_squares,
-        )
-
-        fls = FasterLeastSquaresParams(sketch_type=params.sketch_type)
-        solver = (
-            faster_least_squares
-            if decision.route == "blendenpik"
-            else lsrn_least_squares
-        )
-        X, rinfo = solver(A, B, context, fls)
-        out = X[:, 0] if squeeze else X
-        info = dict(rinfo)
-        info["policy"] = decision.to_dict()
-        policy.observe(decision, info, default_size=default_size)
-        telemetry.run_summary("sketch_and_solve_ls", info)
-        return (out, info) if return_info else out
-    if decision.route == "refine":
-        from ..solvers.refine import RefineParams, refine_least_squares
-
-        rp = RefineParams(
-            sketch_type=decision.sketch_type,
-            sketch_size=decision.sketch_size,
-        )
-        X, rinfo = refine_least_squares(
-            A, B, context, rp, fault_plan=fault_plan
-        )
-        out = X[:, 0] if squeeze else X
-        info = dict(rinfo)
-        info["policy"] = decision.to_dict()
         policy.observe(
-            decision, info, default_size=default_size,
-            refine=rinfo.get("refine"),
+            decision, info, default_size=default_size, bf16=bf16_note,
+            fp8=fp8_note,
         )
         telemetry.run_summary("sketch_and_solve_ls", info)
-        return (out, info) if return_info else out
-
-    # Under an enclosing jit trace the host-side certificate reads and
-    # ladder control flow cannot run — emit the plain unguarded graph.
-    if not guard_on:
-        S = create_sketch(stype, m, s, context)
-        # Plan-cached applies: repeated sketch-and-solve calls at the same
-        # shape (parameter sweeps, restarts) reuse one fused executable.
-        SA = plans.apply(S, A, Dimension.COLUMNWISE)
-        SB = plans.apply(S, B, Dimension.COLUMNWISE)
-        if fault_plan is not None:
-            SA = fault_plan.corrupt_sketch(0, SA)
-        X = exact_least_squares(SA, SB, alg=alg)
-        out = X[:, 0] if squeeze else X
         if return_info:
-            report = guard.RecoveryReport.disabled("sketch_and_solve_ls")
-            info = {
-                "recovery": report.to_dict(),
-                "policy": decision.to_dict(),
-            }
-            telemetry.run_summary("sketch_and_solve_ls", info)
             return out, info
         return out
-
-    def run_guarded(A_in, cast_solve):
-        """One trip up the guard ladder; ``cast_solve`` lifts the (narrow)
-        sketch output back to B's dtype before certification + solve (the
-        small s×n problem always solves at full precision)."""
-
-        def attempt(ctx, s_i, i):
-            S = create_sketch(stype, m, s_i, ctx)
-            SA = plans.apply(S, A_in, Dimension.COLUMNWISE)
-            SB = plans.apply(S, B, Dimension.COLUMNWISE)
-            if cast_solve:
-                SA = SA.astype(B.dtype)
-            if fault_plan is not None:
-                SA = fault_plan.corrupt_sketch(i, SA)
-            cert = guard.certify_sketch(SA, stage="sketch_and_solve_ls")
-            if not cert.ok:
-                return None, cert
-            X = exact_least_squares(SA, SB, alg=alg)
-            if not guard.tree_all_finite(X):
-                cert = replace(
-                    cert,
-                    verdict=guard.RESKETCH,
-                    detail="non-finite small-problem solution",
-                )
-                return None, cert
-            return X, cert
-
-        def fallback():
-            A_dense = A.todense() if is_sparse else A
-            return exact_least_squares(A_dense, B, alg="svd")
-
-        return guard.run_ladder(
-            "sketch_and_solve_ls", context, s, m, attempt, fallback
-        )
-
-    def _ok0(report):
-        attempts = report.to_dict().get("attempts") or []
-        return bool(attempts) and attempts[0].get("verdict") == guard.OK
-
-    bf16_note = None
-    fp8_note = None
-    if decision.compute_dtype == "float8_e4m3fn":
-        # fp8-first (one rung below bf16, reached only through a clean
-        # bf16 history): the sketch OPERAND is rounded to e4m3 — the
-        # rung's precision semantics — then lifted to bf16 so the apply
-        # reuses the proven f32-accumulating machinery (on fp8-MXU
-        # hardware XLA folds the f8→bf16 convert into the matmul).  The
-        # guard certificate checks the lifted sketch; a non-OK attempt 0
-        # — or a backend that cannot lower f8 at all — escalates to the
-        # input dtype and records ``fp8: fail`` so the policy retires
-        # the rung for this key.
-        from ..core.precision import fp8_dtype
-
-        X = report = None
-        f8 = fp8_dtype()
-        if f8 is not None:
-            try:
-                X, report = run_guarded(
-                    A.astype(f8).astype(jnp.bfloat16), True
-                )
-            except Exception:  # noqa: BLE001 — f8 lowering failure → f32
-                X = report = None
-        if report is None or not _ok0(report):
-            decision.escalated = True
-            fp8_note = "fail"
-            X, report = run_guarded(A, False)
-    elif decision.compute_dtype == "bfloat16":
-        # bf16-first: the MXU-heavy sketch runs at bf16 (the
-        # f32-accumulable kernel entry points make it nearly free); the
-        # guard certificate checks the lifted sketch and a non-OK attempt
-        # 0 escalates the whole solve back to the input dtype.
-        X, report = run_guarded(A.astype(jnp.bfloat16), True)
-        if not _ok0(report):
-            decision.escalated = True
-            bf16_note = "fail"
-            X, report = run_guarded(A, False)
-    else:
-        X, report = run_guarded(A, False)
-    out = X[:, 0] if squeeze else X
-    info = {"recovery": report.to_dict(), "policy": decision.to_dict()}
-    policy.observe(
-        decision, info, default_size=default_size, bf16=bf16_note,
-        fp8=fp8_note,
-    )
-    telemetry.run_summary("sketch_and_solve_ls", info)
-    if return_info:
-        return out, info
-    return out
 
 
 def streaming_least_squares(

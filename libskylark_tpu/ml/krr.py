@@ -37,7 +37,7 @@ from ..core.params import Params
 from ..parallel.mesh import fully_replicated
 from ..sketch.base import Dimension, create_sketch
 from ..solvers.krylov import KrylovParams, cg
-from ..utils import compile_cache
+from ..utils import PhaseTimer, compile_cache
 from .kernels import Kernel
 from .model import FeatureMapModel, KernelModel
 
@@ -506,78 +506,84 @@ def streaming_kernel_ridge(
     the counter stream / storage.  Multi-chip runs shard the panels with
     ``mesh`` machinery upstream (see ``__graft_entry__.dryrun_multichip``).
     """
-    compile_cache.place()
-    params = params or KrrParams()
-    n, d = shape
-    if n % block_rows:
-        # Largest divisor of n not exceeding the request: callers get a
-        # working panel size instead of a divisibility error (the panel
-        # size only shapes memory, not results).  A degenerate divisor
-        # (n near-prime) would turn the panel loops into per-row
-        # iteration — error out with an actionable message instead.
-        best = max(b for b in range(1, block_rows + 1) if n % b == 0)
-        # best == n is always usable (the whole problem fits in ONE
-        # panel — nb=1 — the degenerate-divisor concern is moot); only
-        # error when a large n truly fractures into tiny panels.
-        if best < n and best < max(256, block_rows // 16):
-            raise ValueError(
-                f"n={n} has no usable panel divisor <= {block_rows} "
-                f"(best is {best}); pad n to a composite size or pass a "
-                "block_rows that divides it"
-            )
-        block_rows = best
-    nb = n // block_rows
-    Y2, _ = _as2d(Y)
-    t = Y2.shape[1]
+    with telemetry.span("krr_train"):
+        compile_cache.place()
+        params = params or KrrParams()
+        n, d = shape
+        if n % block_rows:
+            # Largest divisor of n not exceeding the request: callers get a
+            # working panel size instead of a divisibility error (the panel
+            # size only shapes memory, not results).  A degenerate divisor
+            # (n near-prime) would turn the panel loops into per-row
+            # iteration — error out with an actionable message instead.
+            best = max(b for b in range(1, block_rows + 1) if n % b == 0)
+            # best == n is always usable (the whole problem fits in ONE
+            # panel — nb=1 — the degenerate-divisor concern is moot); only
+            # error when a large n truly fractures into tiny panels.
+            if best < n and best < max(256, block_rows // 16):
+                raise ValueError(
+                    f"n={n} has no usable panel divisor <= {block_rows} "
+                    f"(best is {best}); pad n to a composite size or pass a "
+                    "block_rows that divides it"
+                )
+            block_rows = best
+        nb = n // block_rows
+        Y2, _ = _as2d(Y)
+        t = Y2.shape[1]
 
-    sizes = _chunk_sizes(d, s, params)
-    maps = [kernel.create_rft(sz, _tag(params), context) for sz in sizes]
+        with telemetry.span("krr.programs"):
+            sizes = _chunk_sizes(d, s, params)
+            maps = [
+                kernel.create_rft(sz, _tag(params), context) for sz in sizes
+            ]
+            programs = [
+                streaming_krr_chunk_programs(
+                    maps, c, sizes[c], nb, block_rows, t, lam, block_fn,
+                    feature_dtype,
+                )
+                for c in range(len(maps))
+            ]
+        factors = []
+        Ws = [jnp.zeros((sz, t), jnp.float32) for sz in sizes]
+        # Panel-major residual (see streaming_krr_chunk_programs): sharded
+        # callers pay one reshard here, zero per-sweep R collectives after.
+        R = Y2.astype(jnp.float32).reshape(nb, block_rows, t)
 
-    programs = [
-        streaming_krr_chunk_programs(
-            maps, c, sizes[c], nb, block_rows, t, lam, block_fn,
-            feature_dtype,
-        )
-        for c in range(len(maps))
-    ]
-    factors = []
-    Ws = [jnp.zeros((sz, t), jnp.float32) for sz in sizes]
-    # Panel-major residual (see streaming_krr_chunk_programs): sharded
-    # callers pay one reshard here, zero per-sweep R collectives after.
-    R = Y2.astype(jnp.float32).reshape(nb, block_rows, t)
+        # Without a caller's timer the phases only annotate the trace.
+        timer = timer if timer is not None else PhaseTimer(sync=False)
 
-    import contextlib
-
-    # Sweep 0 is unconditional (factors must exist), matching
-    # large_scale_kernel_ridge's loop structure where the first sweep
-    # runs outside the iteration count — iter_lim=0 means "one pass".
-    for it in range(max(params.iter_lim, 1)):
-        phase = (
-            timer.phase("sweep0" if it == 0 else "sweep")
-            if timer is not None
-            else contextlib.nullcontext()
-        )
-        with phase as ph:
-            delsize = 0.0
-            for c, (gram, zr, apply_delta) in enumerate(programs):
-                if it == 0:
-                    factors.append(cho_factor(gram(*block_args), lower=True))
-                ZR = zr(R, Ws[c], *block_args)
-                delta = cho_solve(factors[c], ZR)
-                Ws[c] = Ws[c] + delta
-                R = apply_delta(R, delta, *block_args)
-                delsize += float(jnp.sum(delta * delta))
-            if ph is not None:
+        # Sweep 0 is unconditional (factors must exist), matching
+        # large_scale_kernel_ridge's loop structure where the first sweep
+        # runs outside the iteration count — iter_lim=0 means "one pass".
+        for it in range(max(params.iter_lim, 1)):
+            with timer.phase("sweep0" if it == 0 else "sweep") as ph:
+                delsize = 0.0
+                for c, (gram, zr, apply_delta) in enumerate(programs):
+                    if it == 0:
+                        with telemetry.span("krr.gram"):
+                            G = gram(*block_args)
+                        with telemetry.span("krr.factor"):
+                            factors.append(cho_factor(G, lower=True))
+                        del G
+                    with telemetry.span("krr.zr"):
+                        ZR = zr(R, Ws[c], *block_args)
+                    with telemetry.span("krr.solve"):
+                        delta = cho_solve(factors[c], ZR)
+                        Ws[c] = Ws[c] + delta
+                    with telemetry.span("krr.apply_delta"):
+                        R = apply_delta(R, delta, *block_args)
+                    with telemetry.span("krr.converge"):  # a host wait
+                        delsize += float(jnp.sum(delta * delta))
                 ph.result = R
-        wnorm = float(jnp.sqrt(sum(jnp.sum(W * W) for W in Ws)))
-        reldel = (delsize**0.5) / max(wnorm, 1e-30)
-        params.log(2, f"iteration {it}, relupdate = {reldel:.2e}")
-        if it > 0 and reldel < params.tolerance:
-            break
+            with telemetry.span("krr.converge"):
+                wnorm = float(jnp.sqrt(sum(jnp.sum(W * W) for W in Ws)))
+            reldel = (delsize**0.5) / max(wnorm, 1e-30)
+            params.log(2, f"iteration {it}, relupdate = {reldel:.2e}")
+            if it > 0 and reldel < params.tolerance:
+                break
 
-    W = jnp.concatenate(Ws, axis=0)
-    return FeatureMapModel(maps, W)
-
+        W = jnp.concatenate(Ws, axis=0)
+        return FeatureMapModel(maps, W)
 
 def streaming_krr_chunk_programs(
     maps, c, sz, nb, block_rows, t, lam, block_fn, feature_dtype

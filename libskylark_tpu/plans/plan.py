@@ -236,28 +236,27 @@ def apply(S, A, dim: Dimension | str = Dimension.COLUMNWISE):
     ):
         PLAN_CACHE.bump("bypasses")
         return S.apply(A, dim)
-    A = jnp.asarray(A)
-    key = (
-        "apply",
-        _token(S),
-        dim.value,
-        A.shape,
-        A.dtype.name,
-        _sharding_key(A),
-    )
     from .. import policy
 
-    policy.note_plan("apply", S, dim=dim.value, shape=A.shape, dtype=A.dtype.name)
-    plan = PLAN_CACHE.get_or_build(
-        key, lambda: SketchPlan(key, lambda A_: S.apply(A_, dim))
-    )
-    if telemetry.enabled():
-        with telemetry.span(
-            "sketch.apply", dim=dim.value, shape=list(A.shape)
-        ) as sp:
-            sp.result = plan(A)
-        return sp.result
-    return plan(A)
+    A = jnp.asarray(A)
+    with telemetry.span("plans.lookup"):
+        key = (
+            "apply",
+            _token(S),
+            dim.value,
+            A.shape,
+            A.dtype.name,
+            _sharding_key(A),
+        )
+        policy.note_plan(
+            "apply", S, dim=dim.value, shape=A.shape, dtype=A.dtype.name
+        )
+        plan = PLAN_CACHE.get_or_build(
+            key, lambda: SketchPlan(key, lambda A_: S.apply(A_, dim))
+        )
+    with telemetry.span("sketch.apply", dim=dim.value, shape=A.shape) as sp:
+        out = sp.result = plan(A)  # an enabled span blocks on it at exit
+    return out
 
 
 def accumulate_slice(

@@ -81,75 +81,82 @@ def faster_least_squares(
     which case the Blendenpik-native retry loop still runs — it predates
     the guard and is the paper's own robustness mechanism).
     """
-    params = params or FasterLeastSquaresParams()
-    m, n = A.shape
-    if m < n:
-        raise ValueError(f"faster_least_squares needs tall A, got {A.shape}")
-    eps = float(jnp.finfo(jnp.asarray(A).dtype if not hasattr(A, "todense") else A.data.dtype).eps)
-    threshold = params.cond_threshold or 0.1 / np.sqrt(eps)
+    with telemetry.span("blendenpik"):
+        params = params or FasterLeastSquaresParams()
+        m, n = A.shape
+        if m < n:
+            raise ValueError(f"faster_least_squares needs tall A, got {A.shape}")
+        eps = float(jnp.finfo(jnp.asarray(A).dtype if not hasattr(A, "todense") else A.data.dtype).eps)
+        threshold = params.cond_threshold or 0.1 / np.sqrt(eps)
 
-    guarded = guard.enabled()
-    report = (
-        guard.RecoveryReport(stage="blendenpik")
-        if guarded
-        else guard.RecoveryReport.disabled("blendenpik")
-    )
-    stype = params.sketch_type or (
-        "CWT" if hasattr(A, "todense") else "FJLT"
-    )
-    gamma = params.gamma
-    R = None
-    for attempt in range(1, params.max_attempts + 1):
-        s = min(int(gamma * n), m)
-        SA = _sketch_once(A, s, stype, context)
-        R_try = jnp.linalg.qr(SA, mode="r")
-        # 1-norm triangular condition estimate of the preconditioner, the
-        # quantity the reference's retry loop consumes (``utcondest`` in
-        # ``build_precond``, accelerated_...Elemental.hpp:68-77, 225-246).
-        cond = _tri_condest(R_try)
-        R = R_try
-        good = np.isfinite(cond) and cond < threshold
-        report.record(
-            "initial" if attempt == 1 else "grow",
-            verdict=guard.OK if good else guard.RESKETCH,
-            cond=cond,
-            sketch_size=s,
-            detail="" if good else f"utcondest {cond:.3e} >= {threshold:.3e}",
+        guarded = guard.enabled()
+        report = (
+            guard.RecoveryReport(stage="blendenpik")
+            if guarded
+            else guard.RecoveryReport.disabled("blendenpik")
         )
-        if good:
-            report.recovered = attempt > 1
-            break
-        gamma *= 2  # re-sketch larger (accelerated_...hpp:241-252)
-    if not (np.isfinite(cond) and cond < threshold):
-        # All attempts produced a bad preconditioner: fall back to the
-        # exact SVD solver, as the reference does after its retry budget
-        # (``_alt_solver``, accelerated_...Elemental.hpp:247-257, 275-280).
-        from ..linalg.least_squares import exact_least_squares
+        stype = params.sketch_type or (
+            "CWT" if hasattr(A, "todense") else "FJLT"
+        )
+        gamma = params.gamma
+        R = None
+        for attempt in range(1, params.max_attempts + 1):
+            s = min(int(gamma * n), m)
+            with telemetry.span("blendenpik.sketch"):
+                SA = _sketch_once(A, s, stype, context)
+            with telemetry.span("blendenpik.factor"):
+                R_try = jnp.linalg.qr(SA, mode="r")
+            # 1-norm triangular condition estimate of the preconditioner, the
+            # quantity the reference's retry loop consumes (``utcondest`` in
+            # ``build_precond``, accelerated_...Elemental.hpp:68-77, 225-246).
+            # (its float() is where the host waits for sketch, QR and estimate)
+            with telemetry.span("blendenpik.condest"):
+                cond = _tri_condest(R_try)
+            R = R_try
+            good = np.isfinite(cond) and cond < threshold
+            report.record(
+                "initial" if attempt == 1 else "grow",
+                verdict=guard.OK if good else guard.RESKETCH,
+                cond=cond,
+                sketch_size=s,
+                detail="" if good else f"utcondest {cond:.3e} >= {threshold:.3e}",
+            )
+            if good:
+                report.recovered = attempt > 1
+                break
+            gamma *= 2  # re-sketch larger (accelerated_...hpp:241-252)
+        if not (np.isfinite(cond) and cond < threshold):
+            # All attempts produced a bad preconditioner: fall back to the
+            # exact SVD solver, as the reference does after its retry budget
+            # (``_alt_solver``, accelerated_...Elemental.hpp:247-257, 275-280).
+            from ..linalg.least_squares import exact_least_squares
 
-        A_d = A.todense() if hasattr(A, "todense") else A
-        X = exact_least_squares(A_d, B, alg="svd")
-        report.record(
-            "fallback", verdict=guard.FALLBACK, detail="exact svd solve"
-        )
-        report.recovered = True
-        info = {
-            "attempts": attempt,
-            "condest": cond,
-            "fallback": "svd",
-            "iterations": 0,
-            "recovery": report.to_dict(),
-        }
+            A_d = A.todense() if hasattr(A, "todense") else A
+            with telemetry.span("blendenpik.fallback"):
+                X = exact_least_squares(A_d, B, alg="svd")
+            report.record(
+                "fallback", verdict=guard.FALLBACK, detail="exact svd solve"
+            )
+            report.recovered = True
+            info = {
+                "attempts": attempt,
+                "condest": cond,
+                "fallback": "svd",
+                "iterations": 0,
+                "recovery": report.to_dict(),
+            }
+            telemetry.run_summary("blendenpik", info)
+            return X, info
+        precond = TriInversePrecond(R, lower=False)
+        X, info = lsqr(A, B, precond=precond, params=params.krylov)
+        if guarded:
+            with telemetry.span("guard.check"):  # the host waits for LSQR here
+                guard.check_finite(X, "blendenpik_lsqr", report=report)
+        info["attempts"] = attempt
+        info["condest"] = cond
+        info["recovery"] = report.to_dict()
         telemetry.run_summary("blendenpik", info)
         return X, info
-    precond = TriInversePrecond(R, lower=False)
-    X, info = lsqr(A, B, precond=precond, params=params.krylov)
-    if guarded:
-        guard.check_finite(X, "blendenpik_lsqr", report=report)
-    info["attempts"] = attempt
-    info["condest"] = cond
-    info["recovery"] = report.to_dict()
-    telemetry.run_summary("blendenpik", info)
-    return X, info
 
 
 def lsrn_least_squares(
@@ -166,41 +173,51 @@ def lsrn_least_squares(
     solve, the solution passes a finiteness sentinel, and
     ``info["recovery"]`` records the attempts.
     """
-    params = params or FasterLeastSquaresParams()
-    m, n = A.shape
-    s = min(int(params.gamma * n), m)
-    # LSRN wants a Gaussian-like sketch for its SVD preconditioner.
-    stype = params.sketch_type or (
-        "CWT" if hasattr(A, "todense") else "JLT"
-    )
-    guarded = guard.enabled()
-    report = (
-        guard.RecoveryReport(stage="lsrn")
-        if guarded
-        else guard.RecoveryReport.disabled("lsrn")
-    )
-    SA = _sketch_once(A, s, stype, context)
-    if guarded and not guard.tree_all_finite(SA):
-        # LSRN's SVD preconditioner absorbs ill conditioning by design, so
-        # the only sketch pathology worth guarding here is non-finiteness.
-        report.record(
-            "initial", verdict=guard.RESKETCH, sketch_size=s,
-            detail="non-finite sketch output",
+    with telemetry.span("lsrn"):
+        params = params or FasterLeastSquaresParams()
+        m, n = A.shape
+        s = min(int(params.gamma * n), m)
+        # LSRN wants a Gaussian-like sketch for its SVD preconditioner.
+        stype = params.sketch_type or (
+            "CWT" if hasattr(A, "todense") else "JLT"
         )
-        SA = _sketch_once(A, s, stype, guard.derived_context(context, 1))
-        report.record("resketch", verdict=guard.OK, sketch_size=s)
-        guard.check_finite(SA, "lsrn_sketch", report=report)
-        report.recovered = True
-    elif guarded:
-        report.record("initial", verdict=guard.OK, sketch_size=s)
-    _, sv, Vt = jnp.linalg.svd(SA, full_matrices=False)
-    eps = jnp.finfo(sv.dtype).eps
-    cutoff = sv[0] * eps * max(SA.shape)
-    sinv = jnp.where(sv > cutoff, 1.0 / sv, 0.0)
-    N = Vt.T * sinv[None, :]  # V·Σ⁻¹
-    X, info = lsqr(A, B, precond=MatPrecond(N), params=params.krylov)
-    if guarded:
-        guard.check_finite(X, "lsrn_lsqr", report=report)
-    info["recovery"] = report.to_dict()
-    telemetry.run_summary("lsrn", info)
-    return X, info
+        guarded = guard.enabled()
+        report = (
+            guard.RecoveryReport(stage="lsrn")
+            if guarded
+            else guard.RecoveryReport.disabled("lsrn")
+        )
+        with telemetry.span("lsrn.sketch"):
+            SA = _sketch_once(A, s, stype, context)
+        with telemetry.span("guard.check"):
+            bad_sketch = guarded and not guard.tree_all_finite(SA)
+        if bad_sketch:
+            # LSRN's SVD preconditioner absorbs ill conditioning by design, so
+            # the only sketch pathology worth guarding here is non-finiteness.
+            report.record(
+                "initial", verdict=guard.RESKETCH, sketch_size=s,
+                detail="non-finite sketch output",
+            )
+            with telemetry.span("lsrn.sketch"):
+                SA = _sketch_once(
+                    A, s, stype, guard.derived_context(context, 1)
+                )
+            report.record("resketch", verdict=guard.OK, sketch_size=s)
+            with telemetry.span("guard.check"):
+                guard.check_finite(SA, "lsrn_sketch", report=report)
+            report.recovered = True
+        elif guarded:
+            report.record("initial", verdict=guard.OK, sketch_size=s)
+        with telemetry.span("lsrn.factor"):
+            _, sv, Vt = jnp.linalg.svd(SA, full_matrices=False)
+            eps = jnp.finfo(sv.dtype).eps
+            cutoff = sv[0] * eps * max(SA.shape)
+            sinv = jnp.where(sv > cutoff, 1.0 / sv, 0.0)
+            N = Vt.T * sinv[None, :]  # V·Σ⁻¹
+        X, info = lsqr(A, B, precond=MatPrecond(N), params=params.krylov)
+        if guarded:
+            with telemetry.span("guard.check"):
+                guard.check_finite(X, "lsrn_lsqr", report=report)
+        info["recovery"] = report.to_dict()
+        telemetry.run_summary("lsrn", info)
+        return X, info

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import telemetry
 from ..core.params import Params
 from ..resilient.chunked import ChunkedSolver
 from .precond import IdPrecond
@@ -115,16 +116,23 @@ def _chunk_stepper(body, iter_lim: int, done_of=None):
 
     def step_chunk(s, num_iters: int):
         if not lifted:
-            closed, out_shape = jax.make_jaxpr(body, return_shape=True)(s)
+            with telemetry.span("krylov.lift"):
+                closed, out_shape = jax.make_jaxpr(body, return_shape=True)(s)
             lifted.extend((closed, jax.tree.structure(out_shape)))
-        return run(s, lifted[0].consts, num_iters=num_iters)
+        # trace, lower, cache key, fetch and enqueue of the segment
+        with telemetry.span("krylov.segment"):
+            return run(s, lifted[0].consts, num_iters=num_iters)
 
     return step_chunk
 
 
 def _one_shot(factory_state_solver, iter_lim: int):
     sol = factory_state_solver
-    return sol.extract_result(sol.step_chunk(sol.init_state(), max(iter_lim, 0)))
+    with telemetry.span("krylov.init"):  # eager: one pass over A
+        state = sol.init_state()
+    state = sol.step_chunk(state, max(iter_lim, 0))
+    with telemetry.span("krylov.result"):
+        return sol.extract_result(state)
 
 
 def lsqr_chunked(

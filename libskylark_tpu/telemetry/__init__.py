@@ -4,7 +4,7 @@ The observability layer the reference never had (its ``utility/timer.hpp``
 macros reduce wall timers over MPI ranks and nothing else): one
 process-wide :class:`Registry` of counters/gauges/histograms, nestable
 :func:`span` contexts (wall time under the ``PhaseTimer`` sync
-discipline, device regions via ``utils.profiling.annotate``), and a
+discipline, profiler regions via ``utils.profiling.region``), and a
 monotonically sequenced JSONL event sink — the *run ledger* — with the
 schema ``{ts, seq, pid, kind, name, attrs}``.
 
@@ -14,9 +14,16 @@ recovery-ladder attempts (``guard``), checkpoint save/restore
 (``resilient``), and per-chunk solver progress; every ``(x, info)``
 solver entrypoint closes its run with a :func:`run_summary` event.
 
-Gated by ``SKYLARK_TELEMETRY`` (default OFF, read per call): disabled,
-every entry point returns before allocating — runs are bit-identical to
-a build without this package.  ``SKYLARK_TELEMETRY_DIR`` (or
+What is gated and what is not.  :func:`span` ALWAYS opens the profiler
+annotation ``skylark:<name>`` (``utils.profiling.region``; ``PhaseTimer``
+phases open the same one), so any ``jax.profiler`` trace of a run shows
+the program's stages on the device's clock — ``skylark:<entry>`` around
+a public call, ``skylark:<layer>.<stage>`` inside it.  Everything else
+waits for ``SKYLARK_TELEMETRY`` (default OFF, read per call): disabled,
+a span is the bare annotation and every other entry point returns
+before allocating — no ledger, no registry write, no sync, no
+``jax.monitoring`` listener, no ``atexit`` hook; runs are bit-identical
+to a build without this package.  ``SKYLARK_TELEMETRY_DIR`` (or
 :func:`configure`, or the CLIs' ``--telemetry-dir``) points the ledger
 at a directory; without it events still count in the registry.
 
@@ -59,7 +66,7 @@ from .timeline import (
     timeline_tick,
     timeline_windows,
 )
-from .spans import NOOP_SPAN, Span, span
+from .spans import Span, span
 from .trace import (
     RECORDER,
     FlightRecorder,
@@ -108,7 +115,6 @@ __all__ = [
     "reset_timeline",
     "span",
     "Span",
-    "NOOP_SPAN",
     "snapshot",
     "run_summary",
     "report",

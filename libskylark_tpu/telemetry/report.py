@@ -5,7 +5,9 @@ used to be: the registry's metrics plus ``plans.stats()``, the prefetch
 overlap ratio (from the counters the streaming engine folds in when a
 pass closes), and the guard / checkpoint / policy counter groups (the
 policy group covers decisions made, escalations, and profile
-hits/misses — ``docs/autotuning.md``).
+hits/misses — ``docs/autotuning.md``), and the ``span.*`` counters summed
+per span name (calls, seconds, and the traces / lowerings / compiles JAX
+made while the span was open).
 
 ``report()`` is the multi-process reduction, and deliberately REUSES
 ``utils.timer.timer_report``'s gather contract: with
@@ -186,6 +188,17 @@ def snapshot(fleet: bool = False, root=None) -> dict:
         # Time-series ring counters (ticks) — present only once a
         # window closed.
         snap["timeline"] = timeline_counters
+    spans: dict = {}
+    for k, v in counters.items():
+        if k.startswith("span."):
+            # span.<name>.<what>; the name has dots of its own
+            name, _, what = k[len("span."):].rpartition(".")
+            spans.setdefault(name, {})[what] = v
+    if spans:
+        # Per span name: calls, seconds and what JAX built inside
+        # (traces, lowerings, compiles, cache_hits and their seconds) —
+        # "krylov.segment: 1 lowering a call" without a profiler.
+        snap["spans"] = spans
     return snap
 
 

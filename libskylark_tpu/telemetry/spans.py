@@ -1,20 +1,31 @@
-"""Nestable spans: wall time + device regions + ledger events.
+"""Nestable spans: profiler annotations always, wall time + ledger
+events when telemetry is on.
 
-A span is the telemetry analogue of one ``PhaseTimer`` phase, and keeps
-its sync discipline: assign the span handle's ``result`` inside the
-region and the exit path runs ``jax.block_until_ready`` on it before
-reading the clock, so the span measures DEVICE time, not dispatch time.
-Each span also opens a ``utils.profiling.annotate`` region, so an XProf
-trace captured around the run carries the same names as the ledger.
+Every span opens ``utils.profiling.region(name)`` — the
+``TraceAnnotation`` ``skylark:<name>`` — whether or not
+``SKYLARK_TELEMETRY`` is set, so a profiler trace captured around a run
+shows the program's stages on the device trace's clock.  With telemetry
+off that is ALL a span is: :func:`span` hands back the bare annotation —
+no event, no counter, no sync, no listener, nothing allocated beside it.
 
-Nesting is tracked per thread: every span records its parent's id (the
-``seq`` of the parent's ``span_start`` event) so the ledger reconstructs
-the span tree.  ``span(...)`` with telemetry disabled returns a shared
-no-op singleton — no allocation, no sync, no events.
+With telemetry on a span is the analogue of one ``PhaseTimer`` phase,
+and keeps its sync discipline: assign the span handle's ``result``
+inside the region and the exit path runs ``jax.block_until_ready`` on it
+before reading the clock, so the span measures DEVICE time, not dispatch
+time.  Nesting is tracked per thread: every span records its parent's
+id (the ``seq`` of the parent's ``span_start`` event) so the ledger
+reconstructs the span tree.  ``span_end`` also says what JAX built while
+the span was open — ``traces``/``trace_s`` (jaxpr traces),
+``lowerings``/``lower_s`` (jaxpr → MLIR), ``compiles``/``compile_s``
+(backend compile requests, persistent-cache fetches among them) and
+``cache_hits`` — from ``jax.monitoring``'s events; a key that would read
+0 is left out.  The listener is registered by the first enabled span,
+never at import.
 """
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 
@@ -25,25 +36,19 @@ from . import config
 from .ledger import event
 from .registry import REGISTRY
 
-__all__ = ["span", "Span", "NOOP_SPAN"]
+__all__ = ["span", "Span"]
 
 _LOCAL = threading.local()
 
-
-class _NoopSpan:
-    """Shared disabled-path span: accepts ``result`` assignment (ignored,
-    never synced) and nests freely."""
-
-    __slots__ = ("result",)
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NOOP_SPAN = _NoopSpan()
+# jax.monitoring duration events -> (count key, seconds key) of span_end
+_BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("traces", "trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("lowerings", "lower_s"),
+    "/jax/core/compile/backend_compile_duration": ("compiles", "compile_s"),
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_LISTEN_LOCK = threading.Lock()
+_listening = False
 
 
 def _stack() -> list:
@@ -51,6 +56,34 @@ def _stack() -> list:
     if stack is None:
         stack = _LOCAL.spans = []
     return stack
+
+
+def _on_duration(name: str, secs: float, **_):
+    keys = _BUILD_EVENTS.get(name)
+    if keys is not None:
+        # JAX traces, lowers and compiles on the calling thread: the open
+        # spans of this thread are the ones the work happened under.
+        for sp in _stack():
+            sp.built[keys[0]] += 1
+            sp.built[keys[1]] += secs
+
+
+def _on_event(name: str, **_):
+    if name == _CACHE_HIT_EVENT:
+        for sp in _stack():
+            sp.built["cache_hits"] += 1
+
+
+def _listen() -> None:
+    """Register the ``jax.monitoring`` listeners, once a process."""
+    global _listening
+    if _listening:
+        return
+    with _LISTEN_LOCK:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            jax.monitoring.register_event_listener(_on_event)
+            _listening = True
 
 
 class Span:
@@ -64,8 +97,10 @@ class Span:
         self.result = None
         self.id = None
         self.seconds = None
+        self.built = collections.Counter()  # what JAX built inside
 
     def __enter__(self):
+        _listen()
         stack = _stack()
         start_attrs = dict(self.attrs)
         if stack:
@@ -74,14 +109,14 @@ class Span:
         self._t0 = time.perf_counter()
         self.id = event("span_start", self.name, start_attrs)
         stack.append(self)
-        self._region = profiling.annotate(self.name)
+        self._region = profiling.region(self.name)
         self._region.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self._region.__exit__(exc_type, exc, tb)
         if self.result is not None:
             jax.block_until_ready(self.result)
+        self._region.__exit__(exc_type, exc, tb)
         self.seconds = time.perf_counter() - self._t0
         stack = _stack()
         if stack and stack[-1] is self:
@@ -91,6 +126,9 @@ class Span:
         end_attrs = dict(self.attrs)
         end_attrs["span"] = self.id
         end_attrs["seconds"] = round(self.seconds, 6)
+        for key, value in self.built.items():
+            REGISTRY.inc(f"span.{self.name}.{key}", value)
+            end_attrs[key] = round(value, 6)
         if exc_type is not None:
             end_attrs["error"] = exc_type.__name__
         event("span_end", self.name, end_attrs)
@@ -106,8 +144,10 @@ def span(name: str, **attrs):
             sp.result = acc        # blocked on at exit (PhaseTimer rule)
             sp.attrs["rows"] = k   # lands on the span_end event
 
-    Disabled (``SKYLARK_TELEMETRY=0``): returns the shared no-op span.
+    Disabled (``SKYLARK_TELEMETRY=0``): returns the bare profiler
+    annotation ``skylark:<name>`` — ``result`` may still be assigned to
+    it (never synced); ``attrs`` is the enabled span's alone.
     """
     if not config.enabled():
-        return NOOP_SPAN
+        return profiling.region(name)
     return Span(name, attrs)

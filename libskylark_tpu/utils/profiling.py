@@ -4,6 +4,14 @@ wall timers").
 
 ``PhaseTimer`` (``utils.timer``) covers the wall-clock side; this module
 wraps ``jax.profiler`` for op-level traces viewable in XProf/TensorBoard.
+
+This is the one place that builds a ``TraceAnnotation``:
+``telemetry.span`` and ``PhaseTimer.phase`` both open :func:`region`, so
+the program's spans lie on the profiler's clock, beside the device's
+lines, under one naming rule — ``skylark:<entry>`` for the span of a
+whole public call, ``skylark:<layer>.<stage>`` for a stage of it
+(``docs/observability.md`` lists them).  With no profiler session an
+annotation costs a fraction of a microsecond and leaves nothing behind.
 """
 
 from __future__ import annotations
@@ -12,7 +20,9 @@ from contextlib import contextmanager
 
 import jax
 
-__all__ = ["trace", "annotate"]
+__all__ = ["trace", "annotate", "region"]
+
+PREFIX = "skylark:"  # every span the program opens, and nothing else
 
 
 @contextmanager
@@ -35,3 +45,9 @@ def annotate(name: str):
     """Named region inside a trace (≙ the reference's per-phase timer
     labels); usable as decorator or context manager."""
     return jax.profiler.TraceAnnotation(name)
+
+
+def region(name: str):
+    """The program's span ``name`` as it stands in a trace:
+    ``annotate("skylark:" + name)``."""
+    return annotate(PREFIX + name)

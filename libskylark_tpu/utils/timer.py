@@ -12,6 +12,9 @@ deliberately takes a boolean, not a mesh) and prints the same
 three-column reduction.  Without it the report stays per-process.
 Device work is made observable by assigning the phase handle's
 ``result`` (blocked on at phase exit — the reference's barrier).
+Every phase also opens ``profiling.region(name)``, the annotation
+``telemetry.span`` opens, so a profiler trace shows ``skylark:sweep``
+around the sweep's device work on the same clock.
 """
 
 from __future__ import annotations
@@ -22,6 +25,8 @@ from contextlib import contextmanager
 
 import jax
 import numpy as np
+
+from . import profiling
 
 __all__ = ["PhaseTimer", "timer_report", "aggregate_report"]
 
@@ -54,14 +59,15 @@ class PhaseTimer:
     @contextmanager
     def phase(self, name: str):
         handle = _PhaseHandle()
-        t0 = time.perf_counter()
-        try:
-            yield handle
-        finally:
-            if self.sync and handle.result is not None:
-                jax.block_until_ready(handle.result)
-            self.totals[name] += time.perf_counter() - t0
-            self.counts[name] += 1
+        with profiling.region(name):
+            t0 = time.perf_counter()
+            try:
+                yield handle
+            finally:
+                if self.sync and handle.result is not None:
+                    jax.block_until_ready(handle.result)
+                self.totals[name] += time.perf_counter() - t0
+                self.counts[name] += 1
 
     def report(self, distributed: bool = False) -> str:
         return timer_report(self.totals, self.counts, distributed=distributed)

@@ -1,0 +1,243 @@
+"""The program's stage spans, read back from a profiler trace on the CPU.
+
+``telemetry.span`` opens the annotation ``skylark:<name>`` whether or not
+``SKYLARK_TELEMETRY`` is set, so a ``jax.profiler`` trace around a solve
+shows every stage on the device trace's clock.  Here: each measured path
+runs once under ``jax.profiler.start_trace`` with telemetry unset; every
+span of the path is there the right number of times, the stage spans lie
+inside their entry span, and the answer is bit-identical to the one
+computed with no profiler session.  With telemetry on, the ledger's
+``span_end`` says what JAX built inside the span.
+
+All in one file: a process has one profiler session at a time, and the
+suite gives a file to one worker.
+"""
+
+import atexit
+import collections
+import glob
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax._src import monitoring
+
+from libskylark_tpu import SketchContext, ml, telemetry
+from libskylark_tpu.linalg import approximate_least_squares
+from libskylark_tpu.solvers import faster_least_squares, lsrn_least_squares
+
+pytestmark = pytest.mark.telemetry
+
+M, N = 2048, 16
+ROWS, D, S, T, PANEL = 512, 8, 32, 2, 128
+
+
+def _ls_problem():
+    rng = np.random.default_rng(5)
+    A = jnp.asarray(rng.standard_normal((M, N)), jnp.float32)
+    return A, A @ jnp.ones((N,), jnp.float32)
+
+
+def _blendenpik():
+    A, b = _ls_problem()
+    return faster_least_squares(A, b, SketchContext(seed=11))[0]
+
+
+def _lsrn():
+    A, b = _ls_problem()
+    return lsrn_least_squares(A, b, SketchContext(seed=11))[0]
+
+
+def _sketch_solve():
+    A, b = _ls_problem()
+    return approximate_least_squares(A, b, SketchContext(seed=11))
+
+
+def _block_fn(start, rows, X):
+    return jax.lax.dynamic_slice_in_dim(X, start, rows, 0)
+
+
+def _krr_train():
+    rng = np.random.default_rng(7)
+    X = jnp.asarray(rng.standard_normal((ROWS, D)), jnp.float32)
+    Y = jnp.asarray(rng.standard_normal((ROWS, T)), jnp.float32)
+    model = ml.streaming_kernel_ridge(
+        ml.GaussianKernel(D, sigma=3.0), _block_fn, (ROWS, D), Y, 1.0, S,
+        SketchContext(seed=3), ml.KrrParams(max_split=2 * S, iter_lim=2),
+        block_rows=PANEL, feature_dtype=jnp.float32, block_args=(X,),
+    )
+    return model.W
+
+
+# path -> (call, entry span, {stage span: times a call})
+PATHS = {
+    "blendenpik": (_blendenpik, "blendenpik", {
+        "blendenpik.sketch": 1, "blendenpik.factor": 1, "blendenpik.condest": 1,
+        "krylov.init": 1, "krylov.lift": 1, "krylov.segment": 1,
+        "krylov.result": 1, "guard.check": 1}),
+    "lsrn": (_lsrn, "lsrn", {
+        "lsrn.sketch": 1, "lsrn.factor": 1, "krylov.init": 1, "krylov.lift": 1,
+        "krylov.segment": 1, "krylov.result": 1, "guard.check": 2}),
+    # A and b are sketched by one plan each; the guard certifies attempt 0
+    "sketch_solve": (_sketch_solve, "sketch_solve", {
+        "plans.lookup": 2, "sketch.apply": 2, "guard.certify": 1,
+        "sketch_solve.small": 1, "guard.check": 1}),
+    # one feature chunk, two sweeps: the Gram and its factor in sweep 0 only
+    "krr_train": (_krr_train, "krr_train", {
+        "krr.programs": 1, "krr.gram": 1, "krr.factor": 1, "krr.zr": 2,
+        "krr.solve": 2, "krr.apply_delta": 2, "krr.converge": 4}),
+}
+PHASES = {"krr_train": {"sweep0": 1, "sweep": 1}}  # PhaseTimer's, no dot: no stage
+
+_TRACED: dict = {}
+
+
+def _spans_of(trace_dir):
+    """``(name, start, end)`` of every ``skylark:`` event of the trace."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    return [
+        (ev.name[len("skylark:"):], ev.start_ns, ev.start_ns + ev.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("skylark:")]
+
+
+def under_profiler(call, trace_dir):
+    """``call()`` under a profiler session (the Python tracer off: the
+    spans are ``TraceMe`` events) and the spans the trace then holds."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        out = call()
+    finally:
+        jax.profiler.stop_trace()
+    return out, _spans_of(str(trace_dir))
+
+
+def traced(path, tmp_path_factory):
+    """One warm call with no profiler, then one under a session."""
+    if path not in _TRACED:
+        call = PATHS[path][0]
+        plain = np.asarray(call())
+        under, spans = under_profiler(
+            call, tmp_path_factory.mktemp("trace_" + path))
+        _TRACED[path] = (plain, np.asarray(under), spans)
+    return _TRACED[path]
+
+
+@pytest.fixture(autouse=True)
+def _telemetry_unset(monkeypatch):
+    monkeypatch.delenv("SKYLARK_TELEMETRY", raising=False)
+    monkeypatch.delenv("SKYLARK_TELEMETRY_DIR", raising=False)
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_every_span_is_in_the_trace_once_a_call(path, tmp_path_factory):
+    _, entry, stages = PATHS[path]
+    got = collections.Counter(name for name, _, _ in traced(path, tmp_path_factory)[2])
+    assert got == {entry: 1, **stages, **PHASES.get(path, {})}
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_stage_spans_lie_inside_their_entry_span(path, tmp_path_factory):
+    entry = PATHS[path][1]
+    spans = traced(path, tmp_path_factory)[2]
+    (lo, hi), = [(s, e) for name, s, e in spans if name == entry]
+    for name, s, e in spans:
+        assert lo <= s and e <= hi, name
+        assert ("." in name) == (name in PATHS[path][2]), name
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_the_answer_under_a_profiler_session_is_bit_identical(path, tmp_path_factory):
+    plain, under, _ = traced(path, tmp_path_factory)
+    assert plain.tobytes() == under.tobytes()
+
+
+def test_a_second_attempt_opens_the_attempt_spans_again(tmp_path_factory):
+    """Per attempt: a threshold no sketch can meet makes Blendenpik
+    re-sketch ``max_attempts`` times and fall back."""
+    from libskylark_tpu.solvers import FasterLeastSquaresParams
+
+    A, b = _ls_problem()
+    (_, info), spans = under_profiler(
+        lambda: faster_least_squares(
+            A, b, SketchContext(seed=11),
+            FasterLeastSquaresParams(max_attempts=2, cond_threshold=1.0)),
+        tmp_path_factory.mktemp("trace_attempts"))
+    assert info["fallback"] == "svd"
+    got = collections.Counter(name for name, _, _ in spans)
+    assert got == {"blendenpik": 1, "blendenpik.sketch": 2, "blendenpik.factor": 2,
+                   "blendenpik.condest": 2, "blendenpik.fallback": 1}
+
+
+def test_with_telemetry_unset_a_solve_leaves_nothing_behind():
+    """No ledger, no listener, no ``atexit`` hook, no registry write."""
+    telemetry.reset()
+    before = (len(monitoring.get_event_duration_listeners()),
+              len(monitoring.get_event_listeners()), atexit._ncallbacks())
+    _blendenpik()
+    assert before == (len(monitoring.get_event_duration_listeners()),
+                      len(monitoring.get_event_listeners()), atexit._ncallbacks())
+    assert telemetry.ledger_path() is None
+    assert telemetry.snapshot()["counters"] == {}
+
+
+def test_with_telemetry_on_span_end_says_what_jax_built_inside(tmp_path, monkeypatch):
+    """A warm call still re-traces and re-lowers the LSQR segment (a fresh
+    ``jax.jit`` in every call of ``_chunk_stepper``): the ledger says so
+    without a profiler.  ``snapshot()`` sums it by span name."""
+    off = _blendenpik()
+    monkeypatch.setenv("SKYLARK_TELEMETRY", "1")
+    telemetry.configure(str(tmp_path))
+    telemetry.reset()
+    try:
+        on = [_blendenpik(), _blendenpik()]
+        telemetry.flush()
+        with open(telemetry.ledger_path()) as fh:
+            events = [json.loads(line) for line in fh]
+        snap = telemetry.snapshot()
+    finally:
+        telemetry.close()
+        telemetry.configure(None)
+        telemetry.reset()
+    assert all(np.asarray(x).tobytes() == np.asarray(off).tobytes() for x in on)
+    ends = [e["attrs"] for e in events
+            if e["kind"] == "span_end" and e["name"] == "krylov.segment"]
+    assert len(ends) == 2
+    warm = ends[1]
+    assert warm["lowerings"] == 1 and warm["lower_s"] > 0
+    assert warm["traces"] >= 1 and warm["trace_s"] > 0
+    # the entry span holds its stages' builds
+    entry = [e["attrs"] for e in events
+             if e["kind"] == "span_end" and e["name"] == "blendenpik"][1]
+    assert entry["lowerings"] >= warm["lowerings"]
+    starts = {e["seq"]: e for e in events if e["kind"] == "span_start"}
+    seg = [e for e in events
+           if e["kind"] == "span_start" and e["name"] == "krylov.segment"][1]
+    assert starts[seg["attrs"]["parent"]]["name"] == "blendenpik"
+    per_name = snap["spans"]["krylov.segment"]
+    assert per_name["calls"] == 2 and per_name["lowerings"] == 2
+    assert per_name["lower_s"] == pytest.approx(
+        sum(e["lower_s"] for e in ends), abs=1e-5)
+    assert "lowerings" not in snap["spans"].get("guard.check", {})
+
+
+def test_the_one_place_that_builds_an_annotation():
+    """Spans and ``PhaseTimer`` both go through ``utils.profiling``; no
+    other module of the library constructs a ``TraceAnnotation``."""
+    import libskylark_tpu
+
+    root = os.path.dirname(libskylark_tpu.__file__)
+    holders = []
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path) as fh:
+            if "TraceAnnotation(" in fh.read():
+                holders.append(os.path.relpath(path, root))
+    assert holders == [os.path.join("utils", "profiling.py")]

@@ -6,8 +6,11 @@ squares pass with an injected sketch fault, checked against its ledger.
 import json
 import os
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src import monitoring
 
 from libskylark_tpu import plans, telemetry
 from libskylark_tpu.core.context import SketchContext
@@ -91,13 +94,20 @@ class TestRegistry:
             telemetry.inc("a.calls")
             telemetry.set_gauge("g", 9)
             telemetry.observe("h", 9)
-            assert telemetry.span("x") is telemetry.NOOP_SPAN
+            # a disabled span is the bare profiler annotation: no event,
+            # no counter, no sync of an assigned result, no listener
+            before = len(monitoring.get_event_duration_listeners())
+            with telemetry.span("x", k=1) as sp:
+                sp.result = jnp.ones(3)
+            assert type(sp) is jax.profiler.TraceAnnotation
+            assert len(monitoring.get_event_duration_listeners()) == before
             assert telemetry.event("k", "n", {"a": 1}) is None
             assert telemetry.emit("k", "n", a=1) is None
             assert telemetry.run_summary("n", {"a": 1}) is None
             snap = telemetry.snapshot()
             assert snap["counters"]["a.calls"] == 1
             assert "g" not in snap["gauges"] and "h" not in snap["histograms"]
+            assert "span.x.calls" not in snap["counters"] and "spans" not in snap
         finally:
             telemetry.reset()
 
