@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import guard
+from .. import guard, telemetry
 from ..core.context import SketchContext
 from ..core.matrices import gaussian_matrix
 from ..core.params import Params
@@ -96,6 +96,15 @@ def _sketch_size(k: int, params: SVDParams, n: int, m: int | None = None):
     return k, max(s, k)
 
 
+@jax.jit
+def _project(A, Q):
+    """``Aᵀ·Q`` at full precision, A's rows contracted where they lie.  One
+    program: an eager ``A.T`` is a transposed copy of A on every chip
+    (5 GB a chip at 10⁷ × 512, which the operand's own 5 GB, the basis
+    and U leave no room for)."""
+    return jnp.dot(A.T, Q, precision="highest")
+
+
 def power_iteration(A, Q, num_iterations: int, orthogonalize: bool = True):
     """Subspace iteration ``Q <- orth((A·Aᵀ)·Q)``, repeated.
 
@@ -137,11 +146,12 @@ def approximate_svd_chunked(
 
     def init_state():
         # Q = A·Omegaᵀ — rowwise JLT sketch (nla/svd.hpp:255-257).
-        omega = JLT(n, s, context)
-        return dict(
-            it=jnp.zeros((), jnp.int32),
-            Y=omega.apply(A, Dimension.ROWWISE),
-        )
+        with telemetry.span("svd.sketch"):
+            omega = JLT(n, s, context)
+            return dict(
+                it=jnp.zeros((), jnp.int32),
+                Y=omega.apply(A, Dimension.ROWWISE),
+            )
 
     # A enters as an ARGUMENT (dense array or BCOO pytree) so jit
     # references a device buffer instead of baking A into the program.
@@ -159,29 +169,32 @@ def approximate_svd_chunked(
         return lax.while_loop(cond, body, st)
 
     def step_chunk(st, num_iters: int):
-        return _chunk(st, A, num_iters)
+        # _chunk is a fresh jax.jit per solver: traced and lowered here
+        with telemetry.span("svd.power"):
+            return _chunk(st, A, num_iters)
 
     def extract_result(st):
         Y = st["Y"]
-        # The power-iteration body already ends orthonormalized unless
-        # skip_qr, so only orthonormalize here when the loop didn't.
-        Q = Y if (niter > 0 and orthogonalize) else _orth(Y)
+        with telemetry.span("svd.project"):
+            # The power-iteration body already ends orthonormalized unless
+            # skip_qr, so only orthonormalize here when the loop didn't.
+            Q = Y if (niter > 0 and orthogonalize) else _orth(Y)
 
-        # B = Aᵀ·Q (n, s); small SVD; rotate back (nla/svd.hpp:266-285).
-        # Both products pinned: the MXU default would put ~2e-3 (bf16)
-        # error into the singular values (via B) and U's orthogonality
-        # (via the rotation) on hardware.  The power-iteration sweeps keep
-        # the fast default — they only steer the subspace.
-        # (BCOO has no precision knob and does not ride the MXU bf16 path —
-        # its matmul keeps the sparse dispatch.)
-        AtQ = A.T @ Q if hasattr(A, "todense") else jnp.dot(
-            A.T, Q, precision="highest"
-        )
-        B = fully_replicated(AtQ)
-        W, sv, Zt = jnp.linalg.svd(B, full_matrices=False)  # B = W·sv·Zt
-        # A ≈ Q·Bᵀ = (Q·Ztᵀ)·diag(sv)·Wᵀ
-        U = jnp.dot(Q, Zt.T, precision="highest")
-        return U[:, :k], sv[:k], W[:, :k]
+            # B = Aᵀ·Q (n, s); small SVD; rotate back (nla/svd.hpp:266-285).
+            # Both products pinned: the MXU default would put ~2e-3 (bf16)
+            # error into the singular values (via B) and U's orthogonality
+            # (via the rotation) on hardware.  The power-iteration sweeps
+            # keep the fast default — they only steer the subspace.
+            # (BCOO has no precision knob and does not ride the MXU bf16
+            # path — its matmul keeps the sparse dispatch.)
+            AtQ = A.T @ Q if hasattr(A, "todense") else _project(A, Q)
+            B = fully_replicated(AtQ)
+        with telemetry.span("svd.small"):
+            W, sv, Zt = jnp.linalg.svd(B, full_matrices=False)  # B = W·sv·Zt
+        with telemetry.span("svd.rotate"):
+            # A ≈ Q·Bᵀ = (Q·Ztᵀ)·diag(sv)·Wᵀ
+            U = jnp.dot(Q, Zt.T, precision="highest")
+            return U[:, :k], sv[:k], W[:, :k]
 
     return ChunkedSolver(
         init_state=init_state,
@@ -214,9 +227,23 @@ def approximate_svd(
     (fresh-seed resketch → grown oversampling → dense ``jnp.linalg.svd``
     fallback).  Attempt 0 reuses the caller's context, so healthy runs are
     bit-identical to the unguarded path.  ``return_info=True`` returns
-    ``((U, s, V), info)`` with the attempts in ``info["recovery"]``.
+    ``((U, s, V), info)`` with the attempts in ``info["recovery"]`` and
+    their count in ``info["attempts"]`` (1 on a sound run).
     """
-    params = params or SVDParams()
+    with telemetry.span("randomized_svd"):
+        out, report = _guarded_svd(A, rank, context, params or SVDParams())
+    if return_info:
+        # attempts: the factorizations this call ran (1 on a sound run)
+        return out, {
+            "attempts": max(len(report.attempts), 1),
+            "recovery": report.to_dict(),
+        }
+    return out
+
+
+def _guarded_svd(A, rank, context, params):
+    """``((U, s, V), report)``: one factorization, then up the guard's
+    ladder while its certificate fails."""
 
     def run(ctx, p):
         sol = approximate_svd_chunked(A, rank, ctx, p)
@@ -226,16 +253,13 @@ def approximate_svd(
     # Under an enclosing jit trace the host-side certificate reads and
     # ladder control flow cannot run — emit the plain unguarded graph.
     if not guard.enabled() or guard.is_traced(A):
-        out = run(context, params)
-        if return_info:
-            report = guard.RecoveryReport.disabled("randomized_svd")
-            return out, {"recovery": report.to_dict()}
-        return out
+        return run(context, params), guard.RecoveryReport.disabled(
+            "randomized_svd"
+        )
 
     m, n = A.shape
     report = guard.RecoveryReport(stage="randomized_svd")
     retries = guard.max_retries()
-    out = None
     for i in range(retries + 1):
         if i == 0:
             action, ctx, p = "initial", context, params
@@ -251,7 +275,9 @@ def approximate_svd(
                 + rank * (2 ** (i - 1)),
             )
         U, sv, V = run(ctx, p)
-        cert = guard.certify_svd(A, U, sv, V)
+        # the certificate's three host reads: the host waits for U here
+        with telemetry.span("guard.certify"):
+            cert = guard.certify_svd(A, U, sv, V)
         _, width = _sketch_size(rank, p, n, m)
         report.record(
             action, verdict=cert.verdict, detail=cert.detail,
@@ -259,19 +285,14 @@ def approximate_svd(
         )
         if cert.ok:
             report.recovered = i > 0
-            out = (U, sv, V)
-            break
-    if out is None:
-        Ad = A.todense() if hasattr(A, "todense") else A
-        Uf, svf, Vtf = jnp.linalg.svd(jnp.asarray(Ad), full_matrices=False)
-        out = (Uf[:, :rank], svf[:rank], Vtf[:rank].T)
-        report.record(
-            "fallback", verdict=guard.FALLBACK, detail="dense jnp.linalg.svd"
-        )
-        report.recovered = True
-    if return_info:
-        return out, {"recovery": report.to_dict()}
-    return out
+            return (U, sv, V), report
+    Ad = A.todense() if hasattr(A, "todense") else A
+    Uf, svf, Vtf = jnp.linalg.svd(jnp.asarray(Ad), full_matrices=False)
+    report.record(
+        "fallback", verdict=guard.FALLBACK, detail="dense jnp.linalg.svd"
+    )
+    report.recovered = True
+    return (Uf[:, :rank], svf[:rank], Vtf[:rank].T), report
 
 
 def approximate_symmetric_svd(
