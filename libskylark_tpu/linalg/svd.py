@@ -105,6 +105,30 @@ def _project(A, Q):
     return jnp.dot(A.T, Q, precision="highest")
 
 
+@partial(jax.jit, static_argnames=("orthogonalize",))
+def _chunk(st, A, num_iters, niter, *, orthogonalize: bool):
+    """The power-sweep segment: at most ``num_iters`` sweeps
+    ``Y <- orth(A·(Aᵀ·Y))`` from ``st = {"it", "Y"}``, none past sweep
+    ``niter``.
+
+    Built once per (shapes, dtypes, dense or BCOO, ``orthogonalize``) and
+    dispatched from ``jax.jit``'s own in-memory cache after that: the
+    budget is two scalars, so one executable serves every chunk length and
+    every ``num_iterations``.  A is an argument (dense array or BCOO
+    pytree), a device buffer the program references and never a literal in
+    it, and nothing that outlives the call holds A or Y."""
+    stop = jnp.minimum(st["it"] + num_iters, niter)
+
+    def cond(c):
+        return c["it"] < stop
+
+    def body(c):
+        Y = A @ (A.T @ c["Y"])
+        return dict(it=c["it"] + 1, Y=_orth(Y) if orthogonalize else Y)
+
+    return lax.while_loop(cond, body, st)
+
+
 def power_iteration(A, Q, num_iterations: int, orthogonalize: bool = True):
     """Subspace iteration ``Q <- orth((A·Aᵀ)·Q)``, repeated.
 
@@ -153,25 +177,11 @@ def approximate_svd_chunked(
                 Y=omega.apply(A, Dimension.ROWWISE),
             )
 
-    # A enters as an ARGUMENT (dense array or BCOO pytree) so jit
-    # references a device buffer instead of baking A into the program.
-    @partial(jax.jit, static_argnames=("num_iters",))
-    def _chunk(st, A, num_iters: int):
-        stop = jnp.minimum(st["it"] + num_iters, niter)
-
-        def cond(c):
-            return c["it"] < stop
-
-        def body(c):
-            Y = A @ (A.T @ c["Y"])
-            return dict(it=c["it"] + 1, Y=_orth(Y) if orthogonalize else Y)
-
-        return lax.while_loop(cond, body, st)
-
     def step_chunk(st, num_iters: int):
-        # _chunk is a fresh jax.jit per solver: traced and lowered here
+        # the first call at a shape: trace, lower, cache key, fetch; every
+        # later one: a dispatch from jit's cache
         with telemetry.span("svd.power"):
-            return _chunk(st, A, num_iters)
+            return _chunk(st, A, num_iters, niter, orthogonalize=orthogonalize)
 
     def extract_result(st):
         Y = st["Y"]

@@ -3,19 +3,23 @@ the CPU: ``svd.sketch``, ``svd.power``, ``svd.project``, ``svd.small``,
 ``svd.rotate`` open once a call and in that order, inside the entry span
 ``randomized_svd``; ``guard.certify`` follows them; a ladder's second
 attempt opens them again; ``info["attempts"]`` counts the factorizations.
+With ``SKYLARK_TELEMETRY`` on, the ledger says that the sweep segment is
+built by the first call at a shape and by no later one.
 
 A file of its own: a process has one profiler session at a time, and the
 suite gives a file to one worker (``tests/test_stage_spans.py`` holds the
 solvers' and the trainer's).
 """
 
+import json
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from test_stage_spans import under_profiler  # the suite's one trace-and-read helper
 
-from libskylark_tpu import SketchContext, guard
-from libskylark_tpu.linalg import SVDParams, approximate_svd
+from libskylark_tpu import SketchContext, guard, telemetry
+from libskylark_tpu.linalg import SVDParams, approximate_svd, svd
 
 pytestmark = pytest.mark.telemetry
 
@@ -98,3 +102,46 @@ def test_with_the_guard_off_one_factorization_and_no_certificate(tmp_path_factor
                                       tmp_path_factory.mktemp("svd_unguarded"))
     assert [name for name, _, _ in spans] == ["randomized_svd", *STAGES]
     assert info["attempts"] == 1 and info["recovery"]["guarded"] is False
+
+
+def test_with_telemetry_on_only_the_cold_call_builds_under_svd_power(tmp_path, monkeypatch):
+    """The sweep segment is the module-level ``svd._chunk``: traced and
+    lowered by the first call at a shape, dispatched from ``jax.jit``'s
+    cache by every later one.  ``snapshot()`` sums it by span name, so the
+    segment's hit share is 1 - lowerings / calls of ``svd.power``."""
+    A = _operand()
+    off = _factor(A)
+    svd._chunk.clear_cache()  # the first call below is the cold one
+    monkeypatch.setenv("SKYLARK_TELEMETRY", "1")
+    telemetry.configure(str(tmp_path))
+    telemetry.reset()
+    try:
+        on = [_factor(A), _factor(A), _factor(A)]
+        telemetry.flush()
+        with open(telemetry.ledger_path()) as fh:
+            events = [json.loads(line) for line in fh]
+        snap = telemetry.snapshot()
+    finally:
+        telemetry.close()
+        telemetry.configure(None)
+        telemetry.reset()
+    for (U, s, V), info in on:
+        assert info == off[1]
+        for a, b in zip((U, s, V), off[0], strict=True):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    ends = {stage: [e["attrs"] for e in events
+                    if e["kind"] == "span_end" and e["name"] == stage]
+            for stage in ["randomized_svd", *STAGES]}
+    assert all(len(v) == 3 for v in ends.values())  # once a call, every call
+    cold, *warm = ends["svd.power"]
+    assert cold["lowerings"] == 1 and cold["lower_s"] > 0
+    assert cold["traces"] >= 1 and cold["trace_s"] > 0
+    for attrs in warm:
+        assert not {"lowerings", "traces", "compiles"} & set(attrs)
+    # the entry span holds its stages' builds; a warm call holds none at all
+    assert ends["randomized_svd"][0]["lowerings"] >= 1
+    for attrs in ends["randomized_svd"][1:]:
+        assert "lowerings" not in attrs and "traces" not in attrs
+    power = snap["spans"]["svd.power"]
+    assert power["calls"] == 3 and power["lowerings"] == 1
+    assert power["lower_s"] == pytest.approx(cold["lower_s"], abs=1e-5)
