@@ -383,9 +383,7 @@ def phase_sketch(cfg, seed, counter, rehearse):
         del out, ref, M, host
         gc.collect()
 
-        # Kernel self-checks, compiled, against XLA on random data.  The
-        # flat two-pass scatter and the sampled-FJLT kernel are on no
-        # default route (hash._segment_sum, FJLT._apply_pallas).
+        # Kernel self-checks, compiled, against XLA on random data.
         interp = rehearse
         ph.check("window_self_check",
                  pallas_window.self_check(interpret=interp), 1e-5)

@@ -11,7 +11,7 @@ XLA's excess-precision rules elide ``f32→bf16→f32`` convert pairs (the
 upcast-after-downcast is "at least as precise", so the compiler drops
 it), which silently turns ``x - bf16(x)`` into zero on TPU and collapses
 an astype-based split to single-bf16 accuracy — measured 1.6e-3 max-rel
-on hardware vs 8e-8 for this formulation (tests/test_pallas_hw.py).
+on hardware vs 8e-8 for this formulation (tests/_hw_guards.py).
 """
 
 from __future__ import annotations
@@ -19,24 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["bf16_split3", "f32_accumulable", "fp8_dtype", "fp8_available"]
-
-
-def fp8_dtype():
-    """The fp8 sketch-apply element type — e4m3 (4 exponent / 3 mantissa
-    bits: the accuracy-side fp8, vs e5m2's range-side) — or ``None`` on
-    JAX builds without fp8 support.  MXU fp8 matmuls accumulate in f32,
-    so the precision-ladder contract (narrow operands, f32 accumulate,
-    guard-certified result) carries down from bf16 unchanged; only the
-    operand rounding gets coarser."""
-    return getattr(jnp, "float8_e4m3fn", None)
-
-
-def fp8_available() -> bool:
-    """True when this JAX build can represent e4m3 at all (the ladder's
-    existence check; whether the BACKEND can matmul it profitably is the
-    policy layer's call — ``policy.config.fp8_allowed``)."""
-    return fp8_dtype() is not None
+__all__ = ["bf16_split3", "f32_accumulable"]
 
 
 def f32_accumulable(dtype, *, demote_f64: bool = False) -> bool:
@@ -47,10 +30,10 @@ def f32_accumulable(dtype, *, demote_f64: bool = False) -> bool:
     type, and usually much better).  f64 qualifies only when the caller
     explicitly accepts the demotion (``demote_f64=True``, i.e. a
     force-enabled kernel): x64 parity runs must keep the XLA
-    full-precision lowering by default.  This is the shared dtype gate
-    of the Pallas scatter family (``sketch/pallas_scatter.py``,
-    ``sketch/pallas_window.py``) — the precision ladders hand out bf16
-    operands and previously forced every hash scatter back to XLA."""
+    full-precision lowering by default.  This is the dtype gate of the
+    Pallas window scatter (``sketch/pallas_window.py``) — the precision
+    ladder hands out bf16 operands, which would otherwise force every
+    hash scatter back to XLA."""
     dt = jnp.dtype(dtype)
     if dt in (
         jnp.dtype(jnp.float32),

@@ -7,10 +7,8 @@ bitwise contract:
 
 - :func:`streamed_adjacency_sketch` folds COO edge blocks (from
   ``io.stream_arc_list`` or :func:`graph_block_source`) into ``S·A``
-  through the per-hash ``segment_sum`` scatter — the same
-  ``_segment_sum`` dispatcher the in-core BCOO apply uses, so the TPU
-  ``pallas_scatter`` route engages per the coverage matrix wherever it
-  does in-core.
+  through the per-hash ``jax.ops.segment_sum`` scatter the in-core BCOO
+  apply uses.
 - :func:`incore_adjacency_sketch` is the reference:
   ``S.apply(A_bcoo, dense_output=True)``.
 - :func:`chained_adjacency_sketch` composes ``S₂·(S₁·A)`` either
@@ -89,9 +87,10 @@ def adjacency_sketch_fold(S, ncols: int, dtype=np.float64):
     ``distributed_sketch``).  The accumulator's ``"edge"`` leaf counts
     folded undirected edges for the partition end-check.
     """
+    import jax
     import jax.numpy as jnp
 
-    from ..sketch.hash import HashSketch, _segment_sum
+    from ..sketch.hash import HashSketch
 
     if not isinstance(S, HashSketch):
         raise InvalidParameters(
@@ -117,9 +116,9 @@ def adjacency_sketch_fold(S, ncols: int, dtype=np.float64):
         sa = acc["sa"]
         for h in range(S.nnz):
             key = bs[h][rows] * jnp.int32(ncols) + cols
-            sa = sa + _segment_sum(
-                vals * vs[h][rows], key, S.s * int(ncols)
-            ).astype(jdt).reshape(S.s, int(ncols))
+            sa = sa + jax.ops.segment_sum(
+                vals * vs[h][rows], key, num_segments=S.s * int(ncols)
+            ).reshape(S.s, int(ncols))
         folded = int(block["rows"].shape[0]) // 2
         return {
             "sa": sa,

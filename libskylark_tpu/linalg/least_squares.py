@@ -315,33 +315,7 @@ def approximate_least_squares(
             return bool(attempts) and attempts[0].get("verdict") == guard.OK
 
         bf16_note = None
-        fp8_note = None
-        if decision.compute_dtype == "float8_e4m3fn":
-            # fp8-first (one rung below bf16, reached only through a clean
-            # bf16 history): the sketch OPERAND is rounded to e4m3 — the
-            # rung's precision semantics — then lifted to bf16 so the apply
-            # reuses the proven f32-accumulating machinery (on fp8-MXU
-            # hardware XLA folds the f8→bf16 convert into the matmul).  The
-            # guard certificate checks the lifted sketch; a non-OK attempt 0
-            # — or a backend that cannot lower f8 at all — escalates to the
-            # input dtype and records ``fp8: fail`` so the policy retires
-            # the rung for this key.
-            from ..core.precision import fp8_dtype
-
-            X = report = None
-            f8 = fp8_dtype()
-            if f8 is not None:
-                try:
-                    X, report = run_guarded(
-                        A.astype(f8).astype(jnp.bfloat16), True
-                    )
-                except Exception:  # noqa: BLE001 — f8 lowering failure → f32
-                    X = report = None
-            if report is None or not _ok0(report):
-                decision.escalated = True
-                fp8_note = "fail"
-                X, report = run_guarded(A, False)
-        elif decision.compute_dtype == "bfloat16":
+        if decision.compute_dtype == "bfloat16":
             # bf16-first: the MXU-heavy sketch runs at bf16 (the
             # f32-accumulable kernel entry points make it nearly free); the
             # guard certificate checks the lifted sketch and a non-OK attempt
@@ -356,8 +330,7 @@ def approximate_least_squares(
         out = X[:, 0] if squeeze else X
         info = {"recovery": report.to_dict(), "policy": decision.to_dict()}
         policy.observe(
-            decision, info, default_size=default_size, bf16=bf16_note,
-            fp8=fp8_note,
+            decision, info, default_size=default_size, bf16=bf16_note
         )
         telemetry.run_summary("sketch_and_solve_ls", info)
         if return_info:

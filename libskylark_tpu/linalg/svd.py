@@ -72,7 +72,7 @@ def gram_orth(Y, passes: int = 2):
         # precision='highest' is load-bearing on BOTH products: the TPU
         # MXU default truncates f32 operands to bf16 mantissas, which
         # caps the achievable orthogonality at ~2e-3 no matter how many
-        # passes run (caught by tests/test_pallas_hw.py round 3).
+        # passes run (caught by tests/_hw_guards.py).
         G = fully_replicated(jnp.dot(Y.T, Y, precision="highest"))
         lam, V = jnp.linalg.eigh(G)
         eps = jnp.asarray(jnp.finfo(Y.dtype).eps, G.dtype)

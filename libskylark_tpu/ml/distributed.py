@@ -35,8 +35,8 @@ Bitwise contracts (pinned by ``tests/test_distributed_train.py``):
   the psum-merged ``Σ_partitions Wi``; per-partition leaves stay
   rank-local and never cross the wire.
 
-The policy layer decides the precision rung (``bf16 → fp8`` operand
-rounding with f32 accumulation, kind ``"train"``); attempt 0 is
+The policy layer decides the precision rung (bf16 operand rounding
+with f32 accumulation, kind ``"train"``); attempt 0 is
 guard-certified and a bad certificate on ANY rank escalates EVERY rank
 back to full precision (world verdict via a second psum), recorded in
 ``info["recovery"]`` and observed back into the profile store.
@@ -753,14 +753,11 @@ class DistributedBlockADMMTrainer:
             "registered": register_as,
         }
         model.info = info
-        bf16_note = fp8_note = None
+        bf16_note = None
         if decision.compute_dtype == "bfloat16":
             bf16_note = "fail" if escalated else "ok"
-        elif decision.compute_dtype == "float8_e4m3fn":
-            fp8_note = "fail" if escalated else "ok"
         policy.observe(
-            decision, info, default_size=D, bf16=bf16_note, fp8=fp8_note,
-            batches=nbatches,
+            decision, info, default_size=D, bf16=bf16_note, batches=nbatches
         )
         if registry is not None and register_as:
             # End-of-training serve hand-off: every rank holds identical

@@ -592,9 +592,9 @@ def streaming_krr_chunk_programs(
     ``(gram(*bargs), zr(R, Wc, *bargs), apply_delta(R, delta, *bargs))``.
 
     Module-level (not a closure of :func:`streaming_kernel_ridge`) so
-    the communication-cost model (``experiments/comm_model.py``, VERDICT
-    r3 item 5) can AOT-lower the SAME programs on a virtual mesh and
-    read the collectives out of the compiled HLO.
+    a test can AOT-lower the SAME programs on a virtual mesh and read
+    the collectives out of the compiled HLO
+    (``tests/test_collectives.py::TestStreamingKrrCommSchedule``).
 
     All contractions consume the (block_rows, sz) panel in place via
     dot_general with an f32 preferred_element_type: bf16 panels contract

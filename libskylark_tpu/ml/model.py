@@ -44,8 +44,9 @@ def _dtype_from_name(name):
     try:
         return np.dtype(name)
     except TypeError:
-        # Extension dtypes (bfloat16, float8_*) register with numpy only
-        # through ml_dtypes (a jax dependency) — resolve by attribute.
+        # Extension dtypes (bfloat16 and the rest of ml_dtypes) register
+        # with numpy only through ml_dtypes (a jax dependency) — resolve
+        # by attribute.
         import ml_dtypes
 
         return np.dtype(getattr(ml_dtypes, name))

@@ -30,7 +30,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..sketch.base import Dimension
 from ..sketch.dense import DenseSketch
-from ..sketch.hash import _segment_sum as _hash_segment_sum
 
 __all__ = [
     "cross_host_psum",
@@ -572,9 +571,9 @@ def _columnwise_sparse_program(S, m: int, block: int, mesh: Mesh,
             start = (h * S.n, off)
             b = S.buckets(start=start, num=block)  # (block,) in-shard
             v = S.values(dtype, start=start, num=block)
-            acc = acc + _hash_segment_sum(
-                d * v[lr], b[lr] * m + cc, S.s * m
-            ).astype(dtype)
+            acc = acc + jax.ops.segment_sum(
+                d * v[lr], b[lr] * m + cc, num_segments=S.s * m
+            )
         out = acc.reshape(S.s, m)
         if scatter:
             return jax.lax.psum_scatter(
@@ -680,9 +679,9 @@ def _columnwise_sparse_2d_program(S, rblock: int, cblock: int, mesh: Mesh):
             start = (h * S.n, off)
             b = S.buckets(start=start, num=rblock)  # in-shard row window
             v = S.values(dtype, start=start, num=rblock)
-            acc = acc + _hash_segment_sum(
-                d * v[lr], b[lr] * cblock + lc, S.s * cblock
-            ).astype(dtype)
+            acc = acc + jax.ops.segment_sum(
+                d * v[lr], b[lr] * cblock + lc, num_segments=S.s * cblock
+            )
         out = acc.reshape(S.s, cblock)
         return jax.lax.psum(out, ax_r)
 
@@ -729,9 +728,9 @@ def _rowwise_sparse_program(S, block: int, mesh: Mesh):
             start = h * S.n
             b = S.buckets(start=start, num=S.n)
             v = S.values(dtype, start=start, num=S.n)
-            acc = acc + _hash_segment_sum(
-                d * v[cc], lr * S.s + b[cc], block * S.s
-            ).astype(dtype)
+            acc = acc + jax.ops.segment_sum(
+                d * v[cc], lr * S.s + b[cc], num_segments=block * S.s
+            )
         return acc.reshape(block, S.s)
 
     return jax.shard_map(

@@ -78,12 +78,11 @@ def fused_enabled() -> bool:
 
 def _kernel_env_token() -> tuple:
     """The env knobs that statically steer which scatter kernel a slice
-    trace bakes in (``hash._window_mode`` / ``_segment_sum``).  Folded
-    into the slice-plan key so a runtime flip re-traces rather than
-    serving an executable built under the old routing."""
+    trace bakes in (``hash._window_mode``).  Folded into the slice-plan
+    key so a runtime flip re-traces rather than serving an executable
+    built under the old routing."""
     return (
         os.environ.get("SKYLARK_PALLAS_WINDOW", ""),
-        os.environ.get("SKYLARK_PALLAS_SCATTER", ""),
         os.environ.get("SKYLARK_NO_PALLAS", "0"),
     )
 

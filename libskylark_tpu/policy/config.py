@@ -22,12 +22,6 @@ operators can flip them at runtime:
   rung on any backend (CPU tests), ``0`` force-denies it; unset, bf16 is
   considered only on MXU backends (tpu/gpu) where the
   ``f32_accumulable`` kernel entry points make it cheap.
-- ``SKYLARK_POLICY_FP8`` — same contract one rung lower: ``1``
-  force-allows the fp8 (e4m3) sketch-apply rung anywhere (CPU tests,
-  when XLA can lower f8 there), ``0`` force-denies; unset, fp8 is
-  considered only on MXU backends AND only after the key's bf16 history
-  is clean (fp8 is strictly more aggressive, so it must climb through
-  the bf16 rung first — ``policy/decide.py``).
 """
 
 from __future__ import annotations
@@ -41,7 +35,6 @@ __all__ = [
     "min_samples",
     "warm_plans",
     "bf16_allowed",
-    "fp8_allowed",
 ]
 
 # configure() override; None defers to SKYLARK_POLICY_DIR.
@@ -91,17 +84,6 @@ def warm_plans(default: int = 8) -> int:
 def bf16_allowed(backend: str) -> bool:
     """May the precision rung propose bf16-first on ``backend``?"""
     raw = os.environ.get("SKYLARK_POLICY_BF16")
-    if raw is not None:
-        return raw.lower() not in ("0", "false", "")
-    return backend in ("tpu", "gpu", "cuda", "rocm")
-
-
-def fp8_allowed(backend: str) -> bool:
-    """May the precision rung propose the fp8 (e4m3) sketch-apply rung
-    on ``backend``?  Same override contract as :func:`bf16_allowed`;
-    the history gates (clean bf16 record, no fp8 failures) live in
-    ``decide.py`` — this is the hardware/env gate only."""
-    raw = os.environ.get("SKYLARK_POLICY_FP8")
     if raw is not None:
         return raw.lower() not in ("0", "false", "")
     return backend in ("tpu", "gpu", "cuda", "rocm")

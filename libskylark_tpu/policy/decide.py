@@ -39,12 +39,8 @@ What a matured entry can change:
   and no bf16 failure is on record; the guard certificate checks the
   narrow sketch and the caller escalates back to the input dtype on a
   RESKETCH verdict (the ``f32_accumulable`` kernel entry points make
-  the narrow attempt nearly free).  One rung lower, fp8 (e4m3)
-  sketch-apply with f32 accumulation: strictly harder to earn — the
-  key's bf16 record must be CLEAN over at least ``min_samples`` runs
-  (fp8 climbs through the bf16 rung, never skips it), no fp8 failure
-  on record, the backend must pass ``config.fp8_allowed``, and the JAX
-  build must carry e4m3 at all (``core.precision.fp8_available``).
+  the narrow attempt nearly free).  The ladder is f32 → bf16 and stops
+  there.
 """
 
 from __future__ import annotations
@@ -285,21 +281,4 @@ def choose_route(
             "bf16-first: healthy entry, no bf16 failure on record; guard "
             "certifies, f32 is the escalation rung"
         )
-        fp = entry.get("fp8") or {}
-        from ..core.precision import fp8_available
-
-        if (
-            int(bf.get("ok", 0)) >= config.min_samples()
-            and int(fp.get("fail", 0)) == 0
-            and config.fp8_allowed(sig.backend)
-            and fp8_available()
-        ):
-            # The rung below: e4m3 operands, f32 accumulation.  Earned
-            # only through a proven-clean bf16 history at this key, and
-            # retired by a single recorded fp8 failure.
-            d.compute_dtype = "float8_e4m3fn"
-            d.reasons.append(
-                f"fp8-first: {int(bf.get('ok', 0))} clean bf16 runs, no "
-                "fp8 failure on record; guard certifies, f32 escalates"
-            )
     return d

@@ -240,7 +240,7 @@ class ProfileStore:
         ``obs`` fields (all optional): ``ok0`` (attempt-0 certificate
         OK), ``resketches``, ``fallback``, ``cond``, ``sketch_type``,
         ``sketch_size`` (certified-OK size), ``default_size``, ``route``,
-        ``bf16`` / ``fp8`` (``"ok"``/``"fail"``), ``refine`` (the solve's
+        ``bf16`` (``"ok"``/``"fail"``), ``refine`` (the solve's
         ``info["refine"]`` dict: ``converged``/``iters``/``rung``),
         ``escalated``, ``rows_per_s``, ``batches``.
         """
@@ -287,9 +287,6 @@ class ProfileStore:
             if obs.get("bf16") in ("ok", "fail"):
                 b = e.setdefault("bf16", {"ok": 0, "fail": 0})
                 b[obs["bf16"]] = b.get(obs["bf16"], 0) + 1
-            if obs.get("fp8") in ("ok", "fail"):
-                f8 = e.setdefault("fp8", {"ok": 0, "fail": 0})
-                f8[obs["fp8"]] = f8.get(obs["fp8"], 0) + 1
             rf_obs = obs.get("refine")
             if isinstance(rf_obs, dict) and rf_obs.get("converged") is not None:
                 rf = e.setdefault(

@@ -109,9 +109,7 @@ def consult(
     )
     if d.route not in ("sketch", "cholesky"):
         telemetry.inc(f"policy.route.{d.route}")
-    if d.compute_dtype == "float8_e4m3fn":
-        telemetry.inc("policy.fp8_first")
-    elif d.compute_dtype:
+    if d.compute_dtype:
         telemetry.inc("policy.bf16_first")
     return d
 
@@ -149,7 +147,6 @@ def observe(
     *,
     default_size: int | None = None,
     bf16: str | None = None,
-    fp8: str | None = None,
     refine: dict | None = None,
     rows_per_s: float | None = None,
     batches: int | None = None,
@@ -170,10 +167,6 @@ def observe(
         obs["bf16"] = bf16
     elif decision.compute_dtype == "bfloat16":
         obs["bf16"] = "ok" if obs.get("ok0", True) else "fail"
-    if fp8 is not None:
-        obs["fp8"] = fp8
-    elif decision.compute_dtype == "float8_e4m3fn":
-        obs["fp8"] = "ok" if obs.get("ok0", True) else "fail"
     if rows_per_s is not None:
         obs["rows_per_s"] = rows_per_s
         obs["batches"] = int(batches or 0)

@@ -71,8 +71,8 @@ def _gather_mode(nrows: int, s: int, m: int, dtype) -> str:
 # a THREE-PASS bf16 SPLIT (A = hi + lo + lo2 exactly; G is ±1 — exact in
 # bf16 — so each pass is an exact selection-and-accumulate in f32 and the
 # sum reproduces full f32 precision): 3 bf16 matmuls at ~95% MFU beat
-# both the 6-pass f32 matmul and the WHT+gather path (measured r2, the
-# VERDICT item-2 fix).  Thresholds per bf16-equivalent pass.
+# both the 6-pass f32 matmul and the WHT+gather path.  Thresholds per
+# bf16-equivalent pass.
 _GEMM_FPB = {
     jnp.bfloat16: 500.0,
     jnp.float32: 500.0 / 3.0,
@@ -81,8 +81,8 @@ _GEMM_FPB = {
 # Element cap on the realized (n, S) ±1 matrix: its transient (plus the
 # int32 popcount broadcast) must stay far below HBM capacity — beyond
 # this the streamed WHT path is used regardless of the flops gate
-# (ADVICE r1: the gate modeled flops-per-byte only and could transiently
-# allocate ~1 GB at n=128K, S=1024).
+# (the gate models flops-per-byte only and could transiently allocate
+# ~1 GB at n=128K, S=1024).
 _GEMM_MAX_ELEMENTS = 64 << 20  # 64M entries ≈ 256 MB of int32 transient
 
 
@@ -276,30 +276,12 @@ class FJLT(SketchTransform):
     def _apply_pallas(self, A, interpret: bool = False):
         """Fused one-pass D·x → WHT kernel (natural order, matching the
         XLA path): the full (m, NB) transform is written and the usual
-        XLA sampled gather follows.  The sampled-epilogue variant, which
-        selects and rescales IN the kernel so that only (m, S) reaches
-        HBM, is not a default route — the v5e compiler has no lane
-        gather across vregs (see ``pallas_fut._sampled_epilogue``); it
-        runs in interpret mode (CPU tests) and compiled only when
-        ``SKYLARK_PALLAS_FJLT_SAMPLED=1`` forces it (``=0`` closes both)."""
+        XLA sampled gather and rescale follow."""
         from . import pallas_fut
 
         if not jnp.issubdtype(A.dtype, jnp.floating):
             A = A.astype(jnp.float32)
         D = self._rfut.diagonal(A.dtype)
-        mode = os.environ.get("SKYLARK_PALLAS_FJLT_SAMPLED", "")
-        if (
-            mode != "0"
-            and (interpret or mode == "1")
-            and pallas_fut.supported_sampled(
-                A.shape[0], self.n, self._nb, self.s
-            )
-        ):
-            with jax.ensure_compile_time_eval():
-                idx = np.asarray(self._ust.samples, np.int32)
-            return pallas_fut.rfut_rowwise_sampled(
-                A, D, self._nb, idx, interpret=interpret
-            )
         T = pallas_fut.rfut_rowwise(A, D, self._nb, interpret=interpret)
         scale = jnp.asarray(np.sqrt(self._nb / self.s), T.dtype)
         return scale * self._ust.apply(T, Dimension.ROWWISE)

@@ -30,7 +30,7 @@ parts against W_hi, plus A_hi against W_lo): unlike FJLT's ±1 operand,
 W is Gaussian-valued, so bf16 needs the W_lo correction too; the dropped
 ``W_lo·(A_lo+A_lo2)`` terms leave ~2^-16-relative pre-cos error — below
 the feature map's own O(1/√S) Monte-Carlo error by orders of magnitude
-(guarded on hardware in tests/test_pallas_hw.py).
+(guarded on hardware in tests/_hw_guards.py).
 """
 
 from __future__ import annotations

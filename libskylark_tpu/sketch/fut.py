@@ -86,7 +86,7 @@ def wht(x, axis: int = 0):
     # f32/f64 inputs pin full matmul precision: the TPU MXU's default
     # drops f32 operands to bf16 mantissas, which silently degraded the
     # transform to ~1e-2 absolute error on hardware (caught by the
-    # compiled-kernel parity test, tests/test_pallas_hw.py).  H is ±1, so
+    # compiled-kernel parity test, tests/_hw_guards.py).  H is ±1, so
     # only the input mantissa width matters.  (A bf16_split3 chain was
     # measured SLOWER than precision="highest" here — the factor einsums
     # are layout-bound, not MXU-bound — so the simple pin stays; the

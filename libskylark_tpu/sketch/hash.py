@@ -33,34 +33,8 @@ from jax.experimental import sparse as jsparse
 from ..core.context import SketchContext
 from ..core.precision import bf16_split3, f32_accumulable
 from ..core.random import sample
-from . import pallas_scatter, pallas_window
+from . import pallas_window
 from .base import Dimension, SketchTransform, register_sketch
-
-
-def _segment_sum(addends, key, num_segments: int):
-    """Flat scatter-add: ``jax.ops.segment_sum`` by default, on every
-    backend.  The Pallas two-pass kernel (``pallas_scatter``) is NOT a
-    default route: the v5e compiler refuses it as written — its (1, C)
-    chunk blocks ("the last two dimensions of your block shape [must
-    be] divisible by 8 and 128") and, behind those, its scalar loads and
-    stores at dynamic LANE positions of VMEM refs — so it runs only when
-    ``SKYLARK_PALLAS_SCATTER=1`` forces it (compiled: raises whatever
-    the compiler raises) or ``=interpret`` runs it in interpret mode
-    (CPU tests).  ``SKYLARK_NO_PALLAS=1`` closes both.
-
-    Dtype gate of the forced modes: f32 natively; bf16/f16/f64 ride the
-    kernel's f32-accumulate boundary cast
-    (``precision.f32_accumulable``)."""
-    mode = os.environ.get("SKYLARK_PALLAS_SCATTER", "")
-    if (
-        mode in ("1", "interpret")
-        and f32_accumulable(addends.dtype, demote_f64=True)
-        and pallas_scatter.supported(addends.shape[0], num_segments)
-    ):
-        return pallas_scatter.segment_sum_flat(
-            addends, key, num_segments, interpret=(mode == "interpret")
-        )
-    return jax.ops.segment_sum(addends, key, num_segments=num_segments)
 
 
 def _window_mode(k: int, m: int, num_segments: int, dtype, nnz: int = 1) -> str:
@@ -97,7 +71,7 @@ def _window_mode(k: int, m: int, num_segments: int, dtype, nnz: int = 1) -> str:
 
 def _segment_sum_rows(A_block, b, v, num_segments: int, mode: str, acc=None):
     """Row scatter-add ``out[b[i], :] += v[i] * A_block[i, :]`` — the
-    windowed analogue of :func:`_segment_sum`, and the ONE dispatcher
+    windowed analogue of ``jax.ops.segment_sum``, and the ONE dispatcher
     both the eager ``_apply_slice_columnwise`` and the jit-safe
     ``apply_slice_kernel`` call (with ``mode`` decided up front by
     :func:`_window_mode`), so the plans slice path and the eager path
@@ -257,9 +231,9 @@ class HashSketch(SketchTransform):
                 b = self.buckets(h * self.n + start, k)
                 v = self.values(dtype, h * self.n + start, k)
                 key = b[rows] * jnp.int32(m) + cols
-                out = out + _segment_sum(
-                    data * v[rows], key, self.s * m
-                ).astype(dtype).reshape(self.s, m)
+                out = out + jax.ops.segment_sum(
+                    data * v[rows], key, num_segments=self.s * m
+                ).reshape(self.s, m)
             return out
         A_block = A_block.astype(dtype)
         mode = _window_mode(k, A_block.shape[1], self.s, dtype, self.nnz)
@@ -602,7 +576,6 @@ class HashSketch(SketchTransform):
         scaled operand ``hi + lo + lo2`` (3 exact bf16 passes), which is
         *more* accurate than the old f32 matmul (whose MXU default
         silently truncated operands to bf16 mantissas) and ~3× faster.
-        Replaces the round-2 ``_hash_matrix`` f32 path (VERDICT item 2).
         """
         return self._scaled_contract(self._scaled_pairs(), A, dim, dtype)
 
@@ -645,9 +618,9 @@ class HashSketch(SketchTransform):
                 key = b[h][hashed] * jnp.int32(batch) + cols
             else:
                 key = rows * jnp.int32(self.s) + b[h][hashed]
-            out = out + _segment_sum(
-                data * v[h][hashed], key, self.s * batch
-            ).astype(dtype)
+            out = out + jax.ops.segment_sum(
+                data * v[h][hashed], key, num_segments=self.s * batch
+            )
         shape = (self.s, batch) if axis == 0 else (batch, self.s)
         return out.reshape(shape)
 

@@ -6,12 +6,9 @@ scatter-add per hash window:
 
     out[b[i], :] += v[i] * A[i, :]        i in [0, k)
 
-XLA lowers this (via ``jax.ops.segment_sum``) to a TPU scatter — the
-measured laggard of the bench suite (CWT 0.90x / MMT 0.84x vs baseline,
-BENCH_r03) — and the flat two-pass kernel in ``pallas_scatter`` cannot
-serve it: flattening a (k, m) block into k·m entries re-pays the
-partition sort per column.  TPU has no vector scatter, but the row form
-needs none: one scalar-indexed VECTOR accumulate per entry —
+XLA lowers this (via ``jax.ops.segment_sum``) to a TPU scatter.  TPU
+has no vector scatter, but the row form needs none: one scalar-indexed
+VECTOR accumulate per entry —
 ``scratch[b[i], :] += v[i] * a_row`` — touches all m lanes at once, so
 the scalar-loop cost amortizes over the row width instead of per
 element.

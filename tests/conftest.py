@@ -170,7 +170,7 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "kernels: Pallas kernel tests (window/flat scatter, fused "
+        "kernels: Pallas kernel tests (window scatter, gather, fused "
         "stream chunks) in interpret mode on CPU CI; tier-1, guarded "
         f"by a per-test {KERNELS_TIMEOUT_S}s timeout",
     )

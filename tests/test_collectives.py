@@ -755,14 +755,13 @@ class TestLinearCLI:
 
 
 class TestStreamingKrrCommSchedule:
-    """HLO lock for the sharded streaming-KRR chunk programs — the comm
-    structure the v5p-32 bound in BASELINE.md is computed from
-    (``experiments/comm_model.py``).  Two load-bearing properties:
+    """HLO lock for the sharded streaming-KRR chunk programs' comm
+    structure.  Two load-bearing properties:
     (1) XLA hoists the per-panel partial-contraction psums OUT of the
     panel while-loop (one all-reduce per program, not nb); (2) the
     traced-offset dynamic_slice of the row-sharded residual costs
-    all-gathers of R — known, bounded, and counted in the model.  A JAX
-    upgrade that regresses either changes these counts."""
+    all-gathers of R — known and bounded.  A JAX upgrade that regresses
+    either changes these counts."""
 
     def _programs(self):
         from libskylark_tpu.ml import GaussianKernel, KrrParams

@@ -64,62 +64,12 @@ class TestPallasRFUT:
             np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4
         )
 
-
-class TestPallasSampledFJLT:
-    """The fused sampled-epilogue kernel (VERDICT r4 item 5): selection +
-    rescale inside the kernel, only (m, S) ever written to HBM."""
-
-    def test_sampled_matches_base_plus_take(self, rng):
-        m, nb, s = 32, 512, 128
-        x = jnp.asarray(rng.standard_normal((m, nb)).astype(np.float32))
-        d = jnp.asarray(np.sign(rng.standard_normal(nb)).astype(np.float32))
-        idx = rng.integers(0, nb, s).astype(np.int32)  # with duplicates
-        out = pallas_fut.rfut_rowwise_sampled(x, d, nb, idx, interpret=True)
-        base = pallas_fut.rfut_rowwise(x, d, nb, interpret=True)
-        ref = np.asarray(base)[:, idx] * np.sqrt(nb / s)
-        np.testing.assert_allclose(
-            np.asarray(out), ref, rtol=1e-5, atol=1e-5
-        )
-
-    @pytest.mark.slow
-    def test_fjlt_fused_path_matches_xla(self, rng):
-        n, s, m = 512, 128, 32
-        A = jnp.asarray(rng.standard_normal((m, n)).astype(np.float32))
-        S1 = FJLT(n, s, SketchContext(seed=5))
-        ref = S1.apply(A, "rowwise")  # XLA path (CPU backend)
-        # interpret=True takes the fused branch (supported_sampled holds
-        # for s=128) without needing the hardware probe.
-        out = S1._apply_pallas(A, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4
-        )
-
-    def test_supported_sampled_predicate(self):
-        assert pallas_fut.supported_sampled(1024, 4096, 4096, 1024)
-        assert not pallas_fut.supported_sampled(1024, 4096, 4096, 64)
-        assert not pallas_fut.supported_sampled(1024, 4096, 4096, 200)
-        assert not pallas_fut.supported_sampled(7, 4096, 4096, 256)
-
     def test_unsupported_shape_raises_value_error(self, rng):
         """A shape the gate rejects must fail with a pointer to the
         predicate, not an opaque TypeError from `m // None`."""
-        m, nb, s = 7, 512, 128  # no tile divides m=7
+        m, nb = 7, 512  # no tile divides m=7
         assert pallas_fut._tile_rows(m, nb) is None
         x = jnp.asarray(rng.standard_normal((m, nb)).astype(np.float32))
         d = jnp.asarray(np.sign(rng.standard_normal(nb)).astype(np.float32))
-        idx = rng.integers(0, nb, s).astype(np.int32)
-        with pytest.raises(ValueError, match="check supported_sampled"):
-            pallas_fut.rfut_rowwise_sampled(x, d, nb, idx, interpret=True)
         with pytest.raises(ValueError, match="check supported"):
             pallas_fut.rfut_rowwise(x, d, nb, interpret=True)
-
-    def test_fused_disable_env(self, rng, monkeypatch):
-        n, s, m = 512, 128, 16
-        monkeypatch.setenv("SKYLARK_PALLAS_FJLT_SAMPLED", "0")
-        A = jnp.asarray(rng.standard_normal((m, n)).astype(np.float32))
-        S1 = FJLT(n, s, SketchContext(seed=6))
-        ref = S1.apply(A, "rowwise")
-        out = S1._apply_pallas(A, interpret=True)  # forced two-step
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4
-        )

@@ -5,7 +5,7 @@ as traced jax ops, so the grid/BlockSpec plumbing, the scalar-loop
 accumulate, the padding seams, and the fused-emit bitwise contract are
 all exercised on every PR — not only under SKYLARK_RUN_PERF=1 on TPU.
 The compiled-lowering half of the battery lives in
-``tests/_hw_guards.py`` / ``test_pallas_hw.py``.
+``tests/_hw_guards.py`` and ``tests/test_tpu_compile.py``.
 
 x64 is on (conftest), so every array here is built f32 explicitly — the
 window kernel's default dtype gate routes f64 to XLA on purpose.
@@ -22,7 +22,7 @@ from libskylark_tpu import plans, streaming
 from libskylark_tpu.core.context import SketchContext
 from libskylark_tpu.core.precision import f32_accumulable
 from libskylark_tpu.resilient import FaultPlan
-from libskylark_tpu.sketch import pallas_scatter, pallas_window
+from libskylark_tpu.sketch import pallas_window
 from libskylark_tpu.sketch.hash import (
     CWT,
     MMT,
@@ -325,37 +325,6 @@ def test_segment_sum_rows_oversized_falls_back(window_interpret):
     big_s = 5_000_000
     assert not pallas_window.supported(100, big_s, 128)
     assert _window_mode(100, 128, big_s, jnp.float32) == "xla"
-
-
-# ---------------------------------------------------------------------------
-# bf16/f64-tolerant flat-kernel entry (pallas_scatter)
-# ---------------------------------------------------------------------------
-
-
-def test_flat_entry_bf16(rng):
-    nnz, s = 4 * pallas_scatter._C, 1024
-    vals = _rand(rng, nnz, jnp.bfloat16)
-    keys = jnp.asarray(rng.integers(0, s, nnz), jnp.int32)
-    out = pallas_scatter.segment_sum_flat(vals, keys, s, interpret=True)
-    assert out.dtype == jnp.bfloat16
-    ref = jax.ops.segment_sum(
-        vals.astype(jnp.float32), keys, num_segments=s
-    )
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref), rtol=2e-2, atol=1e-2
-    )
-
-
-def test_flat_entry_f64(rng):
-    nnz, s = 4 * pallas_scatter._C, 1024
-    vals = _rand(rng, nnz, jnp.float64)
-    keys = jnp.asarray(rng.integers(0, s, nnz), jnp.int32)
-    out = pallas_scatter.segment_sum_flat(vals, keys, s, interpret=True)
-    assert out.dtype == jnp.float64
-    ref = jax.ops.segment_sum(vals, keys, num_segments=s)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
-    )
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ import json
 import os
 import subprocess
 import sys
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -238,6 +239,79 @@ def test_bf16_clean_run_stays_bf16_and_matches_dtype(
     assert info["policy"]["compute_dtype"] == "bfloat16"
     assert "escalated" not in info["policy"]
     assert np.asarray(x).dtype == np.float32  # cast back before the solve
+
+
+def _tpu_entry_with_clean_bf16_history(**extra):
+    """A mature, healthy f32 key on backend "tpu" with 100 clean bf16
+    runs and no failure: the history the ladder's last rung is read
+    from."""
+    entry = {
+        "runs": 100, "updated": 100.0,
+        "guard": {"ok": 100, "resketch": 0, "fallback": 0},
+        "cond": {"last": 3.0, "max": 3.0},
+        "sketch": {"type": "FJLT", "min_ok": 32, "default": 32},
+        "bf16": {"ok": 100, "fail": 0},
+        "routes": {"sketch": 100},
+        "escalations": 0,
+    }
+    entry.update(extra)
+    return entry
+
+
+def test_precision_ladder_stops_at_bf16(policy_env):
+    """f32 → bf16 and no further: however long and clean the bf16
+    history at a key on a TPU, the decision stays on the bf16 rung."""
+    sig = ProblemSignature(
+        kind="ls", m=240, n=8, dtype="float32", backend="tpu"
+    )
+    view = {"entries": {sig.key: _tpu_entry_with_clean_bf16_history()}}
+    d = choose_route(sig, store_view=view)
+    assert d.source == "profile"
+    assert d.compute_dtype == "bfloat16"
+
+
+def test_profile_with_a_retired_rungs_record_still_loads(policy_env):
+    """A store file from a build that still had a rung below bf16
+    carries an ``"fp8"`` record in its entries.  It is outside input:
+    the file loads, merges, folds and saves, and the record moves no
+    decision."""
+    sig = ProblemSignature(
+        kind="ls", m=240, n=8, dtype="float32", backend="tpu"
+    )
+    old = _tpu_entry_with_clean_bf16_history(fp8={"ok": 3, "fail": 1})
+    payload = {"entries": {sig.key: old}, "plans": [], "meta": {}}
+    crc = zlib.crc32(
+        json.dumps(payload, sort_keys=True, separators=(",", ":")).encode()
+    ) & 0xFFFFFFFF
+    os.makedirs(policy_env)
+    with open(os.path.join(policy_env, "profile-4242.json"), "w") as fh:
+        json.dump({"version": 1, "pid": 4242, "payload": payload,
+                   "crc": crc}, fh, sort_keys=True)
+    policy.invalidate_cache()
+    view = load_entries(policy_env)
+    assert view["corrupt_files"] == 0
+    assert view["entries"][sig.key]["bf16"] == {"ok": 100, "fail": 0}
+    with_record = choose_route(sig, store_view=view)
+    without = choose_route(
+        sig,
+        store_view={
+            "entries": {sig.key: _tpu_entry_with_clean_bf16_history()}
+        },
+    )
+    assert with_record.to_dict() == without.to_dict()
+    assert with_record.compute_dtype == "bfloat16"
+    # a new observation folds onto the merged entry and the file this
+    # process writes is read back whole
+    store = ProfileStore(policy_env)
+    store.fold(sig.key, {"ok0": True, "route": "sketch", "bf16": "ok"},
+               now=200.0)
+    assert store.save(now=200.0) is not None
+    entry = load_entries(policy_env)["entries"][sig.key]
+    assert load_entries(policy_env)["corrupt_files"] == 0
+    assert entry["runs"] == 101
+    assert entry["bf16"] == {"ok": 101, "fail": 0}
+    after = choose_route(sig, store_view=load_entries(policy_env))
+    assert after.compute_dtype == "bfloat16"
 
 
 # ---------------------------------------------------------------------------

@@ -8,9 +8,7 @@ tests cannot see any of that.  Nothing runs, so nothing here says
 anything about results or times.
 
 Every Pallas kernel a default route can pick on a TPU is compiled at its
-bench shape, and the text must hold a ``tpu_custom_call``; the two
-kernels no default route reaches are pinned as refused, so a compiler
-that starts accepting them shows up here.
+bench shape, and the text must hold a ``tpu_custom_call``.
 
 The topology is described inside a module-scoped fixture (never at
 import: only one process may load the TPU library, and every xdist
@@ -22,7 +20,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -30,7 +27,7 @@ from libskylark_tpu import SketchContext
 from libskylark_tpu.sketch import CWT, FJLT, JLT, SJLT
 from libskylark_tpu.sketch import fjlt as fjlt_mod
 from libskylark_tpu.sketch import hash as hash_mod
-from libskylark_tpu.sketch import pallas_fut, pallas_scatter, pallas_window
+from libskylark_tpu.sketch import pallas_fut, pallas_window
 
 K, M, S = 131_072, 4096, 1024  # the bench's sketch shape
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
@@ -62,7 +59,7 @@ def as_tpu(monkeypatch):
     branch they take on the chip."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for k in ("SKYLARK_PALLAS_WINDOW", "SKYLARK_PALLAS_GATHER",
-              "SKYLARK_PALLAS_SCATTER", "SKYLARK_NO_PALLAS"):
+              "SKYLARK_NO_PALLAS"):
         monkeypatch.delenv(k, raising=False)
 
 
@@ -141,24 +138,6 @@ def test_fused_stream_chunk_step_routes_to_kernel(one_chip, as_tpu, cls, nnz):
     assert "tpu_custom_call" in text
 
 
-# -- flat scatter: refused, and on no default route ---------------------------
-
-
-def test_flat_scatter_is_refused_and_not_default(one_chip, as_tpu):
-    nnz, segs = 10_000_000, 1 << 17
-    assert pallas_scatter.supported(nnz, segs)
-    with pytest.raises(ValueError, match="divisible by 8 and 128"):
-        _text(
-            lambda v, k: pallas_scatter.segment_sum_flat(v, k, segs),
-            one_chip, ((nnz,), F32), ((nnz,), I32),
-        )
-    text = _text(
-        lambda v, k: hash_mod._segment_sum(v, k, segs),
-        one_chip, ((nnz,), F32), ((nnz,), I32),
-    )
-    assert "tpu_custom_call" not in text
-
-
 # -- FUT / FJLT ---------------------------------------------------------------
 
 
@@ -181,19 +160,13 @@ def test_rfut_rowwise_guard_shape(one_chip):
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
-def test_rfut_sampled_is_refused_and_not_default(one_chip, as_tpu, dtype):
-    idx = np.random.default_rng(0).integers(0, M, S).astype(np.int32)
-    assert pallas_fut.supported_sampled(K, M, M, S)
-    with pytest.raises(ValueError, match="Shape mismatch"):
-        _text(
-            lambda x, d: pallas_fut.rfut_rowwise_sampled(x, d, M, idx),
-            one_chip, ((K, M), dtype), ((M,), dtype),
-        )
-    # The default route of the kernel branch is the two-step form: the
-    # fused kernel, then XLA's gather.
+def test_fjlt_apply_pallas_is_one_kernel_then_xla_gather(one_chip, dtype):
+    """The kernel branch of the rowwise FJLT: the fused D·x → WHT kernel
+    writes (m, NB), and XLA gathers the S sampled lanes."""
     sk = FJLT(M, S, SketchContext(seed=5))
     text = _text(sk._apply_pallas, one_chip, ((256, M), dtype))
-    assert "tpu_custom_call" in text
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "gather(" in text
 
 
 def test_gather_scaled_rows(one_chip, as_tpu):
