@@ -10,7 +10,16 @@
 
 TPU notes: the sketch is the sharded MXU-heavy op; QR/SVD of the s×n
 sketch is replicated-small (the reference holds SA in ``[*,*]``).  The
-retry loop runs eagerly (host) since it changes shapes; each LSQR solve is
+retry loop is host control flow (each attempt changes shapes); each of its
+stages launches one cached program.  The sketch is the planned apply
+(``plans.apply``): ``PLAN_CACHE`` keys the executable on the sketch's JSON,
+the shape, the dtype and the sharding, so a solve from the same context
+state launches it again, while a context with advanced counters, or a
+retry's larger ``s``, compiles one more.  The QR is ``jnp.linalg.qr``'s own
+jit, the condition estimate the module-level jit ``_tri_condest`` with one
+host read behind it.  A sparse A, a tracer and ``SKYLARK_NO_PLANS=1`` take
+the eager apply (a ``bypass`` in ``plans.stats()``).  LSRN's sketch goes
+the same way; its SVD preconditioner is built eagerly.  Each LSQR solve is
 a single jitted while_loop.
 """
 
@@ -18,10 +27,12 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
+import jax.scipy.linalg as jsl
 import numpy as np
 
-from .. import guard, telemetry
+from .. import guard, plans, telemetry
 from ..core.context import SketchContext
 from ..core.params import Params
 from ..sketch.base import Dimension, create_sketch
@@ -51,19 +62,22 @@ class FasterLeastSquaresParams(Params):
 def _sketch_once(A, s, sketch_type, context):
     m = A.shape[0]
     S = create_sketch(sketch_type, m, s, context)
-    return S.apply(A, Dimension.COLUMNWISE)
+    SA = plans.apply(S, A, Dimension.COLUMNWISE)
+    # a hash sketch of a BCOO operand is a BCOO; the s×n factorizations
+    # below are dense (the reference holds SA in ``[*,*]``)
+    return SA.todense() if hasattr(SA, "todense") else SA
 
 
-def _tri_condest(R) -> float:
+@jax.jit
+def _tri_condest(R):
     """1-norm condition estimate of upper-triangular R — ≙ the reference's
     ``utcondest`` (LAPACK ``trcon``-style, ``accelerated_...Elemental.hpp:
-    25-66``): ‖R‖₁·‖R⁻¹‖₁ via a triangular solve against the identity."""
-    import jax.scipy.linalg as jsl
-
+    25-66``): ‖R‖₁·‖R⁻¹‖₁ via a triangular solve against the identity.
+    One program; the caller's ``float()`` is the host read."""
     n = R.shape[0]
     Rinv = jsl.solve_triangular(R, jnp.eye(n, dtype=R.dtype), lower=False)
     one_norm = lambda M: jnp.max(jnp.sum(jnp.abs(M), axis=0))
-    return float(one_norm(R) * one_norm(Rinv))
+    return one_norm(R) * one_norm(Rinv)
 
 
 def faster_least_squares(
@@ -111,7 +125,7 @@ def faster_least_squares(
             # ``build_precond``, accelerated_...Elemental.hpp:68-77, 225-246).
             # (its float() is where the host waits for sketch, QR and estimate)
             with telemetry.span("blendenpik.condest"):
-                cond = _tri_condest(R_try)
+                cond = float(_tri_condest(R_try))
             R = R_try
             good = np.isfinite(cond) and cond < threshold
             report.record(

@@ -12,7 +12,6 @@ unregistered objects) keep the per-solve lifted segment, and a checkpoint
 written by the parent's carry layout resumes.
 """
 
-import contextlib
 import gc
 import os
 import shutil
@@ -21,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax._src import monitoring
+from _builds import builds
 from jax.experimental import sparse as jsparse
 
 from libskylark_tpu import SketchContext
@@ -47,27 +46,7 @@ from libskylark_tpu.solvers import (
 )
 
 M, N = 83, 7  # shapes no other test file solves at
-BUILD_EVENTS = (
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-)
 DATA = os.path.join(os.path.dirname(__file__), "data", "krylov_parent_carry")
-
-
-@contextlib.contextmanager
-def builds():
-    """The traces and lowerings JAX makes inside the block, by event name."""
-    seen = []
-
-    def listener(name, secs, **_):
-        if name in BUILD_EVENTS:
-            seen.append(name)
-
-    monitoring.register_event_duration_secs_listener(listener)
-    try:
-        yield seen
-    finally:
-        monitoring.unregister_event_duration_listener(listener)
 
 
 def tall(seed, m=M, n=N, dtype=jnp.float64):

@@ -17,22 +17,36 @@ import pytest
 
 from libskylark_tpu import plans
 from libskylark_tpu.core.context import SketchContext
-from libskylark_tpu.sketch import CWT, JLT, MMT, GaussianRFT
+from libskylark_tpu.sketch import CWT, FJLT, JLT, MMT, GaussianRFT
 
 
 def _mk(cls, n, s, seed=11, **kw):
     return cls(n, s, SketchContext(seed=seed), **kw)
 
 
-# One linear dense, two hash-based, one feature map: together they cover
-# the matmul, segment-sum, and pointwise-epilogue plan bodies.
+def _fjlt_streamed(n, s):
+    """An FJLT past its SRHT-GEMM gate: diagonal, factored WHT, row sample
+    and rescale — the route Blendenpik's sketch takes at the benchmark's
+    shape (small shapes take the GEMM route and would pin something else)."""
+    S = _mk(FJLT, n, s)
+    assert not S._gemm_wins(jnp.float32) and not S._gemm_wins(jnp.float64)
+    return S
+
+
+SMALL = (96, 48, 37, jnp.float64)  # n, s, m, dtype
+# One linear dense, two hash-based, one feature map and one fast transform:
+# together they cover the matmul, segment-sum, pointwise-epilogue and
+# WHT-and-gather plan bodies.
 TRANSFORMS = [
-    pytest.param(lambda n, s: _mk(JLT, n, s), id="JLT"),
-    pytest.param(lambda n, s: _mk(CWT, n, s), id="CWT"),
-    pytest.param(lambda n, s: _mk(MMT, n, s), id="MMT"),
+    pytest.param(lambda n, s: _mk(JLT, n, s), SMALL, id="JLT"),
+    pytest.param(lambda n, s: _mk(CWT, n, s), SMALL, id="CWT"),
+    pytest.param(lambda n, s: _mk(MMT, n, s), SMALL, id="MMT"),
     pytest.param(
-        lambda n, s: _mk(GaussianRFT, n, s, sigma=1.3), id="GaussianRFT"
+        lambda n, s: _mk(GaussianRFT, n, s, sigma=1.3), SMALL,
+        id="GaussianRFT",
     ),
+    pytest.param(_fjlt_streamed, (4096, 2048, 24, jnp.float32), id="FJLT-wht-f32"),
+    pytest.param(_fjlt_streamed, (4096, 2048, 24, jnp.float64), id="FJLT-wht-f64"),
 ]
 
 
@@ -40,12 +54,12 @@ class TestBitwiseParity:
     """planned == eager, to the bit, both dims (the hard contract)."""
 
     @pytest.mark.parametrize("dim", ["columnwise", "rowwise"])
-    @pytest.mark.parametrize("make", TRANSFORMS)
-    def test_planned_equals_eager(self, make, dim, rng):
-        n, s, m = 96, 48, 37
+    @pytest.mark.parametrize("make,sizes", TRANSFORMS)
+    def test_planned_equals_eager(self, make, sizes, dim, rng):
+        n, s, m, dtype = sizes
         S = make(n, s)
         shape = (n, m) if dim == "columnwise" else (m, n)
-        A = jnp.asarray(rng.standard_normal(shape))
+        A = jnp.asarray(rng.standard_normal(shape), dtype)
         eager = np.asarray(S.apply(A, dim))
         planned = np.asarray(plans.apply(S, A, dim))
         np.testing.assert_array_equal(planned, eager)

@@ -79,12 +79,15 @@ def _krr_train():
 
 # path -> (call, entry span or None, {stage span: times a call})
 PATHS = {
+    # the sketch is the planned apply: its two spans open under `.sketch`
     "blendenpik": (_blendenpik, "blendenpik", {
-        "blendenpik.sketch": 1, "blendenpik.factor": 1, "blendenpik.condest": 1,
+        "blendenpik.sketch": 1, "plans.lookup": 1, "sketch.apply": 1,
+        "blendenpik.factor": 1, "blendenpik.condest": 1,
         "krylov.init": 1, "krylov.segment": 1, "krylov.result": 1,
         "guard.check": 1}),
     "lsrn": (_lsrn, "lsrn", {
-        "lsrn.sketch": 1, "lsrn.factor": 1, "krylov.init": 1,
+        "lsrn.sketch": 1, "plans.lookup": 1, "sketch.apply": 1,
+        "lsrn.factor": 1, "krylov.init": 1,
         "krylov.segment": 1, "krylov.result": 1, "guard.check": 2}),
     # a (matvec, rmatvec) pair is opaque to the stepper: the lifted segment
     "lsqr_callables": (_lsqr_callables, None, {
@@ -101,19 +104,29 @@ PATHS = {
 }
 PHASES = {"krr_train": {"sweep0": 1, "sweep": 1}}  # PhaseTimer's, no dot: no stage
 
+# the CPU client's event around one program's execution (on the chip the
+# benchmark's readers match ``PJRT_LoadedExecutable_Execute``)
+LAUNCH = "PjRtCpuExecutable::Execute"
+
 _TRACED: dict = {}
 
 
-def _spans_of(trace_dir):
-    """``(name, start, end)`` of every ``skylark:`` event of the trace."""
+def _events_of(trace_dir, keep):
+    """``(name, start, end)`` of every event of the trace that ``keep(name)``."""
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
     return [
-        (ev.name[len("skylark:"):], ev.start_ns, ev.start_ns + ev.duration_ns)
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
         for plane in ProfileData.from_file(path).planes
         for line in plane.lines for ev in line.events
-        if ev.name.startswith("skylark:")]
+        if keep(ev.name)]
+
+
+def _spans_of(trace_dir):
+    """``(name, start, end)`` of every ``skylark:`` event of the trace."""
+    return [(name[len("skylark:"):], start, end) for name, start, end
+            in _events_of(trace_dir, lambda name: name.startswith("skylark:"))]
 
 
 def under_profiler(call, trace_dir):
@@ -170,6 +183,20 @@ def test_the_answer_under_a_profiler_session_is_bit_identical(path, tmp_path_fac
     assert plain.tobytes() == under.tobytes()
 
 
+def test_each_blendenpik_stage_launches_one_program(tmp_path_factory):
+    """Sketch, QR and condition estimate are a cached program each, every
+    one launched under its own stage span (the benchmark reads device time
+    and idle by these spans: a stage fused into its neighbour's program
+    would silence its metric)."""
+    _blendenpik()
+    trace_dir = tmp_path_factory.mktemp("trace_launches")
+    _, spans = under_profiler(_blendenpik, trace_dir)
+    launches = [s for _, s, _ in _events_of(str(trace_dir), LAUNCH.__eq__)]
+    for stage in ("blendenpik.sketch", "blendenpik.factor", "blendenpik.condest"):
+        (lo, hi), = [(s, e) for name, s, e in spans if name == stage]
+        assert sum(lo <= t <= hi for t in launches) == 1, stage
+
+
 def test_a_second_attempt_opens_the_attempt_spans_again(tmp_path_factory):
     """Per attempt: a threshold no sketch can meet makes Blendenpik
     re-sketch ``max_attempts`` times and fall back."""
@@ -183,7 +210,8 @@ def test_a_second_attempt_opens_the_attempt_spans_again(tmp_path_factory):
         tmp_path_factory.mktemp("trace_attempts"))
     assert info["fallback"] == "svd"
     got = collections.Counter(name for name, _, _ in spans)
-    assert got == {"blendenpik": 1, "blendenpik.sketch": 2, "blendenpik.factor": 2,
+    assert got == {"blendenpik": 1, "blendenpik.sketch": 2, "plans.lookup": 2,
+                   "sketch.apply": 2, "blendenpik.factor": 2,
                    "blendenpik.condest": 2, "blendenpik.fallback": 1}
 
 

@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 from jax import lax
 from jax.experimental import sparse as jsparse
-from test_krylov_segment import BUILD_EVENTS, builds  # the traces and lowerings of a block
+from _builds import BUILD_EVENTS, builds  # the traces and lowerings of a block
 
 from libskylark_tpu import SketchContext
 from libskylark_tpu.linalg import SVDParams, approximate_svd, approximate_svd_chunked, svd
