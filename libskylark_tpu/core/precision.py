@@ -19,7 +19,43 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["bf16_split3", "f32_accumulable"]
+__all__ = ["bf16_split3", "f32_accumulable", "long_dot"]
+
+
+# Terms one MXU product sums at a time in :func:`long_dot`.
+LONG_DOT_BLOCK = 4096
+
+
+@jax.jit
+def long_dot(A, B):
+    """A·B for A (p, n), B (n, q) with a long n: ``LONG_DOT_BLOCK`` terms
+    at a time at ``precision="highest"`` with a ≥f32 accumulator, the
+    blocks' products added in that dtype.
+
+    One f32 product at ``highest`` over 49,152 terms on a v5e's MXU was
+    off by 7.2e-6 of the product's norm; 4,096 terms at a time by 6.0e-8,
+    what float64 says of f32 (PERF.md section 6, PR 31).  The first is
+    enough for a least-squares residual; it is not for a difference of
+    two such products that has to be right to 5e-7 of its terms (the
+    Woodbury preconditioner of ``ml/krr.py``: CG stalled on an
+    indefinite M).  Up to one block this is the plain product."""
+    n = A.shape[1]
+    block = min(LONG_DOT_BLOCK, n)
+    acc_dtype = jnp.promote_types(jnp.promote_types(A.dtype, B.dtype), jnp.float32)
+
+    def part(start, size):
+        return jnp.dot(
+            jax.lax.dynamic_slice_in_dim(A, start, size, 1),
+            jax.lax.dynamic_slice_in_dim(B, start, size, 0),
+            precision="highest", preferred_element_type=acc_dtype,
+        )
+
+    if block == n:
+        return part(0, n)
+    acc = jax.lax.fori_loop(
+        1, n // block, lambda i, acc: acc + part(i * block, block), part(0, block)
+    )
+    return acc + part(n - n % block, n % block) if n % block else acc
 
 
 def f32_accumulable(dtype, *, demote_f64: bool = False) -> bool:

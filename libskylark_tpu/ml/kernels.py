@@ -21,6 +21,7 @@ import json
 import math
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
 from ..core.context import SketchContext
@@ -34,6 +35,7 @@ __all__ = [
     "ExpSemigroupKernel",
     "MaternKernel",
     "kernel_by_name",
+    "shifted_gram",
 ]
 
 
@@ -124,6 +126,31 @@ class Kernel(abc.ABC):
     def to_json(self):
         return json.dumps(self.to_dict())
 
+    # -- a pytree of its parameters -------------------------------------------
+    # A kernel crosses ``jax.jit`` as an argument (:func:`shifted_gram`):
+    # the parameters of ``_param_dict`` are its leaves, traced, so one
+    # program serves every sigma at a shape; the kind, N and the
+    # parameters a subclass names in ``_static_params`` (an exponent, a
+    # branch of the formula) are its structure.
+
+    _static_params: tuple[str, ...] = ()
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        jax.tree_util.register_pytree_node_class(cls)
+
+    def tree_flatten(self):
+        params = self._param_dict()
+        static = tuple((k, params.pop(k)) for k in self._static_params)
+        return tuple(params.values()), (self.n, tuple(params), static)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        n, names, static = aux
+        self = object.__new__(cls)  # not __init__: a leaf may be a tracer
+        vars(self).update(zip(names, children), n=n, **dict(static))
+        return self
+
     def __repr__(self):
         params = ", ".join(f"{k}={v}" for k, v in self._param_dict().items())
         return f"{type(self).__name__}(N={self.n}{', ' + params if params else ''})"
@@ -183,6 +210,7 @@ class PolynomialKernel(Kernel):
     """k(x, y) = (γ·xᵀy + c)^q (≙ ``polynomial_t``, ml/kernels.hpp:495)."""
 
     kernel_type = "polynomial"
+    _static_params = ("q",)
 
     def __init__(self, n: int, q: int = 2, c: float = 1.0, gamma: float = 1.0):
         super().__init__(n)
@@ -265,6 +293,7 @@ class MaternKernel(Kernel):
     (≙ ``matern_t``, ml/kernels.hpp:1010)."""
 
     kernel_type = "matern"
+    _static_params = ("nu",)
 
     def __init__(self, n: int, nu: float = 0.5, l: float = 1.0):
         super().__init__(n)
@@ -327,3 +356,47 @@ def from_dict(d: dict) -> Kernel:
     name = d.pop("kernel_type")
     n = d.pop("N")
     return kernel_by_name(name, n, **d)
+
+
+# An (n, n) Gram matrix is built ``_GRAM_BLOCK`` elements at a time (a
+# power-of-two count of rows, 512 MB in f32): the block and the
+# temporaries of its distance and its map are all that lives beside the
+# output.
+_GRAM_BLOCK = 1 << 27
+
+
+@jax.jit
+def shifted_gram(kernel: Kernel, X, lam):
+    """``kernel.gram(X) + lam·I`` as one program with one (n, n) output.
+
+    Rows of the output are written a block at a time into the buffer the
+    loop carries, ``lam`` onto the diagonal entries of each block as it
+    is made: no identity matrix, no unshifted K beside the shifted one
+    (op by op, ``gram(X) + lam * eye(n)`` holds five n×n arrays at its
+    peak).  A block is the largest power-of-two count of rows that keeps
+    it under ``_GRAM_BLOCK`` elements, and all of X when that is no fewer
+    than n, which is one ``kernel.gram(X, X)``.  The last block starts at
+    ``n - block``, so an ``n`` that the block does not divide re-writes a
+    few rows with the values they have.  The kernel is a pytree: its
+    parameters and ``lam`` are arguments of the one program a shape has.
+    """
+    n = X.shape[0]
+    block = min(n, 1 << max(3, (_GRAM_BLOCK // n).bit_length() - 1))
+    cols = jnp.arange(n)
+
+    def rows_from(start):
+        Kb = kernel.gram(jax.lax.dynamic_slice_in_dim(X, start, block), X)
+        on_diag = cols[None, :] == (start + jnp.arange(block))[:, None]
+        return jnp.where(on_diag, Kb + jnp.asarray(lam, Kb.dtype), Kb)
+
+    if block == n:
+        return rows_from(0)
+
+    def body(i, K):
+        start = jnp.minimum(i * block, n - block)
+        return jax.lax.dynamic_update_slice_in_dim(K, rows_from(start), start, 0)
+
+    out = jax.eval_shape(rows_from, 0)
+    return jax.lax.fori_loop(
+        0, -(-n // block), body, jnp.zeros((n, n), out.dtype)
+    )

@@ -34,11 +34,12 @@ from jax.scipy.linalg import cho_factor, cho_solve, solve_triangular
 from .. import guard, plans, telemetry
 from ..core.context import SketchContext
 from ..core.params import Params
+from ..core.precision import long_dot
 from ..parallel.mesh import fully_replicated
 from ..sketch.base import Dimension, create_sketch
 from ..solvers.krylov import KrylovParams, cg
 from ..utils import PhaseTimer, compile_cache
-from .kernels import Kernel
+from .kernels import Kernel, shifted_gram
 from .model import FeatureMapModel, KernelModel
 
 __all__ = [
@@ -236,33 +237,84 @@ def sketched_approximate_kernel_ridge(
     return approximate_kernel_ridge(kernel, X, Y, lam, s, context, params)
 
 
+# Columns of Ũ solved for at a time.  XLA's triangular solve keeps
+# temporaries of fifteen times its right-hand side (12.3 GB for all
+# 49,152 columns against a 4,096² factor, compiled for a v5e; 1.1 GB a
+# block of 4,096).
+_BLOCK = 4096
+
+
+@jax.jit
+def _woodbury_factor(Z, lam):
+    """Ũ = L⁻¹Zᵀ/λ with L = chol(I + ZᵀZ/λ), for features Z (n, s): the
+    Gram product, the Cholesky factorization and the triangular solve of
+    the preconditioner's build as one program.  The solve goes over Ũ's
+    columns a block at a time, the last block from ``n - block`` (it
+    re-writes a few columns with the values they have)."""
+    n, s = Z.shape
+    C = fully_replicated(jnp.eye(s, dtype=Z.dtype) + long_dot(Z.T, Z) / lam)
+    L = jnp.linalg.cholesky(C)
+    block = min(_BLOCK, n)
+
+    def body(i, U):
+        start = jnp.minimum(i * block, n - block)
+        Zb = jax.lax.dynamic_slice_in_dim(Z, start, block)
+        # Solve in C's ≥f32 dtype, store Ũ back in the feature dtype —
+        # the (s, n) buffer is the precond's memory footprint.
+        Ub = solve_triangular(L, Zb.T.astype(C.dtype), lower=True) / lam
+        return jax.lax.dynamic_update_slice_in_dim(U, Ub.astype(Z.dtype), start, 1)
+
+    return jax.lax.fori_loop(
+        0, -(-n // block), body, jnp.zeros((s, n), Z.dtype)
+    )
+
+
+@jax.tree_util.register_pytree_node_class
 class _FeatureMapPrecond:
     """(ZᵀZ + λI)⁻¹ as a preconditioner for (K + λI), via Woodbury.
 
     ≙ ``feature_map_precond_t`` (krr.hpp:312-450): U = Z (s, n) features;
     C = I + U·Uᵀ/λ, L = chol(C), Ũ = L⁻¹U/λ; apply(B) = B/λ − Ũᵀ(Ũ·B).
+
+    A registered pytree with the leaves Ũ and λ, so that it crosses
+    ``jax.jit`` as an argument and CG rides ``krylov.run``'s one cached
+    program.  The products with Ũ run at ``highest`` (they are two reads
+    of Ũ either way, and a rounded R would make M a different operator
+    every iteration), the one over n through
+    ``core.precision.long_dot``: apply is a difference that has to be
+    right to λ/μ of its terms, 5·10⁻⁷ along K's largest eigenvalue
+    μ ≈ n/2 at λ = 0.01 and n = 5·10⁴ (upstream runs in double).
     """
 
-    def __init__(self, kernel, lam, X, s, context, params):
-        S = kernel.create_rft(s, _tag(params), context)
-        U = plans.apply(S, jnp.asarray(X), Dimension.ROWWISE).T  # (s, n)
-        lam = jnp.asarray(lam, U.dtype)
-        C = fully_replicated(
-            jnp.eye(s, dtype=U.dtype) + _psd_gram(U, U.T) / lam
-        )
-        L = jnp.linalg.cholesky(C)
-        # Solve in C's ≥f32 dtype, store Ũ back in the feature dtype —
-        # the (s, n) buffer is the precond's memory footprint.
-        self.U = (solve_triangular(L, U.astype(C.dtype), lower=True) / lam).astype(
-            U.dtype
-        )
-        self.lam = lam
+    def __init__(self, U, lam):
+        self.U, self.lam = U, lam
+
+    @classmethod
+    def build(cls, kernel, lam, X, s, context, params):
+        with telemetry.span("faster_krr.precond.features"):
+            S = kernel.create_rft(s, _tag(params), context)
+            Z = plans.apply(S, jnp.asarray(X), Dimension.ROWWISE)  # (n, s)
+        with telemetry.span("faster_krr.precond.factor"):
+            lam = jnp.asarray(lam, Z.dtype)
+            return cls(_woodbury_factor(Z, lam), lam)  # Z dies with this frame
 
     def apply(self, B):
-        return B / self.lam - self.U.T @ (self.U @ B)
+        UB = long_dot(self.U, B).astype(B.dtype)
+        # ŨᵀUB contracted over Ũ's rows in place: eagerly (``krylov.init``)
+        # a ``.T`` is a second (s, n) array
+        return B / self.lam - jax.lax.dot_general(
+            self.U, UB, (((0,), (0,)), ((), ())), precision="highest"
+        )
 
     def apply_adjoint(self, B):
         return self.apply(B)
+
+    def tree_flatten(self):
+        return (self.U, self.lam), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
 
 
 def faster_kernel_ridge(
@@ -275,38 +327,46 @@ def faster_kernel_ridge(
     params: KrrParams | None = None,
 ):
     """CG on (K + λI)·A = Y preconditioned by the random-feature
-    covariance (≙ ``FasterKernelRidge``, krr.hpp:452-543)."""
-    params = params or KrrParams()
-    X = _dense(X)
-    Y2, _ = _as2d(Y)
-    K = kernel.gram(X)
-    n = K.shape[0]
-    Kl = K + lam * jnp.eye(n, dtype=K.dtype)
-    P = _FeatureMapPrecond(kernel, lam, X, s, context, params)
-    kp = KrylovParams(tolerance=params.tolerance, iter_lim=params.iter_lim)
-    if params.checkpoint_dir:
-        # Preemption-safe CG: everything outside the CG state (Gram,
-        # preconditioner) is deterministically rebuilt from (X, context)
-        # on resume, so only the Krylov carry rides the checkpoint.
-        from ..resilient import ResilientParams, ResilientRunner
-        from ..solvers.krylov import cg_chunked
+    covariance (≙ ``FasterKernelRidge``, krr.hpp:452-543).
 
-        A, info = ResilientRunner(
-            cg_chunked(Kl, Y2, precond=P, params=kp),
-            ResilientParams(
-                am_i_printing=params.am_i_printing,
-                log_level=params.log_level,
-                prefix=params.prefix,
-                checkpoint_dir=params.checkpoint_dir,
-                checkpoint_every=params.checkpoint_every,
-                resume=params.resume,
-            ),
-        ).run()
-    else:
-        A, info = cg(Kl, Y2, precond=P, params=kp)
-    model = KernelModel(kernel, X, A)
-    model.info = info
-    return model
+    Three cached programs and CG's: the preconditioner's feature map
+    (``plans.apply``), its Woodbury factor, and K + λI written once in
+    row blocks (``kernels.shifted_gram``); the preconditioner first, so
+    that its temporaries are gone before the n×n matrix is there.
+    ``model.info`` holds CG's ``iterations``, ``flag``, ``resid`` and
+    ``precond_features``.
+    """
+    with telemetry.span("faster_kernel_ridge"):
+        params = params or KrrParams()
+        X = _dense(X)
+        Y2, _ = _as2d(Y)
+        P = _FeatureMapPrecond.build(kernel, lam, X, s, context, params)
+        with telemetry.span("faster_krr.gram"):
+            Kl = shifted_gram(kernel, X, lam)
+        kp = KrylovParams(tolerance=params.tolerance, iter_lim=params.iter_lim)
+        if params.checkpoint_dir:
+            # Preemption-safe CG: everything outside the CG state (Gram,
+            # preconditioner) is deterministically rebuilt from (X, context)
+            # on resume, so only the Krylov carry rides the checkpoint.
+            from ..resilient import ResilientParams, ResilientRunner
+            from ..solvers.krylov import cg_chunked
+
+            A, info = ResilientRunner(
+                cg_chunked(Kl, Y2, precond=P, params=kp),
+                ResilientParams(
+                    am_i_printing=params.am_i_printing,
+                    log_level=params.log_level,
+                    prefix=params.prefix,
+                    checkpoint_dir=params.checkpoint_dir,
+                    checkpoint_every=params.checkpoint_every,
+                    resume=params.resume,
+                ),
+            ).run()
+        else:
+            A, info = cg(Kl, Y2, precond=P, params=kp)
+        model = KernelModel(kernel, X, A)
+        model.info = {**info, "precond_features": int(s)}
+        return model
 
 
 def _chunk_sizes(d: int, s: int, params: KrrParams) -> list[int]:

@@ -352,10 +352,20 @@ def lsqr(A, B, precond=None, params: KrylovParams | None = None, x0=None):
     return _one_shot(lsqr_chunked(A, B, precond, params, x0), params.iter_lim)
 
 
+def _spd_matvec(A):
+    """CG's product with A.  A dense A is multiplied at ``highest``: the
+    default f32 product on a TPU rounds A and P to bfloat16, which is
+    another system than the stated one (PERF.md section 6, PR 31).
+    LSQR's products are not these (:func:`_ops`)."""
+    if isinstance(A, (jax.Array, np.ndarray)):
+        return lambda x: jnp.dot(A, x, precision="highest")
+    return _ops(A)[0]
+
+
 def _cg_body(s, operands):
     """One preconditioned CG iteration; ``operands = (A, M, tol, bnorm)``."""
     A, M, tol, bnorm = operands
-    matvec, _ = _ops(A)
+    matvec = _spd_matvec(A)
     Q = matvec(s["P"])
     denom = jnp.sum(s["P"] * Q, axis=0)
     alpha = jnp.where(s["done"], 0.0, s["rz"] / jnp.where(denom != 0, denom, 1))
@@ -375,7 +385,7 @@ def cg_chunked(
     """Chunkable preconditioned CG (see :func:`cg`)."""
     params = params or KrylovParams()
     M = precond or IdPrecond()
-    matvec, _ = _ops(A)
+    matvec = _spd_matvec(A)
     B, squeeze = _as2d(B)
     dtype = B.dtype
     tol = jnp.asarray(params.tolerance, dtype)
