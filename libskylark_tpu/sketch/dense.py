@@ -94,6 +94,13 @@ class DenseSketch(SketchTransform):
 
     # -- apply --------------------------------------------------------------
 
+    def _product_dtype(self, dtype):
+        """Result dtype of the Omega product for operands in ``dtype``;
+        None is the operands' own (every linear sketch returns what it
+        was given).  A consumer with a pointwise epilogue of its own
+        (``rft._Underlying``) asks for the f32 accumulator instead."""
+        return None
+
     def apply(self, A, dim: Dimension | str = Dimension.COLUMNWISE):
         return self._apply_impl(A, Dimension.of(dim), omega=None)
 
@@ -107,9 +114,10 @@ class DenseSketch(SketchTransform):
         if not jnp.issubdtype(dtype, jnp.floating):
             dtype = jnp.float32
         w = self.realize(dtype, offset=(0, start), shape=(self.s, k))
+        out = self._product_dtype(dtype)
         if hasattr(A_block, "todense"):
-            return _matmul(w, A_block)
-        return _matmul(w, A_block.astype(dtype))
+            return _matmul(w, A_block, out)
+        return _matmul(w, A_block.astype(dtype), out)
 
     supports_slice_kernel = True
 
@@ -126,7 +134,7 @@ class DenseSketch(SketchTransform):
         w = self.realize(dtype, offset=(0, start), shape=(self.s, k))
         valid = start + jnp.arange(k, dtype=jnp.int32) < self.n
         w = jnp.where(valid[None, :], w, jnp.zeros((), dtype))
-        return _matmul(w, A_block.astype(dtype))
+        return _matmul(w, A_block.astype(dtype), self._product_dtype(dtype))
 
     def hoistable_operands(self, dtype):
         """The realized (S, N) Omega, for streaming consumers to hoist
@@ -191,9 +199,10 @@ class DenseSketch(SketchTransform):
             # value-converted Omega (e.g. bf16-rounded then upcast) would
             # silently break the bit-identical-to-apply contract.
             omega = self.realize(dtype)
+        out = self._product_dtype(dtype)
         if dim is Dimension.COLUMNWISE:
-            return _matmul(omega, A)
-        return _matmul(A, omega.T)
+            return _matmul(omega, A, out)
+        return _matmul_nt(A, omega, out)
 
     def _apply_blocked(self, A, dim: Dimension, dtype):
         """Panel-blocked apply: realize Omega in column panels along N and
@@ -208,16 +217,17 @@ class DenseSketch(SketchTransform):
         out_shape = (
             (self.s,) + A.shape[1:] if cw else A.shape[:-1] + (self.s,)
         )
-        acc = jnp.zeros(out_shape, dtype)
+        out = self._product_dtype(dtype)
+        acc = jnp.zeros(out_shape, out or dtype)
 
         def body(p, acc):
             p0 = p * panel
             w = self.realize(dtype, offset=(0, p0), shape=(self.s, panel))
             if cw:
                 blk = lax.dynamic_slice_in_dim(A, p0, panel, axis=0)
-                return acc + _matmul(w, blk)
+                return acc + _matmul(w, blk, out)
             blk = lax.dynamic_slice_in_dim(A, p0, panel, axis=A.ndim - 1)
-            return acc + _matmul(blk, w.T)
+            return acc + _matmul_nt(blk, w, out)
 
         if nfull:
             acc = lax.fori_loop(0, nfull, body, acc)
@@ -225,17 +235,33 @@ class DenseSketch(SketchTransform):
             pc = self.n - rem0
             w = self.realize(dtype, offset=(0, rem0), shape=(self.s, pc))
             if cw:
-                acc = acc + _matmul(w, A[rem0:])
+                acc = acc + _matmul(w, A[rem0:], out)
             else:
-                acc = acc + _matmul(A[..., rem0:], w.T)
+                acc = acc + _matmul_nt(A[..., rem0:], w, out)
         return acc
 
 
-def _matmul(x, y):
-    """Dense@dense or mixed dense/BCOO matmul (≙ base::Gemm dispatch)."""
+def _matmul(x, y, out_dtype=None):
+    """Dense@dense or mixed dense/BCOO matmul (≙ base::Gemm dispatch).
+    ``out_dtype`` is the dense product's result dtype (the f32
+    accumulator of narrower operands, handed over unrounded); a BCOO
+    product comes back in its operands' dtype whatever is asked."""
     if isinstance(x, jsparse.BCOO) or isinstance(y, jsparse.BCOO):
         return x @ y
-    return jnp.matmul(x, y)
+    return jnp.matmul(x, y, preferred_element_type=out_dtype)
+
+
+def _matmul_nt(x, w, out_dtype=None):
+    """``x @ w.T``.  Asked for a wider result, the transpose rides in the
+    dot's dimension numbers: XLA:CPU sums ``matmul(x, w.T)`` of bf16
+    operands in another order under a ``jit`` than op by op, which the
+    f32 accumulator would show and the eager ≡ planned contract forbids."""
+    if out_dtype is None or isinstance(x, jsparse.BCOO):
+        return _matmul(x, w.T, out_dtype)
+    return lax.dot_general(
+        x, w, (((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=out_dtype,
+    )
 
 
 @register_sketch

@@ -15,9 +15,24 @@ Concrete kernels (constructor params ≙ the reference's data classes):
   ``sqrt(2ν/χ²_{2ν})`` (``RFT_data.hpp:336-345``), inscale 1/l
 - GaussianQRFT / LaplacianQRFT(sigma, skip): QMC rows
 
-The W·X product is the MXU-heavy op; shifts/cos fuse into its epilogue
-under XLA (the reference hand-loops this with OpenMP + an inexact-cosine
-fallback — unnecessary on TPU, the VPU does cos at full throughput).
+The W·X product is the MXU-heavy op; the epilogue fuses into it under
+XLA.  The cosine is NOT free there: XLA's general f32 cosine (argument
+reduction good for any magnitude, in software on the VPU) cost a bf16
+feature pass 3.5 times its GEMM on a v5e (PERF.md section 6, PR 32).
+So the epilogue is chosen by the operand's dtype (``_epilogue_kernel``):
+
+- operands narrower than f32 (bfloat16, float16): the phase is taken
+  from the product's f32 accumulator, never rounded to the operand's
+  dtype, counted in turns ``u = acc·scale/2π + shift/2π``, reduced in one
+  step ``r = u − ⌊u + ½⌋`` and the cosine evaluated by a fixed even
+  polynomial in f32 — six multiply-adds, good to 5e-7, where the
+  features are then rounded to 8 or 11 bits;
+- f32 / f64 operands: ``outscale·cos(scale·WX + shift)`` with XLA's
+  cosine, as ever (a one-step reduction in f32 loses the phase's low
+  bits at large magnitude, and the f32 map is nobody's bottleneck).
+
+(The reference hand-loops this with OpenMP and an inexact-cosine
+fallback, ``RFT_Elemental.hpp:85-120``.)
 """
 
 from __future__ import annotations
@@ -32,7 +47,7 @@ from ..core.context import SketchContext
 from ..core.quasirand import LeapedHaltonSequence
 from ..core.random import chi2_lanes, sample
 from .base import Dimension, SketchTransform, register_sketch
-from .dense import DenseSketch
+from .dense import DenseSketch, _matmul, _matmul_nt
 
 __all__ = [
     "RFT",
@@ -44,23 +59,91 @@ __all__ = [
 ]
 
 _TWO_PI = 2.0 * np.pi
+_INV_TWO_PI = 1.0 / _TWO_PI
+
+# cos(2π r) = P(r²) on r ∈ [−½, ½]: the degree-6 minimax polynomial in
+# t = r² on [0, ¼] (Remez, float64: |error| ≤ 1.1e-8), constant term
+# first.  Evaluated by Horner's rule in f32 it is within 4.3e-7 of the
+# cosine over the whole interval.
+_COS_TURN_POLY = (
+    0.9999999891722795,
+    -19.73920453209491,
+    64.93911898390914,
+    -85.4501655505192,
+    60.16783173261327,
+    -25.96831330580957,
+    6.529610419788367,
+)
 
 
-@partial(jax.jit, static_argnames=("outscale", "columnwise"))
-def _epilogue_kernel(WX, shifts, scales, *, outscale, columnwise):
+def _is_narrow(dtype) -> bool:
+    """Narrower than f32 (bfloat16, float16, the fp8 family)."""
+    dtype = jnp.dtype(dtype)
+    return jnp.issubdtype(dtype, jnp.floating) and jnp.finfo(dtype).bits < 32
+
+
+def _accumulator_dtype(dtype):
+    """What the W·X product of operands in ``dtype`` hands the epilogue:
+    the f32 accumulator for narrow operands, None (their own) else."""
+    return jnp.float32 if _is_narrow(dtype) else None
+
+
+def _feature_dtype(A, WX):
+    """Features come back in the operand's dtype: ``WX``'s, unless the
+    product of a narrow operand was handed over as its accumulator."""
+    dtype = getattr(A, "dtype", None)
+    return dtype if dtype is not None and _is_narrow(dtype) else WX.dtype
+
+
+def _cos_turns(u, amplitude=1.0):
+    """``amplitude·cos(2π u)`` in f32 for a phase ``u`` counted in turns:
+    one-step reduction to r = u − ⌊u + ½⌋ ∈ [−½, ½], then
+    ``_COS_TURN_POLY`` in r² by Horner's rule, the (static) amplitude
+    folded into its coefficients.  The reduction is exact; ``u`` itself
+    carries half an ulp of its magnitude (1e-6 of a turn at 64 radians).
+    ``floor`` and not ``round``: on a v5e ``round-nearest-even`` costs six
+    VPU operations more a register, and the chain is bound by them
+    (PERF.md section 6, PR 32); the two differ only at r = ±½, where the
+    even polynomial reads the same."""
+    r = u - jnp.floor(u + jnp.asarray(0.5, u.dtype))
+    t = r * r
+    p = jnp.asarray(amplitude * _COS_TURN_POLY[-1], u.dtype)
+    for c in _COS_TURN_POLY[-2::-1]:
+        p = p * t + jnp.asarray(amplitude * c, u.dtype)
+    return p
+
+
+@partial(jax.jit, static_argnames=("outscale", "columnwise", "out_dtype"))
+def _epilogue_kernel(WX, shifts, scales, *, outscale, columnwise, out_dtype):
     """The feature-map epilogue as one compiled kernel (``scales`` may be
     None — it drops out of the pytree).  Both the eager apply and the
     plan layer's fused executables inline this same chain, keeping them
-    bit-identical."""
-    if columnwise:
+    bit-identical.
+
+    ``out_dtype`` — the operand's dtype — picks the chain.  f32/f64:
+    ``outscale·cos(scales·WX + shifts)`` in that dtype, XLA's cosine.
+    Narrower: ``WX`` is the product's f32 accumulator (a BCOO product
+    arrives rounded; it is widened and treated alike), ``shifts`` and
+    ``scales`` arrive in turns (both already over 2π, f32), and the
+    cosine is :func:`_cos_turns`."""
+    col = columnwise and WX.ndim > 1
+
+    def per_feature(v):
+        return v[:, None] if col else v
+
+    if not _is_narrow(out_dtype):
         if scales is not None:
-            WX = WX * (scales[:, None] if WX.ndim > 1 else scales)
-        WX = WX + (shifts[:, None] if WX.ndim > 1 else shifts)
-    else:
-        if scales is not None:
-            WX = WX * scales
-        WX = WX + shifts
-    return jnp.asarray(outscale, WX.dtype) * jnp.cos(WX)
+            WX = WX * per_feature(scales)
+        WX = WX + per_feature(shifts)
+        return jnp.asarray(outscale, WX.dtype) * jnp.cos(WX)
+    with jax.named_scope("rft.epilogue.turns"):
+        f32 = jnp.float32
+        per_turn = (
+            jnp.asarray(_INV_TWO_PI, f32) if scales is None
+            else per_feature(scales)
+        )
+        u = WX.astype(f32) * per_turn + per_feature(shifts)
+        return _cos_turns(u, outscale).astype(out_dtype)
 
 
 class RFT(SketchTransform):
@@ -112,24 +195,43 @@ class RFT(SketchTransform):
         (≙ ``_scales`` filled with 1, ``RFT_data.hpp:88-90``)."""
         return None
 
+    def _turns(self):
+        """``(shifts, scales)`` over 2π in f32, as the narrow-operand
+        chain of :func:`_epilogue_kernel` takes them: realized once a
+        map and concrete, for the reason :meth:`shifts` gives."""
+        hit = self.__dict__.get("_turn_cache")
+        if hit is None:
+            with jax.ensure_compile_time_eval():
+                inv = jnp.asarray(_INV_TWO_PI, jnp.float32)
+                scales = self.scales(jnp.float32)
+                hit = self.__dict__["_turn_cache"] = (
+                    self.shifts(jnp.float32) * inv,
+                    None if scales is None else scales * inv,
+                )
+        return hit
+
     def apply(self, A, dim: Dimension | str = Dimension.COLUMNWISE):
         dim = Dimension.of(dim)
         WX = self._underlying.apply(A, dim)
-        return self._epilogue(WX, dim)
+        return self._epilogue(WX, dim, _feature_dtype(A, WX))
 
-    def _epilogue(self, WX, dim: Dimension):
-        """outscale · cos(scales ⊙ WX + shifts) — via the shared jitted
-        kernel so the eager and planned paths run the SAME fused
-        elementwise chain (op-by-op eager dispatch skips the FMA
-        contraction a jit fusion applies to ``WX·scales + shifts``, and
-        the two would differ by a ulp)."""
-        dtype = WX.dtype
+    def _epilogue(self, WX, dim: Dimension, out_dtype):
+        """outscale · cos(scales ⊙ WX + shifts) in ``out_dtype`` — via
+        the shared jitted kernel so the eager and planned paths run the
+        SAME fused elementwise chain (op-by-op eager dispatch skips the
+        FMA contraction a jit fusion applies to ``WX·scales + shifts``,
+        and the two would differ by a ulp)."""
+        if _is_narrow(out_dtype):
+            shifts, scales = self._turns()
+        else:
+            shifts, scales = self.shifts(WX.dtype), self.scales(WX.dtype)
         return _epilogue_kernel(
             WX,
-            self.shifts(dtype),
-            self.scales(dtype),
+            shifts,
+            scales,
             outscale=self.outscale,
             columnwise=dim is Dimension.COLUMNWISE,
+            out_dtype=jnp.dtype(out_dtype),
         )
 
     def _apply_slice_columnwise(self, A_block, start: int):
@@ -147,14 +249,21 @@ class RFT(SketchTransform):
         in :meth:`finalize_slices` once the slice-sums are merged)."""
         return self._underlying.apply_slice_kernel(A_block, start)
 
-    def finalize_slices(self, acc, dim: Dimension | str = Dimension.COLUMNWISE):
+    def finalize_slices(
+        self, acc, dim: Dimension | str = Dimension.COLUMNWISE, dtype=None
+    ):
         """COLUMNWISE slice-sums hold the merged W·A — apply the
         ``outscale·cos(scales ⊙ · + shifts)`` epilogue once here.
-        ROWWISE blocks were finished by :meth:`apply` already."""
+        ROWWISE blocks were finished by :meth:`apply` already.
+
+        ``dtype`` is the blocks' dtype where it is not ``acc``'s: the
+        slices of bfloat16 blocks come back as f32 accumulators, and
+        ``finalize_slices(acc, dim, jnp.bfloat16)`` is then bit for bit
+        the bfloat16 :meth:`apply`."""
         dim = Dimension.of(dim)
         if dim is Dimension.ROWWISE:
             return acc
-        return self._epilogue(acc, dim)
+        return self._epilogue(acc, dim, acc.dtype if dtype is None else dtype)
 
     def hoistable_operands(self, dtype):
         """The realized (S, N) W — loop-invariant, and the expensive
@@ -168,15 +277,20 @@ class RFT(SketchTransform):
     ):
         dim = Dimension.of(dim)
         WX = self._underlying.apply_with_operands(ops, A, dim)
-        return self._epilogue(WX, dim)
+        return self._epilogue(WX, dim, _feature_dtype(A, WX))
 
 
 class _Underlying(DenseSketch):
-    """The dense W (pre-scaled by inscale); not registered — internal."""
+    """The dense W (pre-scaled by inscale); not registered — internal.
+    Its products of narrow operands come back as the f32 accumulator,
+    which the epilogue reads unrounded."""
 
     def __init__(self, n, s, context, scale, dist):
         self.dist = dist
         super().__init__(n, s, context, scale=scale)
+
+    def _product_dtype(self, dtype):
+        return _accumulator_dtype(dtype)
 
 
 @register_sketch
@@ -310,16 +424,25 @@ class QRFT(SketchTransform):
         A = jnp.asarray(A)
         dtype = A.dtype if jnp.issubdtype(A.dtype, jnp.floating) else jnp.float32
         W, shifts = self.realize(dtype)
+        acc_dtype = _accumulator_dtype(dtype)
         if dim is Dimension.COLUMNWISE:
             if A.shape[0] != self.n:
                 raise ValueError(f"columnwise apply needs {self.n} rows, got {A.shape}")
-            WX = W @ A
-            WX = WX + (shifts[:, None] if WX.ndim > 1 else shifts)
+            WX = _matmul(W, A.astype(dtype), acc_dtype)
         else:
             if A.shape[-1] != self.n:
                 raise ValueError(f"rowwise apply needs {self.n} cols, got {A.shape}")
-            WX = A @ W.T + shifts
-        return jnp.asarray(self.outscale, dtype) * jnp.cos(WX)
+            WX = _matmul_nt(A.astype(dtype), W, acc_dtype)
+        if acc_dtype is not None:
+            shifts = shifts.astype(acc_dtype) * jnp.asarray(_INV_TWO_PI, acc_dtype)
+        return _epilogue_kernel(
+            WX,
+            shifts,
+            None,
+            outscale=self.outscale,
+            columnwise=dim is Dimension.COLUMNWISE,
+            out_dtype=jnp.dtype(dtype),
+        )
 
     def _param_dict(self):
         return {"skip": self.skip}

@@ -10,6 +10,8 @@ style):
 - PPT: approximates the polynomial kernel.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -280,6 +282,227 @@ class TestRFT:
             np.asarray(F.apply(X, "columnwise")),
             np.asarray(F2.apply(X, "columnwise")),
         )
+
+
+_RFT_MAPS = [
+    pytest.param(GaussianRFT, {"sigma": 0.5}, id="gaussian"),
+    pytest.param(LaplacianRFT, {"sigma": 40.0}, id="laplacian"),
+    pytest.param(MaternRFT, {"nu": 1.5, "l": 0.6}, id="matern"),
+]
+_DIMS = ["rowwise", "columnwise"]
+
+
+def _bf16_case(cls, kw, dim, rng, n=24, s=64, m=48):
+    """A map, a bfloat16 operand in ``dim``'s layout, and the float64
+    phase ``scales·(X_bf16·W_bf16ᵀ) + shifts`` as (m, s), entries with
+    |phase| > 64 masked out (Cauchy rows have no bound)."""
+    F = cls(n, s, SketchContext(seed=11), **kw)
+    X = jnp.asarray(rng.standard_normal((m, n)), jnp.bfloat16)
+    W = np.asarray(F._underlying.realize(jnp.bfloat16), np.float64)
+    phase = np.asarray(X, np.float64) @ W.T
+    scales = F.scales(jnp.float32)
+    if scales is not None:
+        phase = phase * np.asarray(scales, np.float64)
+    phase = phase + np.asarray(F.shifts(jnp.float32), np.float64)
+    keep = np.abs(phase) <= 64.0
+    assert keep.mean() > 0.9 and np.abs(phase[keep]).max() > 16.0
+    A = X if dim == "rowwise" else X.T
+    return F, A, phase, keep
+
+
+class TestRFTEpilogue:
+    """The epilogue by operand dtype (``rft._epilogue_kernel``): narrow
+    operands take the phase from the f32 accumulator, in turns, through
+    a polynomial cosine; f32/f64 keep ``outscale·cos(WX + shifts)``."""
+
+    @pytest.mark.parametrize("dim", _DIMS)
+    @pytest.mark.parametrize("cls,kw", _RFT_MAPS)
+    def test_bf16_against_float64(self, cls, kw, dim, rng):
+        from libskylark_tpu.sketch import rft
+
+        F, A, phase, keep = _bf16_case(cls, kw, dim, rng)
+        want = np.cos(phase)  # features over outscale
+        # before the last rounding: the f32 chain on the f32 accumulator
+        acc = F._underlying.apply(A, dim)
+        assert acc.dtype == jnp.float32
+        shifts, scales = F._turns()
+        if dim == "columnwise":
+            acc = acc.T
+        u = acc * (rft._INV_TWO_PI if scales is None else scales) + shifts
+        got = np.asarray(jax.jit(rft._cos_turns)(u.astype(jnp.float32)), np.float64)
+        assert np.abs(got - want)[keep].max() < 1e-5
+        # after it: the nearest bfloat16 of the float64 feature, but for ties
+        Z = F.apply(A, dim)
+        assert Z.dtype == jnp.bfloat16
+        Z = np.asarray(Z.astype(jnp.float32), np.float64)
+        Z = Z if dim == "rowwise" else Z.T
+        want = F.outscale * want
+        nearest = np.asarray(
+            jnp.asarray(want, jnp.float32).astype(jnp.bfloat16).astype(jnp.float32),
+            np.float64,
+        )
+        off = (Z != nearest) & keep
+        assert off.mean() < 0.01
+        slack = 1e-5 * F.outscale
+        assert np.all(np.abs(Z - want)[off] <= np.abs(nearest - want)[off] + slack)
+
+    @pytest.mark.parametrize("dim", _DIMS)
+    @pytest.mark.parametrize("cls,kw", _RFT_MAPS)
+    def test_bf16_routes_bitwise(self, cls, kw, dim, rng):
+        """eager ≡ planned ≡ in a caller's jit ≡ apply_with_operands ≡
+        slice-and-finalize, to the bit, in bfloat16."""
+        from libskylark_tpu import plans
+
+        F, A, _, _ = _bf16_case(cls, kw, dim, rng)
+        eager = np.asarray(F.apply(A, dim))
+        routes = {
+            "planned": plans.apply(F, A, dim),
+            "jit": jax.jit(lambda A_: F.apply(A_, dim))(A),
+            "operands": F.apply_with_operands(
+                F.hoistable_operands(jnp.bfloat16), A, dim
+            ),
+        }
+        if dim == "rowwise":  # finished blocks, concatenated
+            routes["slices"] = jnp.concatenate(
+                [F.apply_slice(A[i : i + 16], i, dim) for i in range(0, 48, 16)]
+            )
+        else:  # the f32 accumulator, then one epilogue
+            part = F.apply_slice(A, 0, dim)
+            assert part.dtype == jnp.float32
+            routes["slices"] = F.finalize_slices(part, dim, jnp.bfloat16)
+            part = jax.jit(F.apply_slice_kernel)(A, jnp.int32(0))
+            routes["slice_kernel"] = F.finalize_slices(part, dim, jnp.bfloat16)
+        for name, got in routes.items():
+            assert got.dtype == jnp.bfloat16, name
+            np.testing.assert_array_equal(np.asarray(got), eager, err_msg=name)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+    @pytest.mark.parametrize("cls,kw", _RFT_MAPS)
+    def test_f32_f64_keep_xla_cosine(self, cls, kw, dtype, rng):
+        """f32 and f64 features are bit for bit ``outscale·cos(scales·WX +
+        shifts)`` (what ``krr_faster_mnist_pcg``'s preconditioner reads)."""
+        n, s, m = 24, 64, 48
+        F = cls(n, s, SketchContext(seed=11), **kw)
+        X = jnp.asarray(rng.standard_normal((m, n)), dtype)
+        shifts, scales = F.shifts(dtype), F.scales(dtype)
+        outscale = jnp.asarray(F.outscale, dtype)
+
+        @jax.jit
+        def written_out(WX, shifts, scales):
+            if scales is not None:
+                WX = WX * scales
+            return outscale * jnp.cos(WX + shifts)
+
+        WX = F._underlying.apply(X, "rowwise")
+        assert WX.dtype == dtype
+        got = F.apply(X, "rowwise")
+        assert got.dtype == dtype
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(written_out(WX, shifts, scales))
+        )
+        WX = F._underlying.apply(X.T, "columnwise")
+        want = np.asarray(written_out(
+            WX, shifts[:, None], None if scales is None else scales[:, None]))
+        np.testing.assert_array_equal(np.asarray(F.apply(X.T, "columnwise")), want)
+        np.testing.assert_array_equal(
+            np.asarray(F.finalize_slices(F.apply_slice(X.T, 0))), want
+        )
+
+    def test_linear_sketches_keep_their_dtype(self, rng):
+        """Only the feature maps' underlying product is widened."""
+        from libskylark_tpu.sketch import CT, JLT
+
+        A = jnp.asarray(rng.standard_normal((24, 5)), jnp.bfloat16)
+        for S in (JLT(24, 8, SketchContext(seed=1)), CT(24, 8, SketchContext(seed=1))):
+            assert S.apply(A, "columnwise").dtype == jnp.bfloat16
+            assert S.apply(A.T, "rowwise").dtype == jnp.bfloat16
+            assert S.apply_slice(A[:7], 0).dtype == jnp.bfloat16
+
+    def test_qrft_shares_the_epilogue(self, rng):
+        X = rng.standard_normal((9, 5))
+        F = GaussianQRFT(5, 64, SketchContext(seed=1), sigma=1.5, skip=50)
+        W, shifts = F.realize(jnp.float64)
+        want = F.outscale * np.cos(X @ np.asarray(W).T + np.asarray(shifts))
+        np.testing.assert_allclose(
+            np.asarray(F.apply(jnp.asarray(X), "rowwise")), want, atol=1e-12
+        )
+        # bfloat16 (the Cauchy inverse CDF takes it; ndtri does not):
+        # W and the shifts rounded to bfloat16, the phase kept in f32
+        F = LaplacianQRFT(5, 64, SketchContext(seed=1), sigma=30.0, skip=50)
+        Xb = jnp.asarray(X, jnp.bfloat16)
+        W, shifts = F.realize(jnp.bfloat16)
+        phase = np.asarray(Xb, np.float64) @ np.asarray(W, np.float64).T
+        phase = phase + np.asarray(shifts, np.float64)
+        keep = np.abs(phase) <= 64.0
+        assert keep.mean() > 0.9
+        Zb = F.apply(Xb, "rowwise")
+        assert Zb.dtype == jnp.bfloat16
+        err = np.abs(np.asarray(Zb, np.float64) - F.outscale * np.cos(phase))
+        assert err[keep].max() < 2.0**-8 * F.outscale
+        np.testing.assert_array_equal(
+            np.asarray(Zb), np.asarray(F.apply(Xb.T, "columnwise")).T
+        )
+
+
+class TestChunkProgramEpilogue:
+    """What the streamed trainer's three programs lower to, by feature
+    dtype (counted in the StableHLO text; no device needed)."""
+
+    D, SZ, NB, BR, T = 16, 32, 2, 64, 3
+
+    def _texts(self, dtype):
+        from libskylark_tpu.ml import GaussianKernel
+        from libskylark_tpu.ml.krr import streaming_krr_chunk_programs
+
+        maps = [GaussianKernel(self.D, sigma=4.0).create_rft(
+            self.SZ, "regular", SketchContext(seed=9))]
+
+        def block_fn(start, rows, X):
+            return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
+
+        gram, zr, apply_delta = streaming_krr_chunk_programs(
+            maps, 0, self.SZ, self.NB, self.BR, self.T, 0.1, block_fn, dtype
+        )
+        X = jnp.zeros((self.NB * self.BR, self.D), dtype)
+        R = jnp.zeros((self.NB, self.BR, self.T), jnp.float32)
+        W = jnp.zeros((self.SZ, self.T), jnp.float32)
+        return {
+            "gram": gram.lower(X).as_text(),
+            "zr": zr.lower(R, W, X).as_text(),
+            "apply_delta": apply_delta.lower(R, W, X).as_text(),
+        }
+
+    @pytest.fixture(scope="class")
+    def texts(self):
+        return {"bfloat16": self._texts(jnp.bfloat16),
+                "float32": self._texts(jnp.float32)}
+
+    @pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
+    def test_bf16_features_hold_no_cosine(self, texts, program):
+        text = texts["bfloat16"][program]
+        panel = f"tensor<{self.BR}x{self.SZ}x"
+        # (W's Box-Muller draw has a cosine of its own, at W's shape)
+        assert not re.search(rf"stablehlo\.cosine .*{panel}", text)
+        # the feature product hands over its f32 accumulator ...
+        assert re.search(
+            rf"stablehlo\.dot_general .*\(tensor<{self.BR}x{self.D}xbf16>, "
+            rf"tensor<{self.SZ}x{self.D}xbf16>\) -> {panel}f32>", text)
+        # ... and the epilogue rounds once, at its end
+        body = text[text.index("func.func private @_epilogue_kernel"):]
+        body = body[: body.index("\n  }")]
+        assert body.startswith(
+            f"func.func private @_epilogue_kernel(%arg0: {panel}f32>")
+        to_bf16 = [ln for ln in body.splitlines() if f"-> {panel}bf16>" in ln]
+        assert len(to_bf16) == 2  # the signature and the last convert
+        assert "stablehlo.convert" in body.splitlines()[-2]
+        assert "return" in body.splitlines()[-1]
+
+    @pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
+    def test_f32_features_hold_one_cosine(self, texts, program):
+        text = texts["float32"][program]
+        panel = f"tensor<{self.BR}x{self.SZ}x"
+        assert len(re.findall(rf"stablehlo\.cosine .*{panel}f32>", text)) == 1
+        assert "stablehlo.floor" not in text
 
 
 class TestQRFT:

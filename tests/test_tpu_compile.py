@@ -17,6 +17,7 @@ process, and the persistent compilation cache is off around them.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -217,3 +218,39 @@ def test_streaming_krr_sweep_step_full_size(one_chip):
     compiled = _compile(zr, one_chip, ((NB, BR, 1), F32), ((SZ, 1), F32),
                         ((BR, D), BF16))
     assert compiled.memory_analysis().temp_size_in_bytes < 12 << 30
+
+
+@pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
+def test_streaming_krr_feature_pass_is_one_output_fusion(one_chip, program):
+    """The bf16 feature pass of each chunk program (8192 x 784 -> 1024, the
+    bench's K): the product and the turns epilogue are ONE ``kOutput``
+    fusion whose convolution yields f32, so no f32 panel lands in HBM
+    and the phase is never rounded to bf16; no cosine at the panel's
+    shape is left (W's Box-Muller draw keeps its own, at W's)."""
+    from libskylark_tpu.ml import GaussianKernel
+    from libskylark_tpu.ml.krr import streaming_krr_chunk_programs
+
+    D, SZ, NB, BR, T = 784, 1024, 2, 8192, 10
+    maps = [GaussianKernel(D, sigma=28.0).create_rft(
+        SZ, "regular", SketchContext(seed=9))]
+
+    def block_fn(start, rows, X):
+        return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
+
+    progs = dict(zip(("gram", "zr", "apply_delta"), streaming_krr_chunk_programs(
+        maps, 0, SZ, NB, BR, T, 1.0, block_fn, BF16)))
+    X, R, W = ((NB * BR, D), BF16), ((NB, BR, T), F32), ((SZ, T), F32)
+    shapes = (X,) if program == "gram" else (R, W, X)
+    text = _text(progs[program], one_chip, *shapes)
+
+    panel = rf"\[{BR},{SZ}\]"
+    fused = [b for b in text.split("\n\n")  # a computation a paragraph
+             if re.search(rf"= f32{panel}\S* convolution\(", b)]
+    assert len(fused) == 1, [b[:80] for b in fused]
+    body = fused[0]
+    assert "rft.epilogue.turns" in body  # the named scope rides in the metadata
+    assert re.search(rf"f32{panel}\S* floor\(", body)
+    assert not re.search(rf"{panel}\S* cosine\(", text)
+    name = re.match(r"%?(\S+)", body).group(1)
+    call = re.search(rf"fusion\([^\n]*kind=(\w+), calls=%?{re.escape(name)}\b", text)
+    assert call and call.group(1) == "kOutput"
