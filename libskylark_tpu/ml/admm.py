@@ -16,6 +16,8 @@ equations reproduce the reference train loop (``BlockADMM.hpp:374-590``):
                            ZtObar_j = Z_j·o_jᵀ;  sum_o += o_j
              del_o = O − sum_o;  Obar = O − del_o/(J+1);  nu += O − Obar
              Wbar = (Σ_partitions Wi + W)/(P+1);  mu += W − Wbar
+             obj  = loss(Σ_j Wbar_jᵀZ_j, Y) at the Wbar the iteration
+                    started from, + λ·reg of the one it ends with
 
 TPU re-design of the parallel schedule (SURVEY §2.7 P10): the reference
 maps data partitions to MPI ranks and feature blocks to OpenMP threads.
@@ -23,17 +25,43 @@ Here data partitions are an explicit **vmapped leading axis** (size P) —
 the algorithm is identical for a given P regardless of device count — and
 the consensus reduction ``Σ_partitions Wi`` is a plain sum that GSPMD
 lowers to a psum over ICI when the P axis is sharded across the mesh.
-Feature blocks are an unrolled loop of MXU GEMMs (XLA overlaps them; no
-OpenMP needed).  The whole iteration is one jitted function — no host
-round-trips inside a step (the reference broadcasts Wbar over MPI every
-iteration, ``BlockADMM.hpp:375``).
+The whole iteration is one program — no host round-trips inside a step
+(the reference broadcasts Wbar over MPI every iteration,
+``BlockADMM.hpp:375``).
+
+**Cached or remade** (≙ upstream's ``CacheTransforms``;
+``ADMMParams.cache_transforms``).  Cached, every ``Z_j`` is realized for
+all rows before the first iteration and kept for the run (``Zs``,
+``(P, s_j, n_i)``): the blocks are an unrolled loop of GEMMs over
+resident operands.  Remade (upstream's default, and what lets features ×
+rows exceed memory), the iteration's operands are X and the maps; block
+j's ``Z_j`` is made inside the iteration, held for its products before
+and after the solve, and dropped: one block is live at a time, the
+block loop made sequential in the program by an explicit dependence.
+``None`` caches when all blocks fit beside X in ``CACHE_FRACTION`` of
+the device's memory.  The two routes share one step body.
+
+**Programs.**  Transform, factor and the iteration scan are module-level
+``jax.jit`` programs keyed by shapes and a hashable ``_Spec`` (loss,
+regularizer, the serialized maps, P, ρ, λ — the last two constants of
+the programs, as they always were): a second ``train`` at the same
+shapes traces, lowers and compiles nothing.  The maps' draws are
+constants of the programs (realized from the counter stream inside).
+
+**Narrow rows.**  When X is narrower than f32 (bfloat16) the features
+take X's dtype, as ``RFT.apply`` gives them, and everything else — state,
+right-hand sides, ``Z_jZ_jᵀ + I``, factors, solves, the objective — is
+f32.  A block then meets an f32 operand of a thin product (k columns) as
+three pieces of the operand in the block's dtype, stacked along k: one
+MXU pass with 3k columns, f32 accumulation, the operand carried to 24
+bits.  f32 and f64 rows keep their dtype throughout.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Sequence
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -41,35 +69,24 @@ import numpy as np
 from jax import lax
 from jax.scipy.linalg import solve_triangular
 
+from .. import telemetry
 from ..core.params import Params
 from ..resilient.chunked import ChunkedSolver
 from ..sketch.base import Dimension
+from ..sketch.rft import _is_narrow
 from ..solvers.prox import get_loss, get_regularizer
 from ..utils import compile_cache
 from ..utils.timer import PhaseTimer
-from .coding import dummy_coding
+from .coding import class_indices, dummy_coding
 from .model import FeatureMapModel
 
-__all__ = ["ADMMParams", "BlockADMMSolver"]
+__all__ = ["ADMMParams", "BlockADMMSolver", "CACHE_FRACTION"]
 
-
-@dataclass
-class _PreparedRun:
-    """Everything ``train``/``chunked`` need that is NOT checkpointable
-    state: the realized feature blocks, cached Cholesky factors, targets,
-    the jittable step function, and the initial state tuple.  All of it is
-    deterministically rebuilt from (X, Y, maps, params) on resume — only
-    the state tuple rides the checkpoint."""
-
-    Zs: list
-    Ls: list
-    Yp: Any
-    state0: tuple
-    step: Callable
-    timer: PhaseTimer
-    d: int
-    classes: Any
-    dtype: Any
+# ``cache_transforms=None`` keeps every feature block for the run when X
+# and the blocks together take no more than this share of the memory of
+# the device that holds X (the rest is the state, the factors and the
+# programs' temporaries); otherwise the blocks are remade.
+CACHE_FRACTION = 0.5
 
 
 @dataclass
@@ -79,6 +96,303 @@ class ADMMParams(Params):
     maxiter: int = 20
     data_partitions: int = 1  # P (≙ MPI size)
     scale_maps: bool = False  # ≙ ScaleFeatureMaps (sqrt(sj/d) per block)
+    # ≙ CacheTransforms: keep every Z_j for the run (True), remake each
+    # inside every iteration (False), or decide by bytes (None).
+    cache_transforms: bool | None = None
+
+
+class _Maps:
+    """The feature maps as a static argument of the programs: equal when
+    their serialized forms are (a map is a pure function of its JSON)."""
+
+    def __init__(self, maps):
+        self.maps = tuple(maps)
+        self.key = tuple(S.to_json() for S in self.maps)
+
+    def __hash__(self):
+        return hash(self.key)
+
+    def __eq__(self, other):
+        return isinstance(other, _Maps) and self.key == other.key
+
+
+@dataclass(frozen=True)
+class _Spec:
+    """What the programs are keyed by besides their operands' shapes."""
+
+    loss: str
+    reg: str
+    maps: _Maps
+    P: int
+    scale_maps: bool
+    cached: bool  # the blocks are operands (True) or remade from X (False)
+    rho: float
+    lam: float
+
+    @property
+    def sizes(self):
+        return tuple(S.s for S in self.maps.maps)
+
+
+@dataclass
+class _PreparedRun:
+    """Everything ``train``/``chunked`` need that is NOT checkpointable
+    state: the programs' key, their operands (the realized feature blocks
+    or, remade, the partitioned X; the cached Cholesky factors; the
+    targets; ρ and λ) and the initial state tuple.  All of it is
+    deterministically rebuilt from (X, Y, maps, params) on resume — only
+    the state tuple rides the checkpoint."""
+
+    spec: _Spec
+    feats: Any  # Zs [(P, s_j, n_i)] cached, X (n, d) remade
+    Ls: list
+    Yp: Any
+    state0: tuple
+    timer: PhaseTimer
+    d: int
+    classes: Any
+    dtype: Any
+
+    @property
+    def Zs(self):
+        return self.feats if self.spec.cached else None
+
+    @property
+    def operands(self):
+        return self.feats, self.Ls, self.Yp
+
+
+def _device_memory_bytes(X):
+    """The memory of one device that holds X, or None where the backend
+    states none (the CPU)."""
+    dev = next(iter(X.devices()))
+    return (dev.memory_stats() or {}).get("bytes_limit")
+
+
+def _cache_fits(X, sizes) -> bool:
+    """Do X and every feature block of all its rows fit, a device, in
+    ``CACHE_FRACTION`` of the memory?  True where no limit is stated."""
+    limit = _device_memory_bytes(X)
+    if limit is None:
+        return True
+    n, d = X.shape
+    held = (n * d + n * sum(sizes)) * X.dtype.itemsize / len(X.devices())
+    return held <= CACHE_FRACTION * limit
+
+
+# -- the programs -----------------------------------------------------------
+
+
+def _block(spec: _Spec, j: int, X):
+    """Z_j (P, s_j, n_i).  Cached, ``X`` is the partitioned columnwise
+    Xp (P, d, n_i) and this is the apply as it always was.  Remade, it
+    is X (n, d) as the caller holds it -- no partitioned or transposed
+    copy beside it -- and the block is the rowwise features of its
+    partitions seen columnwise, a layout the compiler is free to choose
+    (on a v5e it makes (s_j, n_i) straight from the rows at the
+    columnwise apply's speed: PERF.md section 6, PR 33)."""
+    S = spec.maps.maps[j]
+    with jax.named_scope("admm.features"):
+        if spec.cached:
+            Z = jax.vmap(lambda Xc: S.apply(Xc, Dimension.COLUMNWISE))(X)
+        else:
+            Xp = X.reshape(spec.P, X.shape[0] // spec.P, X.shape[1])
+            Z = jax.vmap(lambda Xr: S.apply(Xr, Dimension.ROWWISE))(Xp)
+            Z = Z.transpose(0, 2, 1)
+        if spec.scale_maps:
+            # the barrier keeps the two scalings two roundings, as they
+            # are op by op (the compiler would fold them into one)
+            Z = lax.optimization_barrier(Z) * jnp.asarray(np.sqrt(S.s / S.n), Z.dtype)
+    return Z
+
+
+def _pieces(A, dtype):
+    """f32 ``A`` as three pieces in the narrower ``dtype`` whose sum is A
+    to 24 bits, stacked on a new leading axis.  ``reduce_precision`` and
+    not a cast there and back: the TPU compiler folds
+    ``convert(convert(x, bf16), f32)`` to x, and the lower pieces with it
+    (PERF.md section 6, PR 33)."""
+    fi = jnp.finfo(dtype)
+    out, r = [], A
+    for _ in range(2):
+        hi = lax.reduce_precision(r, fi.nexp, fi.nmant)
+        out.append(hi.astype(dtype))
+        r = r - hi
+    out.append(r.astype(dtype))
+    return jnp.stack(out)
+
+
+def _thin(eq, a, b):
+    """One of a block's four products with the k columns of the state.
+    Operands of one dtype: the einsum as it always was.  A block narrower
+    than the state: the state's operand goes in as :func:`_pieces` of it
+    along a new axis beside k, one product in the block's dtype with f32
+    accumulation, and the pieces' results are added."""
+    if a.dtype == b.dtype:
+        return jnp.einsum(eq, a, b)
+    ins, out = eq.split("->")
+    ea, eb = ins.split(",")
+    if _is_narrow(a.dtype):
+        b, eb = _pieces(b, a.dtype), "x" + eb
+    else:
+        a, ea = _pieces(a, b.dtype), "x" + ea
+    return jnp.einsum(
+        f"{ea},{eb}->x{out}", a, b, preferred_element_type=jnp.float32
+    ).sum(0)
+
+
+def _gram(Z, dtype):
+    """Z·Zᵀ a partition, in the state's dtype."""
+    if Z.dtype == dtype:
+        # highest: default f32 matmul (bf16 passes on TPU) can push
+        # Z·Zᵀ + I indefinite → silent NaN factors.
+        return jnp.einsum("pst,put->psu", Z, Z, precision="highest")
+    return jnp.einsum("pst,put->psu", Z, Z, preferred_element_type=dtype)
+
+
+def _chol_solve(L, B):  # (P, s, s) x (P, s, k)
+    Ysol = jax.vmap(lambda l, b: solve_triangular(l, b, lower=True))(L, B)
+    return jax.vmap(
+        lambda l, b: solve_triangular(l.T, b, lower=False)
+    )(L, Ysol)
+
+
+def _after(x, *done):
+    """``x`` once ``done`` are computed: the explicit dependence that
+    makes the block loop sequential when the blocks are remade."""
+    return lax.optimization_barrier((x, done))[0]
+
+
+@partial(jax.jit, static_argnames=("D", "k", "P", "ni", "dtype"))
+def _zero_state(*, D, k, P, ni, dtype):
+    """The state every run starts from, in one launch."""
+    small, tall = jnp.zeros((D, k), dtype), jnp.zeros((P, k, ni), dtype)
+    per = jnp.zeros((P, D, k), dtype)
+    # Wbar, W, mu, O, Obar, nu, del_o, mu_ij, ZtObar_ij, obj
+    return (small, small, small, tall, tall, tall, tall, per, per,
+            jnp.zeros((), dtype))
+
+
+@partial(jax.jit, static_argnames=("spec",))
+def admm_transform(Xp, *, spec: _Spec):
+    """Every feature block of the cached route, kept for the run."""
+    return [_block(spec, j, Xp) for j in range(len(spec.sizes))]
+
+
+@partial(jax.jit, static_argnames=("spec", "dtype"))
+def admm_factor(feats, *, spec: _Spec, dtype):
+    """Cholesky of Z·Zᵀ + I per (partition, block)
+    (≙ Cache[j] = inv(Z·Zᵀ + I), BlockADMM.hpp:437-441).  Remade, each
+    block is made here for its Gram product and dropped."""
+    Ls = []
+    for j, s in enumerate(spec.sizes):
+        Z = feats[j] if spec.cached else _block(spec, j, _after(feats, *Ls))
+        with jax.named_scope("admm.factor"):
+            Ls.append(jnp.linalg.cholesky(_gram(Z, dtype) + jnp.eye(s, dtype=dtype)))
+    return Ls
+
+
+def _step(spec: _Spec, state, feats, Ls, Yp):
+    """One ADMM iteration.  ``feats``/``Ls``/``Yp`` are ARGUMENTS of the
+    programs, never closure captures: jit would embed closed-over device
+    arrays as constants in the serialized program."""
+    loss, reg = get_loss(spec.loss), get_regularizer(spec.reg)
+    P, J = spec.P, len(spec.sizes)
+    starts = np.cumsum((0,) + spec.sizes)
+    D = int(starts[-1])
+    Wbar, W, mu, O, Obar, nu, del_o, mu_ij, ZtObar, _ = state
+    k, dtype = Wbar.shape[1], Wbar.dtype
+    rho, lam = jnp.asarray(spec.rho, dtype), jnp.asarray(spec.lam, dtype)
+    if not spec.cached:
+        # nothing of a block is loop-invariant: block 0 waits for the carry
+        feats = _after(feats, Wbar)
+
+    with jax.named_scope("admm.prox"):
+        mu_ij = mu_ij - Wbar[None]
+        Obar = Obar - nu
+        O = jax.vmap(lambda ob, y: loss.prox(ob, 1.0 / rho, y))(Obar, Yp)
+        W = reg.prox(Wbar - mu, lam / rho)
+
+    sum_o = jnp.zeros_like(O)
+    wbar_out = jnp.zeros_like(O)
+    Wi = jnp.zeros((P, D, k), dtype)
+    mu_ij_new = mu_ij
+    ZtObar_new = ZtObar
+    dsum = del_o / (J + 1.0) + nu  # (P, k, ni)
+    for j in range(J):
+        lo, hi = int(starts[j]), int(starts[j + 1])
+        Z = feats[j] if spec.cached else _block(spec, j, feats)  # (P, sj, ni)
+        with jax.named_scope("admm.thin_products"):
+            wbar_out = wbar_out + _thin("psn,sk->pkn", Z, Wbar[lo:hi])
+            rhs = (
+                Wbar[None, lo:hi]
+                - mu_ij[:, lo:hi]
+                + ZtObar[:, lo:hi]
+                + _thin("psn,pkn->psk", Z, dsum)
+            )
+        with jax.named_scope("admm.block_solve"):
+            Wij = _chol_solve(Ls[j], rhs)  # (P, sj, k)
+        with jax.named_scope("admm.thin_products"):
+            o = _thin("psk,psn->pkn", Wij, Z)
+            Wi = Wi.at[:, lo:hi].set(Wij)
+            mu_ij_new = mu_ij_new.at[:, lo:hi].add(Wij)
+            ZtObar_new = ZtObar_new.at[:, lo:hi].set(
+                _thin("psn,pkn->psk", Z, o)
+            )
+            sum_o = sum_o + o
+        if not spec.cached:
+            # block j + 1 is made when block j's products are done
+            feats = _after(feats, wbar_out, sum_o, ZtObar_new)
+
+    del_o = O - sum_o
+    Obar = O - del_o / (J + 1.0)
+    nu = nu + O - Obar
+    # Consensus: sum over partitions (psum over ICI when sharded)
+    # ≙ the MPI reduce of Wi (BlockADMM.hpp:574-578).
+    Wbar = (jnp.sum(Wi, axis=0) + W) / (P + 1.0)
+    mu = mu + W - Wbar
+    obj = jax.vmap(loss.evaluate)(wbar_out, Yp).sum() + lam * reg.evaluate(Wbar)
+    return (Wbar, W, mu, O, Obar, nu, del_o, mu_ij_new, ZtObar_new, obj)
+
+
+admm_step = jax.jit(_step, static_argnames=("spec",))
+
+
+@partial(jax.jit, static_argnames=("spec", "maxiter"))
+def admm_iterate(state, feats, Ls, Yp, *, spec: _Spec, maxiter: int):
+    """All iterations in ONE ``lax.scan``: the per-iteration objective
+    readback costs a full host round-trip and a device sync, so sync
+    once at the end and report the whole objective trace from the
+    returned array."""
+
+    def body(st, _):
+        st = _step(spec, st, feats, Ls, Yp)
+        return st, st[-1]
+
+    return lax.scan(body, state, None, length=maxiter)
+
+
+@partial(jax.jit, static_argnames=("spec", "maxiter", "num_iters"))
+def admm_chunk(st, feats, Ls, Yp, *, spec: _Spec, maxiter: int, num_iters: int):
+    """At most ``num_iters`` iterations of a chunked run's state
+    ``dict(it, inner, objs)``: the same step body under a while loop."""
+    stop = jnp.minimum(st["it"] + num_iters, maxiter)
+
+    def cond(c):
+        return c["it"] < stop
+
+    def body(c):
+        inner = _step(spec, c["inner"], feats, Ls, Yp)
+        return dict(
+            it=c["it"] + 1,
+            inner=inner,
+            objs=c["objs"].at[c["it"]].set(inner[-1]),
+        )
+
+    return lax.while_loop(cond, body, st)
+
+
+# -- the solver -------------------------------------------------------------
 
 
 class BlockADMMSolver:
@@ -90,7 +404,7 @@ class BlockADMMSolver:
         self,
         loss: str,
         regularizer: str,
-        feature_maps: Sequence,
+        feature_maps,
         params: ADMMParams | None = None,
     ):
         self.loss = get_loss(loss)
@@ -100,17 +414,11 @@ class BlockADMMSolver:
             raise ValueError("BlockADMMSolver needs at least one feature map")
         self.params = params or ADMMParams()
 
-    def _apply_map(self, S, Xp, d):
-        """Vmapped columnwise feature apply: Xp (P, d, ni) → (P, sj, ni)."""
-        Z = jax.vmap(lambda Xc: S.apply(Xc, Dimension.COLUMNWISE))(Xp)
-        if self.params.scale_maps:
-            Z = Z * jnp.asarray(np.sqrt(S.s / d), Z.dtype)
-        return Z
-
     def _prepare(self, X, Y, classes=None, regression: bool = False) -> _PreparedRun:
-        """Shared setup for :meth:`train` and :meth:`chunked`: realize the
-        feature blocks, cache the Cholesky factors, build the jittable
-        per-iteration step and the initial state tuple."""
+        """Shared setup for :meth:`train` and :meth:`chunked`: code the
+        labels, pick the route, realize the feature blocks (cached) or
+        hand X on (remade), cache the Cholesky factors, build the
+        initial state tuple."""
         p = self.params
         X = X.todense() if hasattr(X, "todense") else jnp.asarray(X)
         n, d = X.shape
@@ -118,129 +426,89 @@ class BlockADMMSolver:
         if n % P:
             raise ValueError(f"n={n} not divisible by data_partitions={P}")
         ni = n // P
-
-        label_based = getattr(self.loss, "label_based", False)
-        if regression:
-            T = jnp.asarray(Y)
-            T = T[:, None] if T.ndim == 1 else T
-            k = T.shape[1]
-            Yp = T.reshape(P, ni, k).transpose(0, 2, 1)
-        else:
-            T, classes = dummy_coding(Y, classes, dtype=X.dtype)
-            k = T.shape[1]
-            if label_based:
-                # Hinge/logistic take class indices (≙ the reference's
-                # crammed losses consuming the raw label vector).
-                cls = jnp.asarray(
-                    np.searchsorted(np.asarray(classes), np.asarray(Y))
-                ).astype(X.dtype)
-                Yp = cls.reshape(P, ni)
-            else:
-                Yp = T.reshape(P, ni, k).transpose(0, 2, 1)
-
-        # Partitioned columnwise layout: Xp (P, d, ni).
-        Xp = X.reshape(P, ni, d).transpose(0, 2, 1)
-        dtype = X.dtype
-
-        J = len(self.maps)
-        sizes = [S.s for S in self.maps]
-        starts = np.cumsum([0] + sizes)
-        D = int(starts[-1])
+        # Narrow rows: the features take X's dtype, everything else is f32.
+        dtype = jnp.dtype(jnp.float32) if _is_narrow(X.dtype) else X.dtype
 
         # Phase timers ≙ the reference's ADMM SKYLARK_TIMER instrumentation
-        # (transform/iteration/prediction, BlockADMM.hpp:357-365).
+        # (transform/iteration/prediction, BlockADMM.hpp:357-365); each
+        # phase opens the stage span of its name.
         timer = PhaseTimer()
-        with timer.phase("transform") as ph:
-            Zs = [self._apply_map(S, Xp, d) for S in self.maps]  # (P, sj, ni)
-            ph.result = Zs
-        # Cached Cholesky of Z·Zᵀ + I per (partition, block)
-        # (≙ Cache[j] = inv(Z·Zᵀ + I), BlockADMM.hpp:437-441).
-        with timer.phase("factor") as ph:
-            Ls = [
-                jnp.linalg.cholesky(
-                    # highest: default f32 matmul (bf16 passes on TPU) can
-                    # push Z·Zᵀ + I indefinite → silent NaN factors.
-                    jnp.einsum("pst,put->psu", Z, Z, precision="highest")
-                    + jnp.eye(Z.shape[1], dtype=dtype)
-                )
-                for Z in Zs
-            ]
-            ph.result = Ls
+        with timer.phase("admm.labels") as ph:
+            label_based = getattr(self.loss, "label_based", False)
+            if regression:
+                T = jnp.asarray(Y)
+                T = T[:, None] if T.ndim == 1 else T
+                k = T.shape[1]
+                Yp = T.reshape(P, ni, k).transpose(0, 2, 1)
+            elif isinstance(Y, jax.Array):
+                # labels on the device are coded there: no read of n
+                # labels to the host and no copy back
+                cls, classes = class_indices(Y, classes)
+                k = len(classes)
+                if label_based:
+                    Yp = cls.astype(dtype).reshape(P, ni)
+                else:
+                    T = jnp.where(cls[:, None] == jnp.arange(k), 1.0, -1.0)
+                    Yp = T.astype(dtype).reshape(P, ni, k).transpose(0, 2, 1)
+            else:
+                T, classes = dummy_coding(Y, classes, dtype=dtype)
+                k = T.shape[1]
+                if label_based:
+                    # Hinge/logistic take class indices (≙ the reference's
+                    # crammed losses consuming the raw label vector).
+                    cls = jnp.asarray(
+                        np.searchsorted(np.asarray(classes), np.asarray(Y))
+                    ).astype(dtype)
+                    Yp = cls.reshape(P, ni)
+                else:
+                    Yp = T.reshape(P, ni, k).transpose(0, 2, 1)
+            ph.result = Yp
 
-        rho = jnp.asarray(p.rho, dtype)
-        lam = jnp.asarray(p.lam, dtype)
-        loss, reg = self.loss, self.regularizer
-
-        def chol_solve(L, B):  # (P, s, s) x (P, s, k)
-            Ysol = jax.vmap(lambda l, b: solve_triangular(l, b, lower=True))(L, B)
-            return jax.vmap(
-                lambda l, b: solve_triangular(l.T, b, lower=False)
-            )(L, Ysol)
-
-        # Zs/Ls/Yp enter as ARGUMENTS, not closure captures: jit would
-        # embed closed-over device arrays as constants in the serialized
-        # program (gigabytes of HLO — rejected/slow on AOT compile
-        # services) instead of referencing device-resident buffers.
-        def step(state, Zs, Ls, Yp):
-            Wbar, W, mu, O, Obar, nu, del_o, mu_ij, ZtObar, _ = state
-            mu_ij = mu_ij - Wbar[None]
-            Obar = Obar - nu
-            O = jax.vmap(lambda ob, y: loss.prox(ob, 1.0 / rho, y))(Obar, Yp)
-            W = reg.prox(Wbar - mu, lam / rho)
-
-            sum_o = jnp.zeros_like(O)
-            wbar_out = jnp.zeros_like(O)
-            Wi = jnp.zeros((P, D, k), dtype)
-            mu_ij_new = mu_ij
-            ZtObar_new = ZtObar
-            dsum = del_o / (J + 1.0) + nu  # (P, k, ni)
-            for j in range(J):
-                lo, hi = int(starts[j]), int(starts[j + 1])
-                Z = Zs[j]  # (P, sj, ni)
-                wbar_out = wbar_out + jnp.einsum(
-                    "psn,sk->pkn", Z, Wbar[lo:hi]
-                )
-                rhs = (
-                    Wbar[None, lo:hi]
-                    - mu_ij[:, lo:hi]
-                    + ZtObar[:, lo:hi]
-                    + jnp.einsum("psn,pkn->psk", Z, dsum)
-                )
-                Wij = chol_solve(Ls[j], rhs)  # (P, sj, k)
-                o = jnp.einsum("psk,psn->pkn", Wij, Z)
-                Wi = Wi.at[:, lo:hi].set(Wij)
-                mu_ij_new = mu_ij_new.at[:, lo:hi].add(Wij)
-                ZtObar_new = ZtObar_new.at[:, lo:hi].set(
-                    jnp.einsum("psn,pkn->psk", Z, o)
-                )
-                sum_o = sum_o + o
-
-            del_o = O - sum_o
-            Obar = O - del_o / (J + 1.0)
-            nu = nu + O - Obar
-            # Consensus: sum over partitions (psum over ICI when sharded)
-            # ≙ the MPI reduce of Wi (BlockADMM.hpp:574-578).
-            Wbar = (jnp.sum(Wi, axis=0) + W) / (P + 1.0)
-            mu = mu + W - Wbar
-            obj = jax.vmap(loss.evaluate)(wbar_out, Yp).sum() + lam * reg.evaluate(Wbar)
-            return (Wbar, W, mu, O, Obar, nu, del_o, mu_ij_new, ZtObar_new, obj)
-
-        state = (
-            jnp.zeros((D, k), dtype),        # Wbar
-            jnp.zeros((D, k), dtype),        # W
-            jnp.zeros((D, k), dtype),        # mu
-            jnp.zeros((P, k, ni), dtype),    # O
-            jnp.zeros((P, k, ni), dtype),    # Obar
-            jnp.zeros((P, k, ni), dtype),    # nu
-            jnp.zeros((P, k, ni), dtype),    # del_o
-            jnp.zeros((P, D, k), dtype),     # mu_ij
-            jnp.zeros((P, D, k), dtype),     # ZtObar_ij
-            jnp.zeros((), dtype),            # obj
+        sizes = [S.s for S in self.maps]
+        D = int(sum(sizes))
+        cached = p.cache_transforms
+        if cached is None:
+            cached = _cache_fits(X, sizes)
+        spec = _Spec(
+            loss=self.loss.name, reg=self.regularizer.name,
+            maps=_Maps(self.maps), P=P, scale_maps=bool(p.scale_maps),
+            cached=bool(cached), rho=float(p.rho), lam=float(p.lam),
         )
+        with timer.phase("admm.transform") as ph:
+            if spec.cached:
+                # Partitioned columnwise layout: Xp (P, d, ni).
+                Xp = X.reshape(P, ni, d).transpose(0, 2, 1)
+                feats = admm_transform(Xp, spec=spec)  # [(P, sj, ni)]
+            else:
+                feats = X  # the blocks are made from it inside the programs
+            ph.result = feats
+        with timer.phase("admm.factor") as ph:
+            Ls = ph.result = admm_factor(feats, spec=spec, dtype=dtype)
+
+        state = _zero_state(D=D, k=int(k), P=P, ni=ni, dtype=dtype)
         return _PreparedRun(
-            Zs=Zs, Ls=Ls, Yp=Yp, state0=state, step=step, timer=timer,
-            d=d, classes=classes, dtype=dtype,
+            spec=spec, feats=feats, Ls=Ls, Yp=Yp, state0=state, timer=timer, d=d, classes=classes, dtype=dtype,
         )
+
+    def _model(self, run: _PreparedRun, Wbar, history, val_history=()):
+        p = self.params
+        model = FeatureMapModel(
+            self.maps, Wbar, scale_maps=p.scale_maps, input_dim=run.d,
+            classes=run.classes,
+        )
+        model.history = list(history)
+        model.val_history = list(val_history)
+        model.timers = run.timer
+        model.info = {
+            "iterations": len(model.history),
+            "feature_blocks": len(self.maps),
+            "transforms_cached": int(run.spec.cached),
+            # passes over X an iteration: a remade block is held between
+            # its products before and after the solve, not made twice
+            "feature_passes": 0 if run.spec.cached else 1,
+            "objective": model.history[-1] if model.history else None,
+        }
+        return model
 
     def train(self, X, Y, classes=None, regression: bool = False,
               Xv=None, Yv=None):
@@ -248,13 +516,17 @@ class BlockADMMSolver:
         (regression).  Optional validation set (Xv, Yv) is scored every
         iteration (≙ the per-iteration validation predict,
         ``BlockADMM.hpp:509-540``) into ``model.val_history``.  Returns a
-        ``FeatureMapModel`` (with ``.classes`` and ``.history`` attached).
-        BCOO input is densified (the partitioned reshape needs strides)."""
+        ``FeatureMapModel`` (with ``.classes``, ``.history`` and
+        ``.info`` attached).  BCOO input is densified (the partitioned
+        reshape needs strides)."""
+        with telemetry.span("block_admm_train"):
+            return self._train(X, Y, classes, regression, Xv, Yv)
+
+    def _train(self, X, Y, classes, regression, Xv, Yv):
         compile_cache.place()
         p = self.params
         run = self._prepare(X, Y, classes, regression)
-        Zs, Ls, Yp = run.Zs, run.Ls, run.Yp
-        state, step, timer = run.state0, run.step, run.timer
+        state, timer = run.state0, run.timer
         d, classes = run.d, run.classes
         have_val = Xv is not None and Yv is not None
         if have_val:
@@ -263,28 +535,17 @@ class BlockADMMSolver:
 
         history, val_history = [], []
         if not have_val:
-            # All iterations in ONE jitted lax.scan: the per-iteration
-            # objective readback costs a full host round-trip and a
-            # device sync, so sync once at the end and report the
-            # whole objective trace from the returned array.
-            @jax.jit
-            def run_all(state, Zs, Ls, Yp):
-                def body(st, _):
-                    st = step(st, Zs, Ls, Yp)
-                    return st, st[-1]
-
-                return jax.lax.scan(body, state, None, length=p.maxiter)
-
-            with timer.phase("iteration"):
-                state, objs = run_all(state, Zs, Ls, Yp)
+            with timer.phase("admm.iterate") as ph:
+                state, objs = ph.result = admm_iterate(
+                    state, *run.operands, spec=run.spec, maxiter=int(p.maxiter))
+            with timer.phase("admm.result"):
                 history = [float(o) for o in np.asarray(objs)]
             for it, obj in enumerate(history, 1):
                 p.log(1, f"iteration {it} objective {obj:.6e}")
         else:
-            step = jax.jit(step)
             for it in range(1, p.maxiter + 1):
-                with timer.phase("iteration"):
-                    state = step(state, Zs, Ls, Yp)
+                with timer.phase("admm.iterate"):
+                    state = admm_step(run.spec, state, *run.operands)
                     obj = float(state[-1])  # readback syncs the step
                 history.append(obj)
                 msg = f"iteration {it} objective {obj:.6e}"
@@ -309,24 +570,17 @@ class BlockADMMSolver:
                 p.log(1, msg)
 
         p.log(2, timer.report())
-        Wbar = state[0]
-        model = FeatureMapModel(
-            self.maps, Wbar, scale_maps=p.scale_maps, input_dim=d,
-            classes=classes,
-        )
-        model.history = history
-        model.val_history = val_history
-        model.timers = timer
-        return model
+        return self._model(run, state[0], history, val_history)
 
     def chunked(self, X, Y, classes=None, regression: bool = False) -> ChunkedSolver:
         """Preemption-safe ADMM: a ``ChunkedSolver`` whose state pytree is
         (iteration counter, the 10-tuple ADMM state, objective trace) —
         exactly what a resumed process cannot recompute.  The feature
-        blocks, Cholesky factors, and targets are rebuilt by
-        :meth:`_prepare` on resume (deterministic: counter-based maps,
-        pinned-precision factor products), so a run resumed from a chunk
-        boundary is bit-identical to the uninterrupted chunked run.
+        blocks (or, remade, X itself), Cholesky factors, and
+        targets are rebuilt by :meth:`_prepare` on resume (deterministic:
+        counter-based maps, pinned-precision factor products), so a run
+        resumed from a chunk boundary is bit-identical to the
+        uninterrupted chunked run.
         That kill/resume bit-identity — and the chunked-vs-``train()``
         model parity it rides on — is PINNED by
         ``tests/test_distributed_train.py::TestChunkedContract`` (the
@@ -336,9 +590,8 @@ class BlockADMMSolver:
         Validation scoring is a ``train``-only feature; drive this with
         ``resilient.ResilientRunner`` and score the returned model.
         """
-        p = self.params
         run = self._prepare(X, Y, classes, regression)
-        maxiter = int(p.maxiter)
+        maxiter = int(self.params.maxiter)
 
         def init_state():
             return dict(
@@ -347,39 +600,15 @@ class BlockADMMSolver:
                 objs=jnp.zeros((maxiter,), run.dtype),
             )
 
-        # Zs/Ls/Yp enter as ARGUMENTS for the same reason as in train():
-        # jit would bake closed-over device arrays into the program as
-        # constants.
-        @partial(jax.jit, static_argnames=("num_iters",))
-        def _chunk(st, Zs, Ls, Yp, num_iters: int):
-            stop = jnp.minimum(st["it"] + num_iters, maxiter)
-
-            def cond(c):
-                return c["it"] < stop
-
-            def body(c):
-                inner = run.step(c["inner"], Zs, Ls, Yp)
-                return dict(
-                    it=c["it"] + 1,
-                    inner=inner,
-                    objs=c["objs"].at[c["it"]].set(inner[-1]),
-                )
-
-            return lax.while_loop(cond, body, st)
-
         def step_chunk(st, num_iters: int):
-            return _chunk(st, run.Zs, run.Ls, run.Yp, num_iters)
+            return admm_chunk(st, *run.operands, spec=run.spec,
+                              maxiter=maxiter, num_iters=num_iters)
 
         def extract_result(st):
             it = int(st["it"])
-            model = FeatureMapModel(
-                self.maps, st["inner"][0], scale_maps=p.scale_maps,
-                input_dim=run.d, classes=run.classes,
-            )
-            model.history = [float(o) for o in np.asarray(st["objs"][:it])]
-            model.val_history = []
-            model.timers = run.timer
-            return model
+            return self._model(
+                run, st["inner"][0],
+                [float(o) for o in np.asarray(st["objs"][:it])])
 
         return ChunkedSolver(
             init_state=init_state,

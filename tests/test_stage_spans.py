@@ -77,6 +77,16 @@ def _krr_train():
     return model.W
 
 
+def _block_admm(cache_transforms=False):
+    rng = np.random.default_rng(9)
+    X = jnp.asarray(rng.standard_normal((ROWS, D)), jnp.float32)
+    y = np.argmax(np.asarray(X)[:, :3], axis=1)
+    kernel, ctx = ml.GaussianKernel(D, sigma=3.0), SketchContext(seed=3)
+    maps = [kernel.create_rft(S, "regular", ctx) for _ in range(2)]
+    return ml.BlockADMMSolver("hinge", "l2", maps, ml.ADMMParams(
+        maxiter=3, cache_transforms=cache_transforms)).train(X, y).W
+
+
 # path -> (call, entry span or None, {stage span: times a call})
 PATHS = {
     # the sketch is the planned apply: its two spans open under `.sketch`
@@ -101,6 +111,11 @@ PATHS = {
     "krr_train": (_krr_train, "krr_train", {
         "krr.programs": 1, "krr.gram": 1, "krr.factor": 1, "krr.zr": 2,
         "krr.solve": 2, "krr.apply_delta": 2, "krr.converge": 4}),
+    # the stages of one BlockADMM call, blocks remade: the factor program
+    # and the scan of all iterations are one launch each
+    "block_admm_train": (_block_admm, "block_admm_train", {
+        "admm.labels": 1, "admm.transform": 1, "admm.factor": 1,
+        "admm.iterate": 1, "admm.result": 1}),
 }
 PHASES = {"krr_train": {"sweep0": 1, "sweep": 1}}  # PhaseTimer's, no dot: no stage
 
@@ -271,6 +286,43 @@ def test_with_telemetry_on_span_end_says_what_jax_built_inside(tmp_path, monkeyp
     assert per_name["calls"] == 2 and per_name["lowerings"] == 1
     assert per_name["lower_s"] == pytest.approx(cold["lower_s"], abs=1e-5)
     assert "lowerings" not in snap["spans"].get("guard.check", {})
+
+
+@pytest.mark.parametrize("cache", [True, False], ids=["cached", "remade"])
+def test_a_second_block_admm_call_builds_no_program(tmp_path, monkeypatch, cache):
+    """Transform, factor and the iteration scan are module-level programs
+    keyed by shapes and the serialized maps: a second ``train`` at the
+    same shapes -- a new solver, new map objects -- traces, lowers and
+    compiles nothing, on either route."""
+    off = _block_admm(cache)
+    monkeypatch.setenv("SKYLARK_TELEMETRY", "1")
+    telemetry.configure(str(tmp_path))
+    telemetry.reset()
+    try:
+        on = _block_admm(cache)
+        telemetry.flush()
+        with open(telemetry.ledger_path()) as fh:
+            events = [json.loads(line) for line in fh]
+    finally:
+        telemetry.close()
+        telemetry.configure(None)
+        telemetry.reset()
+    assert np.asarray(on).tobytes() == np.asarray(off).tobytes()
+    ends = {e["name"]: e["attrs"] for e in events if e["kind"] == "span_end"}
+    assert set(ends) == {"block_admm_train"}  # the stages are the timer's phases
+    assert not {"lowerings", "traces", "compiles"} & set(ends["block_admm_train"])
+
+
+def test_each_block_admm_stage_launches_one_program(tmp_path_factory):
+    """The benchmark reads the factor and iterate programs' device time
+    by the spans they are launched under."""
+    _block_admm()
+    trace_dir = tmp_path_factory.mktemp("trace_admm_launches")
+    _, spans = under_profiler(_block_admm, trace_dir)
+    launches = [s for _, s, _ in _events_of(str(trace_dir), LAUNCH.__eq__)]
+    for stage in ("admm.factor", "admm.iterate"):
+        (lo, hi), = [(s, e) for name, s, e in spans if name == stage]
+        assert sum(lo <= t <= hi for t in launches) == 1, stage
 
 
 def test_the_one_place_that_builds_an_annotation():

@@ -254,3 +254,41 @@ def test_streaming_krr_feature_pass_is_one_output_fusion(one_chip, program):
     name = re.match(r"%?(\S+)", body).group(1)
     call = re.search(rf"fusion\([^\n]*kind=(\w+), calls=%?{re.escape(name)}\b", text)
     assert call and call.group(1) == "kOutput"
+
+
+def test_block_admm_remade_iterate_holds_one_block_at_full_size(one_chip):
+    """The BlockADMM iteration with its feature blocks remade, at the
+    benchmark cell's size (2,097,152 x 784 bf16 rows, 4 blocks of 1024
+    features, 10 classes): all four blocks are 17.2 GB and one is 4.3,
+    so the program fits only while one is live at a time.  Its
+    operations carry the four stage scopes, and the f32 operands of the
+    thin products reach the bf16 blocks as ``reduce-precision`` pieces
+    (a cast there and back is folded away by this compiler)."""
+    from libskylark_tpu.ml import GaussianKernel, admm
+
+    N, D, SJ, J, K_ = 2**21, 784, 1024, 4, 10
+    ctx = SketchContext(seed=9)
+    maps = [GaussianKernel(D, sigma=28.0).create_rft(SJ, "regular", ctx)
+            for _ in range(J)]
+    spec = admm._Spec(loss="hinge", reg="l2", maps=admm._Maps(maps), P=1,
+                      scale_maps=False, cached=False, rho=1.0, lam=0.01)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    small, tall = shaped((J * SJ, K_), F32), shaped((1, K_, N), F32)
+    per = shaped((1, J * SJ, K_), F32)
+    state = (small,) * 3 + (tall,) * 4 + (per,) * 2 + (shaped((), F32),)
+    with jax.enable_x64(False):
+        compiled = admm.admm_iterate.lower(
+            state, shaped((N, D), BF16), [shaped((1, SJ, SJ), F32)] * J,
+            shaped((1, N), F32), spec=spec, maxiter=2).compile()
+    block = 2 * N * SJ
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert block < temp < 2 * block, temp / 1e9
+    text = compiled.as_text()
+    for scope in ("admm.features", "admm.thin_products", "admm.prox",
+                  "admm.block_solve"):
+        assert scope in text, scope
+    assert "reduce-precision" in text
+    assert "rft.epilogue.turns" in text
