@@ -35,11 +35,27 @@ all rows before the first iteration and kept for the run (``Zs``,
 ``(P, s_j, n_i)``): the blocks are an unrolled loop of GEMMs over
 resident operands.  Remade (upstream's default, and what lets features ×
 rows exceed memory), the iteration's operands are X and the maps; block
-j's ``Z_j`` is made inside the iteration, held for its products before
-and after the solve, and dropped: one block is live at a time, the
-block loop made sequential in the program by an explicit dependence.
-``None`` caches when all blocks fit beside X in ``CACHE_FRACTION`` of
-the device's memory.  The two routes share one step body.
+j's ``Z_j`` is made inside the iteration, held for two products and
+dropped: one block is live at a time, the block loop made sequential in
+the program by an explicit dependence.  ``None`` caches when all blocks
+fit beside X in ``CACHE_FRACTION`` of the device's memory.  The two
+routes share one step body.
+
+**A block's products.**  The recurrence above meets ``Z_j`` four times:
+``Wbar_jᵀ Z_j`` (the objective's), ``Z_j·dsumᵀ`` (the right-hand
+side's), then after the solve ``o_j = Wi_jᵀ Z_j`` and ``ZtObar_j =
+Z_j·o_jᵀ``.  A remade block is a temporary that is written, read and
+dropped, and its reads set the pace, so that route reads it twice:
+``Z_j·dsumᵀ`` before the solve; after it ONE product whose small operand
+is ``Wbar_j`` and ``Wi_j`` side by side along k (both are known by then,
+``Wbar_j`` since the iteration began), the first k columns of the result
+the objective's, the rest ``o_j`` (summed over the blocks side by side
+and split once, after the loop); and ``ZtObar_j = Z_j Z_jᵀ Wi_j =
+G_j Wi_j`` from the block's Gram matrix, which ``admm_factor`` forms
+once a call for ``L_j`` and keeps beside it (``Gs``: s_j × s_j, no read
+of the block).  The cached route keeps the four products in the
+recurrence's order: its bits are pinned to ``ml/distributed.py``'s own
+copy of the step, and ``G_j Wi_j`` is another order of sums.
 
 **Programs.**  Transform, factor and the iteration scan are module-level
 ``jax.jit`` programs keyed by shapes and a hashable ``_Spec`` (loss,
@@ -146,6 +162,7 @@ class _PreparedRun:
     spec: _Spec
     feats: Any  # Zs [(P, s_j, n_i)] cached, X (n, d) remade
     Ls: list
+    Gs: list  # Z_j·Z_jᵀ (P, s_j, s_j) remade, none cached
     Yp: Any
     state0: tuple
     timer: PhaseTimer
@@ -159,7 +176,7 @@ class _PreparedRun:
 
     @property
     def operands(self):
-        return self.feats, self.Ls, self.Yp
+        return self.feats, self.Ls, self.Gs, self.Yp
 
 
 def _device_memory_bytes(X):
@@ -223,7 +240,7 @@ def _pieces(A, dtype):
 
 
 def _thin(eq, a, b):
-    """One of a block's four products with the k columns of the state.
+    """One of a block's products with the k (or 2k) columns of the state.
     Operands of one dtype: the einsum as it always was.  A block narrower
     than the state: the state's operand goes in as :func:`_pieces` of it
     along a new axis beside k, one product in the block's dtype with f32
@@ -258,9 +275,13 @@ def _chol_solve(L, B):  # (P, s, s) x (P, s, k)
 
 
 def _after(x, *done):
-    """``x`` once ``done`` are computed: the explicit dependence that
-    makes the block loop sequential when the blocks are remade."""
-    return lax.optimization_barrier((x, done))[0]
+    """``(x, *done)``, x once ``done`` are computed: the explicit
+    dependence that makes the block loop sequential when the blocks are
+    remade.  The caller goes on with EVERY output: what nothing reads of
+    a barrier is pruned from it, and the dependence with it (the program
+    compiled for a v5e then made two blocks side by side: PERF.md
+    section 6, PR 34)."""
+    return lax.optimization_barrier((x, *done))
 
 
 @partial(jax.jit, static_argnames=("D", "k", "P", "ni", "dtype"))
@@ -281,21 +302,30 @@ def admm_transform(Xp, *, spec: _Spec):
 
 @partial(jax.jit, static_argnames=("spec", "dtype"))
 def admm_factor(feats, *, spec: _Spec, dtype):
-    """Cholesky of Z·Zᵀ + I per (partition, block)
-    (≙ Cache[j] = inv(Z·Zᵀ + I), BlockADMM.hpp:437-441).  Remade, each
-    block is made here for its Gram product and dropped."""
-    Ls = []
+    """``(Ls, Gs)``: the Cholesky factor of Z·Zᵀ + I per (partition,
+    block) (≙ Cache[j] = inv(Z·Zᵀ + I), BlockADMM.hpp:437-441).  Remade,
+    each block is made here for its Gram product and dropped, and the
+    Gram matrices are kept too (``Gs``; none cached): the iteration
+    takes ``ZtObar_j`` from them."""
+    Ls, Gs = [], []
     for j, s in enumerate(spec.sizes):
-        Z = feats[j] if spec.cached else _block(spec, j, _after(feats, *Ls))
+        if spec.cached:
+            Z = feats[j]
+        else:
+            feats, *Ls = _after(feats, *Ls)
+            Z = _block(spec, j, feats)
         with jax.named_scope("admm.factor"):
-            Ls.append(jnp.linalg.cholesky(_gram(Z, dtype) + jnp.eye(s, dtype=dtype)))
-    return Ls
+            G = _gram(Z, dtype)
+            Ls.append(jnp.linalg.cholesky(G + jnp.eye(s, dtype=dtype)))
+            if not spec.cached:
+                Gs.append(G)
+    return Ls, Gs
 
 
-def _step(spec: _Spec, state, feats, Ls, Yp):
-    """One ADMM iteration.  ``feats``/``Ls``/``Yp`` are ARGUMENTS of the
-    programs, never closure captures: jit would embed closed-over device
-    arrays as constants in the serialized program."""
+def _step(spec: _Spec, state, feats, Ls, Gs, Yp):
+    """One ADMM iteration.  ``feats``/``Ls``/``Gs``/``Yp`` are ARGUMENTS
+    of the programs, never closure captures: jit would embed closed-over
+    device arrays as constants in the serialized program."""
     loss, reg = get_loss(spec.loss), get_regularizer(spec.reg)
     P, J = spec.P, len(spec.sizes)
     starts = np.cumsum((0,) + spec.sizes)
@@ -305,7 +335,7 @@ def _step(spec: _Spec, state, feats, Ls, Yp):
     rho, lam = jnp.asarray(spec.rho, dtype), jnp.asarray(spec.lam, dtype)
     if not spec.cached:
         # nothing of a block is loop-invariant: block 0 waits for the carry
-        feats = _after(feats, Wbar)
+        feats, Wbar = _after(feats, Wbar)
 
     with jax.named_scope("admm.prox"):
         mu_ij = mu_ij - Wbar[None]
@@ -313,8 +343,13 @@ def _step(spec: _Spec, state, feats, Ls, Yp):
         O = jax.vmap(lambda ob, y: loss.prox(ob, 1.0 / rho, y))(Obar, Yp)
         W = reg.prox(Wbar - mu, lam / rho)
 
-    sum_o = jnp.zeros_like(O)
-    wbar_out = jnp.zeros_like(O)
+    if spec.cached:
+        wbar_out = sum_o = jnp.zeros_like(O)
+    else:
+        # Σ_j of (Wbar_jᵀZ_j, o_j) side by side along k, as the stacked
+        # product makes them, split once after the loop (two sums split a
+        # block at a time cost 1.1 % of a call: PERF.md section 6, PR 34)
+        outs = jnp.zeros((P, 2 * k) + O.shape[2:], dtype)
     Wi = jnp.zeros((P, D, k), dtype)
     mu_ij_new = mu_ij
     ZtObar_new = ZtObar
@@ -323,7 +358,6 @@ def _step(spec: _Spec, state, feats, Ls, Yp):
         lo, hi = int(starts[j]), int(starts[j + 1])
         Z = feats[j] if spec.cached else _block(spec, j, feats)  # (P, sj, ni)
         with jax.named_scope("admm.thin_products"):
-            wbar_out = wbar_out + _thin("psn,sk->pkn", Z, Wbar[lo:hi])
             rhs = (
                 Wbar[None, lo:hi]
                 - mu_ij[:, lo:hi]
@@ -333,17 +367,28 @@ def _step(spec: _Spec, state, feats, Ls, Yp):
         with jax.named_scope("admm.block_solve"):
             Wij = _chol_solve(Ls[j], rhs)  # (P, sj, k)
         with jax.named_scope("admm.thin_products"):
-            o = _thin("psk,psn->pkn", Wij, Z)
+            if spec.cached:
+                # the recurrence's products, to the bit ml/distributed.py's
+                wbar_out = wbar_out + _thin("psn,sk->pkn", Z, Wbar[lo:hi])
+                o = _thin("psk,psn->pkn", Wij, Z)
+                zto = _thin("psn,pkn->psk", Z, o)
+                sum_o = sum_o + o
+            else:
+                # the block's second and last read: the objective's
+                # product rides with o_j's, and Z_j·o_jᵀ = G_j·Wi_j
+                both = jnp.concatenate(
+                    [jnp.broadcast_to(Wbar[lo:hi], Wij.shape), Wij], axis=2)
+                outs = outs + _thin("psk,psn->pkn", both, Z)
+                zto = jnp.einsum("psu,puk->psk", Gs[j], Wij, precision="highest")
             Wi = Wi.at[:, lo:hi].set(Wij)
             mu_ij_new = mu_ij_new.at[:, lo:hi].add(Wij)
-            ZtObar_new = ZtObar_new.at[:, lo:hi].set(
-                _thin("psn,pkn->psk", Z, o)
-            )
-            sum_o = sum_o + o
+            ZtObar_new = ZtObar_new.at[:, lo:hi].set(zto)
         if not spec.cached:
             # block j + 1 is made when block j's products are done
-            feats = _after(feats, wbar_out, sum_o, ZtObar_new)
+            feats, outs = _after(feats, outs)
 
+    if not spec.cached:
+        wbar_out, sum_o = outs[:, :k], outs[:, k:]
     del_o = O - sum_o
     Obar = O - del_o / (J + 1.0)
     nu = nu + O - Obar
@@ -359,21 +404,21 @@ admm_step = jax.jit(_step, static_argnames=("spec",))
 
 
 @partial(jax.jit, static_argnames=("spec", "maxiter"))
-def admm_iterate(state, feats, Ls, Yp, *, spec: _Spec, maxiter: int):
+def admm_iterate(state, feats, Ls, Gs, Yp, *, spec: _Spec, maxiter: int):
     """All iterations in ONE ``lax.scan``: the per-iteration objective
     readback costs a full host round-trip and a device sync, so sync
     once at the end and report the whole objective trace from the
     returned array."""
 
     def body(st, _):
-        st = _step(spec, st, feats, Ls, Yp)
+        st = _step(spec, st, feats, Ls, Gs, Yp)
         return st, st[-1]
 
     return lax.scan(body, state, None, length=maxiter)
 
 
 @partial(jax.jit, static_argnames=("spec", "maxiter", "num_iters"))
-def admm_chunk(st, feats, Ls, Yp, *, spec: _Spec, maxiter: int, num_iters: int):
+def admm_chunk(st, feats, Ls, Gs, Yp, *, spec: _Spec, maxiter: int, num_iters: int):
     """At most ``num_iters`` iterations of a chunked run's state
     ``dict(it, inner, objs)``: the same step body under a while loop."""
     stop = jnp.minimum(st["it"] + num_iters, maxiter)
@@ -382,7 +427,7 @@ def admm_chunk(st, feats, Ls, Yp, *, spec: _Spec, maxiter: int, num_iters: int):
         return c["it"] < stop
 
     def body(c):
-        inner = _step(spec, c["inner"], feats, Ls, Yp)
+        inner = _step(spec, c["inner"], feats, Ls, Gs, Yp)
         return dict(
             it=c["it"] + 1,
             inner=inner,
@@ -483,11 +528,12 @@ class BlockADMMSolver:
                 feats = X  # the blocks are made from it inside the programs
             ph.result = feats
         with timer.phase("admm.factor") as ph:
-            Ls = ph.result = admm_factor(feats, spec=spec, dtype=dtype)
+            Ls, Gs = ph.result = admm_factor(feats, spec=spec, dtype=dtype)
 
         state = _zero_state(D=D, k=int(k), P=P, ni=ni, dtype=dtype)
         return _PreparedRun(
-            spec=spec, feats=feats, Ls=Ls, Yp=Yp, state0=state, timer=timer, d=d, classes=classes, dtype=dtype,
+            spec=spec, feats=feats, Ls=Ls, Gs=Gs, Yp=Yp, state0=state, timer=timer, d=d, classes=classes,
+            dtype=dtype,
         )
 
     def _model(self, run: _PreparedRun, Wbar, history, val_history=()):
@@ -506,6 +552,8 @@ class BlockADMMSolver:
             # passes over X an iteration: a remade block is held between
             # its products before and after the solve, not made twice
             "feature_passes": 0 if run.spec.cached else 1,
+            # reads of a block an iteration (the module docstring)
+            "block_reads": 4 if run.spec.cached else 2,
             "objective": model.history[-1] if model.history else None,
         }
         return model

@@ -5,7 +5,10 @@ inside every iteration (``ADMMParams.cache_transforms``).
   (``benchmarks/entries/admm_train.py``: the recurrence written out in
   plain ``jax.numpy``, importing nothing of the library) for hinge and
   squared loss: coefficients and the objective of every iteration;
-- remade against cached at the same size;
+- remade against cached at the same size: the remade route reads each
+  block twice an iteration (the right-hand side's product; then the
+  objective's and ``o_j``'s in one, ``ZtObar_j`` from the block's Gram
+  matrix), the cached route four times, as the recurrence has it;
 - ``None`` picks by bytes, from a memory figure the test supplies;
 - narrow rows keep an f32 state;
 - the cached route is bit for bit the distributed trainer's own copy of
@@ -195,6 +198,68 @@ def test_narrow_routes_agree_to_f32_rounding():
     remade = train("hinge", X, y, maps, cache_transforms=False)
     dw, dobj = apart(remade, np.asarray(cached.W, np.float64), np.asarray(cached.history))
     assert dw < 2e-5 and dobj < 2e-5, (dw, dobj)
+
+
+# -- two reads of a remade block against the cached route's four -------------
+
+LAYOUTS = {"P1": {}, "P4_scaled": {"data_partitions": 4, "scale_maps": True}}
+# Read on a CPU, coefficients / objective trace, hinge then squared:
+#   f64        P1 3.9e-15 / 3.4e-16, 3.2e-15 / 2.2e-16;  P4 scaled 7.5e-15 / 3.0e-16, 6.9e-15 / 2.6e-16
+#   f32        P1 5.3e-6 / 1.7e-7, 5.1e-6 / 1.2e-7;      P4 scaled 3.4e-6 / 1.1e-7, 3.6e-6 / 7.1e-8
+#   bf16 rows  P1 4.7e-6 / 1.7e-7, 4.5e-6 / 1.3e-7
+
+
+@pytest.mark.parametrize("loss", ["hinge", "squared"])
+@pytest.mark.parametrize("dtype,tol,layout", [
+    (np.float64, 1e-11, "P1"), (np.float64, 1e-11, "P4_scaled"),
+    (np.float32, 2e-5, "P1"), (np.float32, 2e-5, "P4_scaled"),
+    (jnp.bfloat16, 2e-5, "P1"),  # XLA:CPU has no batched bf16 x bf16 = f32 product for P = 4
+], ids=["f64-P1", "f64-P4_scaled", "f32-P1", "f32-P4_scaled", "bf16_rows-P1"])
+def test_two_reads_of_a_block_train_the_four_read_model(loss, layout, dtype, tol):
+    """The remade route's schedule (product (2), solve, the stacked
+    product, ``G_j Wi_j``) against the cached route's four products:
+    another order of sums, the rounding of the state's dtype."""
+    X, y = make_data(dtype)
+    maps = make_maps()
+    cached = train(loss, X, y, maps, cache_transforms=True, **LAYOUTS[layout])
+    remade = train(loss, X, y, maps, cache_transforms=False, **LAYOUTS[layout])
+    assert cached.info["block_reads"] == 4 and remade.info["block_reads"] == 2
+    dw, dobj = apart(remade, np.asarray(cached.W, np.float64), np.asarray(cached.history))
+    assert dw < tol and dobj < tol, (dw, dobj)
+
+
+@pytest.mark.parametrize("dtype,tol", [(np.float64, 1e-12), (np.float32, 1e-5),
+                                       (jnp.bfloat16, 1e-5)], ids=["f64", "f32", "bf16_rows"])
+def test_gram_matrix_times_the_solve_is_the_blocks_last_product(dtype, tol):
+    """``ZtObar_j = Z_j (Z_j' Wi_j)`` is ``G_j Wi_j`` with the Gram matrix
+    that ``admm_factor`` keeps beside ``L_j``: no read of the block.  One
+    block, Wi_j a solve with its own factor as in the iteration.  Read,
+    the three blocks: f64 4.7e-15, 4.5e-15, 4.1e-15; f32 3.8e-6, 5.0e-6,
+    1.2e-6; bf16 rows 2.8e-6, 3.3e-6, 7.4e-7 (on a v5e at the benchmark
+    cell's size, bf16 rows: 4.0e-5, PERF.md section 6, PR 34)."""
+    X, y = make_data(dtype)
+    solver = BlockADMMSolver("hinge", "l2", make_maps(), ADMMParams(cache_transforms=False))
+    run = solver._prepare(X, y, np.arange(K))
+    assert [G.shape for G in run.Gs] == [(1, s, s) for s in SIZES]
+    assert all(G.dtype == run.dtype for G in run.Gs)
+    rng = np.random.default_rng(5)
+    for j, s in enumerate(SIZES):
+        Z = admm._block(run.spec, j, X)
+        np.testing.assert_array_equal(
+            np.asarray(run.Ls[j]),
+            np.asarray(jnp.linalg.cholesky(run.Gs[j] + jnp.eye(s, dtype=run.dtype))))
+        Wi = admm._chol_solve(run.Ls[j], jnp.asarray(rng.standard_normal((1, s, K)), run.dtype))
+        read = admm._thin("psn,pkn->psk", Z, admm._thin("psk,psn->pkn", Wi, Z))
+        unread = jnp.einsum("psu,puk->psk", run.Gs[j], Wi, precision="highest")
+        d = float(jnp.linalg.norm(unread - read) / jnp.linalg.norm(read))
+        assert unread.dtype == read.dtype == run.dtype and d < tol, (j, d)
+
+
+def test_the_cached_route_keeps_no_gram_matrices():
+    X, y = make_data()
+    solver = BlockADMMSolver("hinge", "l2", make_maps(), ADMMParams(cache_transforms=True))
+    run = solver._prepare(X, y, np.arange(K))
+    assert run.Gs == [] and len(run.Ls) == len(SIZES)
 
 
 def test_three_pieces_carry_an_f32_operand_through_a_bfloat16_product():

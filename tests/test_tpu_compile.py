@@ -16,6 +16,7 @@ worker imports every test file), compiles run in the test's own
 process, and the persistent compilation cache is off around them.
 """
 
+import math
 import os
 import re
 
@@ -256,6 +257,39 @@ def test_streaming_krr_feature_pass_is_one_output_fusion(one_chip, program):
     assert call and call.group(1) == "kOutput"
 
 
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)(.*)$")
+
+
+def _readers_of_values_made(text, dtype, elems, scope):
+    """``{value: [its readers]}`` for every ``dtype`` value of ``elems``
+    elements that a fusion under the named ``scope`` makes, in any
+    computation of a compiled program's text: a reader is an
+    instruction of the same computation that has the value, or a
+    bitcast of it, among its operands."""
+    found = {}
+    for computation in text.split("\n\n"):
+        inst = {}
+        for line in computation.split("\n"):
+            m = _INSTRUCTION.match(line)
+            if m:
+                name, dt, dims, op, operands, rest = m.groups()
+                size = math.prod(int(d) for d in dims.split(",") if d)
+                inst[name] = (dt, size, op, re.findall(r"%([^\s,]+)", operands), rest)
+
+        def readers(value):
+            out = []
+            for name, (_, _, op, operands, _) in inst.items():
+                if value in operands:
+                    out += readers(name) if op == "bitcast" else [name]
+            return out
+
+        for name, (dt, size, op, _, rest) in inst.items():
+            if (dt, size, op) == (dtype, elems, "fusion") and scope in rest:
+                found[name] = readers(name)
+    return found
+
+
 def test_block_admm_remade_iterate_holds_one_block_at_full_size(one_chip):
     """The BlockADMM iteration with its feature blocks remade, at the
     benchmark cell's size (2,097,152 x 784 bf16 rows, 4 blocks of 1024
@@ -263,7 +297,10 @@ def test_block_admm_remade_iterate_holds_one_block_at_full_size(one_chip):
     so the program fits only while one is live at a time.  Its
     operations carry the four stage scopes, and the f32 operands of the
     thin products reach the bf16 blocks as ``reduce-precision`` pieces
-    (a cast there and back is folded away by this compiler)."""
+    (a cast there and back is folded away by this compiler).  Each block
+    is read twice (the right-hand side's product; the objective's and
+    o_j's in one), where the recurrence's four products read it four
+    times (PR 34)."""
     from libskylark_tpu.ml import GaussianKernel, admm
 
     N, D, SJ, J, K_ = 2**21, 784, 1024, 4, 10
@@ -279,9 +316,10 @@ def test_block_admm_remade_iterate_holds_one_block_at_full_size(one_chip):
     small, tall = shaped((J * SJ, K_), F32), shaped((1, K_, N), F32)
     per = shaped((1, J * SJ, K_), F32)
     state = (small,) * 3 + (tall,) * 4 + (per,) * 2 + (shaped((), F32),)
+    square = [shaped((1, SJ, SJ), F32)] * J  # the factors, and the Gram matrices
     with jax.enable_x64(False):
         compiled = admm.admm_iterate.lower(
-            state, shaped((N, D), BF16), [shaped((1, SJ, SJ), F32)] * J,
+            state, shaped((N, D), BF16), square, square,
             shaped((1, N), F32), spec=spec, maxiter=2).compile()
     block = 2 * N * SJ
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -292,3 +330,6 @@ def test_block_admm_remade_iterate_holds_one_block_at_full_size(one_chip):
         assert scope in text, scope
     assert "reduce-precision" in text
     assert "rft.epilogue.turns" in text
+    reads = _readers_of_values_made(text, "bf16", N * SJ, "admm.features")
+    assert len(reads) == J, reads  # one making of each block, in the loop body
+    assert all(len(r) == 2 for r in reads.values()), reads
