@@ -31,6 +31,7 @@ from ..parallel.mesh import fully_replicated
 from ..resilient.chunked import ChunkedSolver
 from ..sketch.base import Dimension
 from ..sketch.dense import JLT
+from ..utils import profiling
 
 __all__ = [
     "SVDParams",
@@ -68,17 +69,19 @@ def gram_orth(Y, passes: int = 2):
     producing NaNs: clamped directions come out with tiny norm and are
     dropped by the rank-k truncation downstream.
     """
-    for _ in range(passes):
-        # precision='highest' is load-bearing on BOTH products: the TPU
-        # MXU default truncates f32 operands to bf16 mantissas, which
-        # caps the achievable orthogonality at ~2e-3 no matter how many
-        # passes run (caught by tests/_hw_guards.py).
-        G = fully_replicated(jnp.dot(Y.T, Y, precision="highest"))
-        lam, V = jnp.linalg.eigh(G)
-        eps = jnp.asarray(jnp.finfo(Y.dtype).eps, G.dtype)
-        floor = jnp.maximum(lam[-1], 0) * eps * G.shape[0]
-        scale = jnp.where(lam > floor, jax.lax.rsqrt(jnp.maximum(lam, floor)), 0.0)
-        Y = jnp.dot(Y, V * scale[None, :], precision="highest")
+    with jax.named_scope("svd.gram_orth"):
+        for _ in range(passes):
+            # precision='highest' is load-bearing on BOTH products: the TPU
+            # MXU default truncates f32 operands to bf16 mantissas, which
+            # caps the achievable orthogonality at ~2e-3 no matter how many
+            # passes run (caught by tests/_hw_guards.py).
+            G = fully_replicated(jnp.dot(Y.T, Y, precision="highest"))
+            lam, V = jnp.linalg.eigh(G)
+            eps = jnp.asarray(jnp.finfo(Y.dtype).eps, G.dtype)
+            floor = jnp.maximum(lam[-1], 0) * eps * G.shape[0]
+            scale = jnp.where(
+                lam > floor, jax.lax.rsqrt(jnp.maximum(lam, floor)), 0.0)
+            Y = jnp.dot(Y, V * scale[None, :], precision="highest")
     return Y
 
 
@@ -123,7 +126,8 @@ def _chunk(st, A, num_iters, niter, *, orthogonalize: bool):
         return c["it"] < stop
 
     def body(c):
-        Y = A @ (A.T @ c["Y"])
+        with jax.named_scope("svd.sweep_products"):
+            Y = A @ (A.T @ c["Y"])
         return dict(it=c["it"] + 1, Y=_orth(Y) if orthogonalize else Y)
 
     return lax.while_loop(cond, body, st)
@@ -181,7 +185,8 @@ def approximate_svd_chunked(
         # the first call at a shape: trace, lower, cache key, fetch; every
         # later one: a dispatch from jit's cache
         with telemetry.span("svd.power"):
-            return _chunk(st, A, num_iters, niter, orthogonalize=orthogonalize)
+            return profiling.launch(
+                _chunk, st, A, num_iters, niter, orthogonalize=orthogonalize)
 
     def extract_result(st):
         Y = st["Y"]
@@ -197,7 +202,8 @@ def approximate_svd_chunked(
             # keep the fast default — they only steer the subspace.
             # (BCOO has no precision knob and does not ride the MXU bf16
             # path — its matmul keeps the sparse dispatch.)
-            AtQ = A.T @ Q if hasattr(A, "todense") else _project(A, Q)
+            AtQ = (A.T @ Q if hasattr(A, "todense")
+                   else profiling.launch(_project, A, Q))
             B = fully_replicated(AtQ)
         with telemetry.span("svd.small"):
             W, sv, Zt = jnp.linalg.svd(B, full_matrices=False)  # B = W·sv·Zt

@@ -91,7 +91,7 @@ from ..resilient.chunked import ChunkedSolver
 from ..sketch.base import Dimension
 from ..sketch.rft import _is_narrow
 from ..solvers.prox import get_loss, get_regularizer
-from ..utils import compile_cache
+from ..utils import compile_cache, profiling
 from ..utils.timer import PhaseTimer
 from .coding import class_indices, dummy_coding
 from .model import FeatureMapModel
@@ -387,16 +387,18 @@ def _step(spec: _Spec, state, feats, Ls, Gs, Yp):
             # block j + 1 is made when block j's products are done
             feats, outs = _after(feats, outs)
 
-    if not spec.cached:
-        wbar_out, sum_o = outs[:, :k], outs[:, k:]
-    del_o = O - sum_o
-    Obar = O - del_o / (J + 1.0)
-    nu = nu + O - Obar
-    # Consensus: sum over partitions (psum over ICI when sharded)
-    # ≙ the MPI reduce of Wi (BlockADMM.hpp:574-578).
-    Wbar = (jnp.sum(Wi, axis=0) + W) / (P + 1.0)
-    mu = mu + W - Wbar
-    obj = jax.vmap(loss.evaluate)(wbar_out, Yp).sum() + lam * reg.evaluate(Wbar)
+    with jax.named_scope("admm.tail"):
+        if not spec.cached:
+            wbar_out, sum_o = outs[:, :k], outs[:, k:]
+        del_o = O - sum_o
+        Obar = O - del_o / (J + 1.0)
+        nu = nu + O - Obar
+        # Consensus: sum over partitions (psum over ICI when sharded)
+        # ≙ the MPI reduce of Wi (BlockADMM.hpp:574-578).
+        Wbar = (jnp.sum(Wi, axis=0) + W) / (P + 1.0)
+        mu = mu + W - Wbar
+        obj = (jax.vmap(loss.evaluate)(wbar_out, Yp).sum()
+               + lam * reg.evaluate(Wbar))
     return (Wbar, W, mu, O, Obar, nu, del_o, mu_ij_new, ZtObar_new, obj)
 
 
@@ -523,12 +525,14 @@ class BlockADMMSolver:
             if spec.cached:
                 # Partitioned columnwise layout: Xp (P, d, ni).
                 Xp = X.reshape(P, ni, d).transpose(0, 2, 1)
-                feats = admm_transform(Xp, spec=spec)  # [(P, sj, ni)]
+                feats = profiling.launch(
+                    admm_transform, Xp, spec=spec)  # [(P, sj, ni)]
             else:
                 feats = X  # the blocks are made from it inside the programs
             ph.result = feats
         with timer.phase("admm.factor") as ph:
-            Ls, Gs = ph.result = admm_factor(feats, spec=spec, dtype=dtype)
+            Ls, Gs = ph.result = profiling.launch(
+                admm_factor, feats, spec=spec, dtype=dtype)
 
         state = _zero_state(D=D, k=int(k), P=P, ni=ni, dtype=dtype)
         return _PreparedRun(
@@ -584,8 +588,9 @@ class BlockADMMSolver:
         history, val_history = [], []
         if not have_val:
             with timer.phase("admm.iterate") as ph:
-                state, objs = ph.result = admm_iterate(
-                    state, *run.operands, spec=run.spec, maxiter=int(p.maxiter))
+                state, objs = ph.result = profiling.launch(
+                    admm_iterate, state, *run.operands, spec=run.spec,
+                    maxiter=int(p.maxiter))
             with timer.phase("admm.result"):
                 history = [float(o) for o in np.asarray(objs)]
             for it, obj in enumerate(history, 1):
@@ -593,7 +598,8 @@ class BlockADMMSolver:
         else:
             for it in range(1, p.maxiter + 1):
                 with timer.phase("admm.iterate"):
-                    state = admm_step(run.spec, state, *run.operands)
+                    state = profiling.launch(
+                        admm_step, run.spec, state, *run.operands)
                     obj = float(state[-1])  # readback syncs the step
                 history.append(obj)
                 msg = f"iteration {it} objective {obj:.6e}"
@@ -649,8 +655,9 @@ class BlockADMMSolver:
             )
 
         def step_chunk(st, num_iters: int):
-            return admm_chunk(st, *run.operands, spec=run.spec,
-                              maxiter=maxiter, num_iters=num_iters)
+            return profiling.launch(
+                admm_chunk, st, *run.operands, spec=run.spec,
+                maxiter=maxiter, num_iters=num_iters)
 
         def extract_result(st):
             it = int(st["it"])

@@ -379,24 +379,31 @@ def shifted_gram(kernel: Kernel, X, lam):
     ``n - block``, so an ``n`` that the block does not divide re-writes a
     few rows with the values they have.  The kernel is a pytree: its
     parameters and ``lam`` are arguments of the one program a shape has.
+    The operations stand under two named scopes: ``gram.block`` (a
+    block's kernel values) and ``gram.write`` (the shift, the copy into
+    the buffer, the buffer's zero fill).
     """
     n = X.shape[0]
     block = min(n, 1 << max(3, (_GRAM_BLOCK // n).bit_length() - 1))
     cols = jnp.arange(n)
 
     def rows_from(start):
-        Kb = kernel.gram(jax.lax.dynamic_slice_in_dim(X, start, block), X)
-        on_diag = cols[None, :] == (start + jnp.arange(block))[:, None]
-        return jnp.where(on_diag, Kb + jnp.asarray(lam, Kb.dtype), Kb)
+        with jax.named_scope("gram.block"):
+            Kb = kernel.gram(jax.lax.dynamic_slice_in_dim(X, start, block), X)
+        with jax.named_scope("gram.write"):
+            on_diag = cols[None, :] == (start + jnp.arange(block))[:, None]
+            return jnp.where(on_diag, Kb + jnp.asarray(lam, Kb.dtype), Kb)
 
     if block == n:
         return rows_from(0)
 
     def body(i, K):
         start = jnp.minimum(i * block, n - block)
-        return jax.lax.dynamic_update_slice_in_dim(K, rows_from(start), start, 0)
+        Kb = rows_from(start)
+        with jax.named_scope("gram.write"):
+            return jax.lax.dynamic_update_slice_in_dim(K, Kb, start, 0)
 
     out = jax.eval_shape(rows_from, 0)
-    return jax.lax.fori_loop(
-        0, -(-n // block), body, jnp.zeros((n, n), out.dtype)
-    )
+    with jax.named_scope("gram.write"):
+        K0 = jnp.zeros((n, n), out.dtype)
+    return jax.lax.fori_loop(0, -(-n // block), body, K0)

@@ -38,7 +38,7 @@ from ..core.precision import long_dot
 from ..parallel.mesh import fully_replicated
 from ..sketch.base import Dimension, create_sketch
 from ..solvers.krylov import KrylovParams, cg
-from ..utils import PhaseTimer, compile_cache
+from ..utils import PhaseTimer, compile_cache, profiling
 from .kernels import Kernel, shifted_gram
 from .model import FeatureMapModel, KernelModel
 
@@ -296,7 +296,8 @@ class _FeatureMapPrecond:
             Z = plans.apply(S, jnp.asarray(X), Dimension.ROWWISE)  # (n, s)
         with telemetry.span("faster_krr.precond.factor"):
             lam = jnp.asarray(lam, Z.dtype)
-            return cls(_woodbury_factor(Z, lam), lam)  # Z dies with this frame
+            # Z dies with this frame
+            return cls(profiling.launch(_woodbury_factor, Z, lam), lam)
 
     def apply(self, B):
         UB = long_dot(self.U, B).astype(B.dtype)
@@ -342,7 +343,7 @@ def faster_kernel_ridge(
         Y2, _ = _as2d(Y)
         P = _FeatureMapPrecond.build(kernel, lam, X, s, context, params)
         with telemetry.span("faster_krr.gram"):
-            Kl = shifted_gram(kernel, X, lam)
+            Kl = profiling.launch(shifted_gram, kernel, X, lam)
         kp = KrylovParams(tolerance=params.tolerance, iter_lim=params.iter_lim)
         if params.checkpoint_dir:
             # Preemption-safe CG: everything outside the CG state (Gram,
@@ -621,17 +622,18 @@ def streaming_kernel_ridge(
                 for c, (gram, zr, apply_delta) in enumerate(programs):
                     if it == 0:
                         with telemetry.span("krr.gram"):
-                            G = gram(*block_args)
+                            G = profiling.launch(gram, *block_args)
                         with telemetry.span("krr.factor"):
                             factors.append(cho_factor(G, lower=True))
                         del G
                     with telemetry.span("krr.zr"):
-                        ZR = zr(R, Ws[c], *block_args)
+                        ZR = profiling.launch(zr, R, Ws[c], *block_args)
                     with telemetry.span("krr.solve"):
                         delta = cho_solve(factors[c], ZR)
                         Ws[c] = Ws[c] + delta
                     with telemetry.span("krr.apply_delta"):
-                        R = apply_delta(R, delta, *block_args)
+                        R = profiling.launch(
+                            apply_delta, R, delta, *block_args)
                     with telemetry.span("krr.converge"):  # a host wait
                         delsize += float(jnp.sum(delta * delta))
                 ph.result = R
@@ -673,8 +675,9 @@ def streaming_krr_chunk_programs(
         map's counter-realized operands are hoisted to ``ops`` (once per
         program, outside the panel loop): XLA does not LICM the ~11 ms
         per-visit W realization out of the fori_loop by itself."""
-        Xp = block_fn(start, block_rows, *bargs).astype(feature_dtype)
-        return maps[c].apply_with_operands(ops, Xp, Dimension.ROWWISE)
+        with jax.named_scope("krr.features"):
+            Xp = block_fn(start, block_rows, *bargs).astype(feature_dtype)
+            return maps[c].apply_with_operands(ops, Xp, Dimension.ROWWISE)
 
     def _prec(dtype):
         return None if dtype == jnp.bfloat16 else "highest"
@@ -685,12 +688,13 @@ def streaming_krr_chunk_programs(
 
         def body(p, G):
             Zp = chunk_Zp(p * block_rows, bargs, ops)
-            blk = jax.lax.dot_general(
-                Zp, Zp, (((0,), (0,)), ((), ())),
-                precision=_prec(Zp.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            return G + blk
+            with jax.named_scope("krr.gram_product"):
+                blk = jax.lax.dot_general(
+                    Zp, Zp, (((0,), (0,)), ((), ())),
+                    precision=_prec(Zp.dtype),
+                    preferred_element_type=jnp.float32,
+                )
+                return G + blk
 
         G = jax.lax.fori_loop(
             0, nb, body, jnp.zeros((sz, sz), jnp.float32)
@@ -711,12 +715,13 @@ def streaming_krr_chunk_programs(
 
         def body(p, acc):
             Zp = chunk_Zp(p * block_rows, bargs, ops)
-            Rp = jax.lax.dynamic_index_in_dim(R3, p, 0, keepdims=False)
-            return acc + jax.lax.dot_general(
-                Zp, Rp, (((0,), (0,)), ((), ())),
-                precision=_prec(Zp.dtype),
-                preferred_element_type=jnp.float32,
-            )
+            with jax.named_scope("krr.zr_product"):
+                Rp = jax.lax.dynamic_index_in_dim(R3, p, 0, keepdims=False)
+                return acc + jax.lax.dot_general(
+                    Zp, Rp, (((0,), (0,)), ((), ())),
+                    precision=_prec(Zp.dtype),
+                    preferred_element_type=jnp.float32,
+                )
 
         acc0 = jnp.zeros((sz, t), jnp.float32)
         return jax.lax.fori_loop(0, nb, body, acc0) - lam_ * Wc
@@ -727,15 +732,16 @@ def streaming_krr_chunk_programs(
 
         def body(p, R3):
             Zp = chunk_Zp(p * block_rows, bargs, ops)
-            upd = jax.lax.dot_general(
-                Zp, delta.astype(Zp.dtype), (((1,), (0,)), ((), ())),
-                precision=_prec(Zp.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            Rp = jax.lax.dynamic_index_in_dim(R3, p, 0, keepdims=False)
-            return jax.lax.dynamic_update_index_in_dim(
-                R3, Rp - upd, p, 0
-            )
+            with jax.named_scope("krr.delta_product"):
+                upd = jax.lax.dot_general(
+                    Zp, delta.astype(Zp.dtype), (((1,), (0,)), ((), ())),
+                    precision=_prec(Zp.dtype),
+                    preferred_element_type=jnp.float32,
+                )
+                Rp = jax.lax.dynamic_index_in_dim(R3, p, 0, keepdims=False)
+                return jax.lax.dynamic_update_index_in_dim(
+                    R3, Rp - upd, p, 0
+                )
 
         return jax.lax.fori_loop(0, nb, body, R3)
 

@@ -7,6 +7,9 @@ models carry ``.classes`` for decoding.
 
 from __future__ import annotations
 
+from contextlib import nullcontext
+
+from .. import telemetry
 from ..core.context import SketchContext
 from .coding import dummy_coding
 from .kernels import Kernel
@@ -26,9 +29,13 @@ __all__ = [
 ]
 
 
-def _classify(train_fn):
+def _classify(train_fn, labels_span: str | None = None):
+    """``train_fn`` on dummy-coded labels.  The coding reads the labels
+    to the host and copies the codes back; ``labels_span`` is the stage
+    span it runs under, where the trainer has stage spans."""
     def wrapper(kernel: Kernel, X, y, lam: float, *args, **kwargs):
-        T, classes = dummy_coding(y)
+        with telemetry.span(labels_span) if labels_span else nullcontext():
+            T, classes = dummy_coding(y)
         model = train_fn(kernel, X, T, lam, *args, **kwargs)
         model.classes = classes
         return model
@@ -41,4 +48,4 @@ def _classify(train_fn):
 kernel_rlsc = _classify(kernel_ridge)
 approximate_kernel_rlsc = _classify(approximate_kernel_ridge)
 sketched_approximate_kernel_rlsc = _classify(sketched_approximate_kernel_ridge)
-faster_kernel_rlsc = _classify(faster_kernel_ridge)
+faster_kernel_rlsc = _classify(faster_kernel_ridge, "faster_krr.labels")

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..sketch.base import Dimension
+from ..utils import profiling
 from .bucketing import bucket_for, pad_rows
 from .cache import PLAN_CACHE
 
@@ -156,12 +157,24 @@ class SketchPlan:
         kw = {"donate_argnums": donate_argnums} if donate_argnums else {}
         self._jit = jax.jit(traced, **kw)
 
+        def uncounted():
+            """The same program for ``profiling.records()`` to lower
+            again: ``fn`` under the same name, its trace not counted."""
+            def traced(*args):
+                return fn(*args)
+
+            return jax.jit(traced, **kw)
+
+        self._uncounted = uncounted
+
     def __call__(self, *args):
         with self._lock:
             first = self.calls == 0
             self.calls += 1
         if first:
             t0 = time.perf_counter()
+        if profiling.tracing():
+            profiling.note(self._jit, args, {}, lower=self._uncounted)
         out = self._jit(*args)
         if first:
             jax.block_until_ready(out)

@@ -33,6 +33,7 @@ import jax.scipy.linalg as jsl
 import numpy as np
 
 from .. import guard, plans, telemetry
+from ..utils import profiling
 from ..core.context import SketchContext
 from ..core.params import Params
 from ..sketch.base import Dimension, create_sketch
@@ -125,7 +126,7 @@ def faster_least_squares(
             # ``build_precond``, accelerated_...Elemental.hpp:68-77, 225-246).
             # (its float() is where the host waits for sketch, QR and estimate)
             with telemetry.span("blendenpik.condest"):
-                cond = float(_tri_condest(R_try))
+                cond = float(profiling.launch(_tri_condest, R_try))
             R = R_try
             good = np.isfinite(cond) and cond < threshold
             report.record(

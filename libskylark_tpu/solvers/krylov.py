@@ -50,6 +50,7 @@ from jax import lax
 from .. import telemetry
 from ..core.params import Params
 from ..resilient.chunked import ChunkedSolver
+from ..utils import profiling
 from .precond import IdPrecond
 
 __all__ = [
@@ -73,11 +74,23 @@ class KrylovParams(Params):
     iter_lim: int = 100
 
 
+def _scoped(name, f):
+    """``f`` with its operations under the named scope ``name``: the
+    metadata that ``benchmarks/scope_reduce.py`` reads device time by."""
+    def g(*args):
+        with jax.named_scope(name):
+            return f(*args)
+
+    return g
+
+
 def _ops(A):
-    """(matvec, rmatvec) for dense / BCOO / (matvec, rmatvec) pair."""
-    if isinstance(A, tuple):
-        return A
-    return (lambda x: A @ x), (lambda y: A.T @ y)
+    """(matvec, rmatvec) for dense / BCOO / (matvec, rmatvec) pair, under
+    the scopes ``krylov.matvec`` and ``krylov.rmatvec``."""
+    matvec, rmatvec = (
+        A if isinstance(A, tuple) else ((lambda x: A @ x), (lambda y: A.T @ y))
+    )
+    return _scoped("krylov.matvec", matvec), _scoped("krylov.rmatvec", rmatvec)
 
 
 def _colnorm(X):
@@ -144,8 +157,8 @@ def _chunk_stepper(body, operands, iter_lim: int, done_of=None):
         # the first call at a shape: trace, lower, cache key, fetch; every
         # later one: a dispatch from jit's cache
         with telemetry.span("krylov.segment"):
-            return run(
-                s, operands, num_iters, iter_lim, body=body, done_of=done_of
+            return profiling.launch(
+                run, s, operands, num_iters, iter_lim, body=body, done_of=done_of
             )
 
     return step_chunk
@@ -160,11 +173,12 @@ def _lifted_stepper(body, iter_lim: int, done_of):
     (closed over by the jit they would be literals of the executable).
     The jit and the jaxpr die with the solver: a cache keyed on the
     closure would pin what it closes over for the life of the process."""
-    lifted: list = []  # [closed jaxpr of body, output tree]
+    lifted: list = []  # [jaxpr of body, output tree]
+    consts: list = []  # the jaxpr's constants: the segment never closes over them
 
     def body_of(st, consts):
-        closed, out_tree = lifted
-        out = jax.core.eval_jaxpr(closed.jaxpr, consts, *jax.tree.leaves(st))
+        jaxpr, out_tree = lifted
+        out = jax.core.eval_jaxpr(jaxpr, consts, *jax.tree.leaves(st))
         return jax.tree.unflatten(out_tree, out)
 
     @partial(jax.jit, static_argnames=("num_iters",))
@@ -177,10 +191,11 @@ def _lifted_stepper(body, iter_lim: int, done_of):
         if not lifted:
             with telemetry.span("krylov.lift"):
                 closed, out_shape = jax.make_jaxpr(body, return_shape=True)(s)
-            lifted.extend((closed, jax.tree.structure(out_shape)))
+            lifted.extend((closed.jaxpr, jax.tree.structure(out_shape)))
+            consts.extend(closed.consts)
         # trace, lower, cache key, fetch and enqueue of the segment
         with telemetry.span("krylov.segment"):
-            return run(s, lifted[0].consts, num_iters=num_iters)
+            return profiling.launch(run, s, consts, num_iters=num_iters)
 
     return step_chunk
 
@@ -201,9 +216,11 @@ def _one_shot(factory_state_solver, iter_lim: int):
 def _preconditioned(A, N):
     """(matvec, rmatvec) of A·N for a right preconditioner N."""
     matvec0, rmatvec0 = _ops(A)
+    apply = _scoped("krylov.precond", N.apply)
+    apply_adjoint = _scoped("krylov.precond", N.apply_adjoint)
     return (
-        lambda v: matvec0(N.apply(v)),
-        lambda u: N.apply_adjoint(rmatvec0(u)),
+        lambda v: matvec0(apply(v)),
+        lambda u: apply_adjoint(rmatvec0(u)),
     )
 
 
@@ -358,7 +375,8 @@ def _spd_matvec(A):
     another system than the stated one (PERF.md section 6, PR 31).
     LSQR's products are not these (:func:`_ops`)."""
     if isinstance(A, (jax.Array, np.ndarray)):
-        return lambda x: jnp.dot(A, x, precision="highest")
+        return _scoped(
+            "krylov.matvec", lambda x: jnp.dot(A, x, precision="highest"))
     return _ops(A)[0]
 
 
@@ -371,7 +389,8 @@ def _cg_body(s, operands):
     alpha = jnp.where(s["done"], 0.0, s["rz"] / jnp.where(denom != 0, denom, 1))
     X = s["X"] + alpha[None, :] * s["P"]
     R = s["R"] - alpha[None, :] * Q
-    Z = M.apply(R)
+    with jax.named_scope("krylov.precond"):
+        Z = M.apply(R)
     rz_new = jnp.sum(R * Z, axis=0)
     beta = rz_new / jnp.where(s["rz"] != 0, s["rz"], 1)
     P = Z + beta[None, :] * s["P"]
@@ -441,7 +460,8 @@ def _fcg_body(s, operands):
     A, M, tol, bnorm = operands
     matvec, _ = _ops(A)
     memory = s["Pbuf"].shape[0]
-    Z = M.apply(s["R"]) if hasattr(M, "apply") else M(s["R"], s["it"])
+    with jax.named_scope("krylov.precond"):
+        Z = M.apply(s["R"]) if hasattr(M, "apply") else M(s["R"], s["it"])
     # Orthogonalize Z against stored directions (A-inner product).
     coeffs = jnp.einsum("smk,mk->sk", s["Qbuf"], Z) / s["pq"]
     P = Z - jnp.einsum("smk,sk->mk", s["Pbuf"], coeffs)

@@ -333,3 +333,93 @@ def test_block_admm_remade_iterate_holds_one_block_at_full_size(one_chip):
     reads = _readers_of_values_made(text, "bf16", N * SJ, "admm.features")
     assert len(reads) == J, reads  # one making of each block, in the loop body
     assert all(len(r) == 2 for r in reads.values()), reads
+
+
+# -- the named scopes are metadata: the programs stay the programs ------------
+#
+# ``benchmarks/scope_reduce.py`` reads device time by the named scopes on
+# the compiled instructions (PR 35).  The scopes must be there, and
+# opening them must not have moved an instruction: the counts below were
+# read from the parent commit's programs, compiled the same way.
+
+
+def _counts(text):
+    return (len(re.findall(r" fusion\(", text)),
+            len(re.findall(r" convolution\(", text)))
+
+
+def _scopes_that_own_an_operation(text):
+    """The dotted scope names that some operation's time would be put
+    down to: ``profiling.hlo_scopes`` under the one-operation-one-scope
+    rule of ``benchmarks/scope_reduce.py`` (a fusion's first product)."""
+    import sys
+
+    from libskylark_tpu.utils import profiling
+
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
+    import scope_reduce
+
+    dotted = re.compile(r"^[a-z_]+\.[a-z_.]+$")
+    return {scope_reduce.scope_of(scope_reduce.owner(entry), dotted)
+            for entry in profiling.hlo_scopes(text).values()} - {None}
+
+
+def test_block_admm_iterate_carries_its_scopes_and_the_parents_instructions(one_chip):
+    from libskylark_tpu.ml import GaussianKernel, admm
+
+    N, D, SJ, J, K_ = 2**21, 784, 1024, 4, 10
+    ctx = SketchContext(seed=9)
+    maps = [GaussianKernel(D, sigma=28.0).create_rft(SJ, "regular", ctx)
+            for _ in range(J)]
+    spec = admm._Spec(loss="hinge", reg="l2", maps=admm._Maps(maps), P=1,
+                      scale_maps=False, cached=False, rho=1.0, lam=0.01)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    small, tall = shaped((J * SJ, K_), F32), shaped((1, K_, N), F32)
+    per = shaped((1, J * SJ, K_), F32)
+    state = (small,) * 3 + (tall,) * 4 + (per,) * 2 + (shaped((), F32),)
+    square = [shaped((1, SJ, SJ), F32)] * J
+    with jax.enable_x64(False):
+        compiled = admm.admm_iterate.lower(
+            state, shaped((N, D), BF16), square, square,
+            shaped((1, N), F32), spec=spec, maxiter=2).compile()
+    text = compiled.as_text()
+    assert _counts(text) == (555, 136)  # the parent's (commit ce4deed)
+    assert compiled.memory_analysis().temp_size_in_bytes == 5_267_843_072
+    for scope in ("admm.features", "admm.thin_products", "admm.prox",
+                  "admm.block_solve", "admm.tail", "rft.epilogue.turns"):
+        assert scope in text, scope
+    # the prox is fused into the products: it owns next to nothing
+    assert {"admm.features", "admm.thin_products", "admm.block_solve",
+            "admm.tail"} <= _scopes_that_own_an_operation(text)
+
+
+@pytest.mark.parametrize("program,product,counts", [
+    ("gram", "krr.gram_product", (10, 2)),
+    ("zr", "krr.zr_product", (9, 2)),
+    ("apply_delta", "krr.delta_product", (9, 2)),
+])
+def test_streaming_krr_programs_carry_their_scopes_and_the_parents_instructions(
+        one_chip, program, product, counts):
+    from libskylark_tpu.ml import GaussianKernel
+    from libskylark_tpu.ml.krr import streaming_krr_chunk_programs
+
+    D, SZ, NB, BR, T = 784, 1024, 2, 8192, 10
+    maps = [GaussianKernel(D, sigma=28.0).create_rft(
+        SZ, "regular", SketchContext(seed=9))]
+
+    def block_fn(start, rows, X):
+        return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
+
+    progs = dict(zip(("gram", "zr", "apply_delta"), streaming_krr_chunk_programs(
+        maps, 0, SZ, NB, BR, T, 1.0, block_fn, BF16)))
+    X, R, W = ((NB * BR, D), BF16), ((NB, BR, T), F32), ((SZ, T), F32)
+    shapes = (X,) if program == "gram" else (R, W, X)
+    text = _text(progs[program], one_chip, *shapes)
+    assert _counts(text) == counts  # the parent's (commit ce4deed)
+    # the turns epilogue nests under the feature pass
+    assert re.search(r'op_name="[^"]*krr\.features/[^"]*rft\.epilogue\.turns', text)
+    assert {"krr.features", product} <= _scopes_that_own_an_operation(text)
