@@ -30,6 +30,12 @@ from libskylark_tpu.ml import (
 )
 
 
+def block_fn(start, rows, X):
+    """A row panel of X.  Module-level, X through ``block_args``: the
+    trainer's programs are then built once a process, not once a call."""
+    return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
+
+
 def main():
     n, d, s = (
         int(x) for x in (sys.argv[1:4] + [4096, 32, 256][len(sys.argv) - 1 :])
@@ -40,12 +46,10 @@ def main():
     kernel = GaussianKernel(d, sigma=float(np.sqrt(d)))
     params = KrrParams(max_split=s // 2, iter_lim=15, tolerance=1e-7)
 
-    def block_fn(start, rows):
-        return jax.lax.dynamic_slice(X, (start, 0), (rows, d))
-
     model = streaming_kernel_ridge(
         kernel, block_fn, (n, d), y, 0.1, s, SketchContext(seed=7),
         params, block_rows=max(256, n // 16), feature_dtype=jnp.float32,
+        block_args=(X,),
     )
     pred = np.asarray(model.predict(X))[:, 0]
     print(f"streaming KRR: n={n} d={d} s={s}, "

@@ -94,7 +94,7 @@ from ..solvers.prox import get_loss, get_regularizer
 from ..utils import compile_cache, profiling
 from ..utils.timer import PhaseTimer
 from .coding import class_indices, dummy_coding
-from .model import FeatureMapModel
+from .model import FeatureMapModel, _Maps
 
 __all__ = ["ADMMParams", "BlockADMMSolver", "CACHE_FRACTION"]
 
@@ -115,21 +115,6 @@ class ADMMParams(Params):
     # ≙ CacheTransforms: keep every Z_j for the run (True), remake each
     # inside every iteration (False), or decide by bytes (None).
     cache_transforms: bool | None = None
-
-
-class _Maps:
-    """The feature maps as a static argument of the programs: equal when
-    their serialized forms are (a map is a pure function of its JSON)."""
-
-    def __init__(self, maps):
-        self.maps = tuple(maps)
-        self.key = tuple(S.to_json() for S in self.maps)
-
-    def __hash__(self):
-        return hash(self.key)
-
-    def __eq__(self, other):
-        return isinstance(other, _Maps) and self.key == other.key
 
 
 @dataclass(frozen=True)

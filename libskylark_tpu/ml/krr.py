@@ -25,7 +25,9 @@ replicated-small (≙ the reference's ``[*,*]`` / ``[STAR,STAR]`` choices).
 from __future__ import annotations
 
 import dataclasses
+import types
 from dataclasses import dataclass
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +42,7 @@ from ..sketch.base import Dimension, create_sketch
 from ..solvers.krylov import KrylovParams, cg
 from ..utils import PhaseTimer, compile_cache, profiling
 from .kernels import Kernel, shifted_gram
-from .model import FeatureMapModel, KernelModel
+from .model import FeatureMapModel, KernelModel, _Maps
 
 __all__ = [
     "KrrParams",
@@ -544,6 +546,17 @@ def streaming_kernel_ridge(
     compile-time constant (round-tripped through the host, and part of
     the executable); counter-generated sources need none.
 
+    The three per-chunk programs are built once a process and keyed by
+    the maps' value, the sizes, ``feature_dtype`` and ``block_fn``'s
+    identity, λ an operand (:func:`streaming_krr_chunk_programs`): a
+    later call with a fresh kernel, context and maps of equal value, or
+    with another λ, builds nothing.  That holds for a ``block_fn`` that
+    lives with its module and reads its data through ``block_args``
+    alone: it is traced once a process, and what else it read would be
+    read then and never again.  One that closes over anything, has
+    default arguments or reads data from its module's namespace gets
+    programs of the call's own, built again every call.
+
     ``timer``: optional ``utils.PhaseTimer`` — sweep 0 (which absorbs
     the per-chunk program compiles and factorizations) lands in phase
     ``"sweep0"``, steady sweeps in ``"sweep"`` (the ADMM solver's
@@ -597,13 +610,13 @@ def streaming_kernel_ridge(
             maps = [
                 kernel.create_rft(sz, _tag(params), context) for sz in sizes
             ]
+            by_value = _Maps(maps)
             programs = [
                 streaming_krr_chunk_programs(
-                    maps, c, sizes[c], nb, block_rows, t, lam, block_fn,
-                    feature_dtype,
-                )
+                    by_value, c, nb, block_rows, block_fn, feature_dtype)
                 for c in range(len(maps))
             ]
+            lam_ = jnp.asarray(lam, jnp.float32)
         factors = []
         Ws = [jnp.zeros((sz, t), jnp.float32) for sz in sizes]
         # Panel-major residual (see streaming_krr_chunk_programs): sharded
@@ -622,18 +635,17 @@ def streaming_kernel_ridge(
                 for c, (gram, zr, apply_delta) in enumerate(programs):
                     if it == 0:
                         with telemetry.span("krr.gram"):
-                            G = profiling.launch(gram, *block_args)
+                            G = gram(lam_, *block_args)
                         with telemetry.span("krr.factor"):
                             factors.append(cho_factor(G, lower=True))
                         del G
                     with telemetry.span("krr.zr"):
-                        ZR = profiling.launch(zr, R, Ws[c], *block_args)
+                        ZR = zr(lam_, R, Ws[c], *block_args)
                     with telemetry.span("krr.solve"):
                         delta = cho_solve(factors[c], ZR)
                         Ws[c] = Ws[c] + delta
                     with telemetry.span("krr.apply_delta"):
-                        R = profiling.launch(
-                            apply_delta, R, delta, *block_args)
+                        R = apply_delta(R, delta, *block_args)
                     with telemetry.span("krr.converge"):  # a host wait
                         delsize += float(jnp.sum(delta * delta))
                 ph.result = R
@@ -647,102 +659,243 @@ def streaming_kernel_ridge(
         W = jnp.concatenate(Ws, axis=0)
         return FeatureMapModel(maps, W)
 
+
+@dataclass(frozen=True)
+class _ChunkSpec:
+    """What a chunk program is keyed by besides its operands' shapes:
+    the maps by value, the chunk, the panel grid, the feature dtype, and
+    ``block_fn`` by identity."""
+
+    maps: _Maps
+    c: int
+    nb: int
+    block_rows: int
+    feature_dtype: Any
+    block_fn: Callable
+
+    @property
+    def map(self):
+        return self.maps.maps[self.c]
+
+    @property
+    def sz(self):
+        return self.map.s
+
+
+def _chunk_Zp(spec, start, bargs, ops):
+    """(block_rows, sz) feature panel of chunk c, built in-graph.
+    Natural rowwise layout: every consumer contracts it with
+    ``dot_general`` directly — materializing a transpose (or an
+    astype-to-f32 copy) of the panel costs ~3 extra HBM passes per
+    visit, measured ~2.3 s/sweep-pass at the 10M×4096 shape.  The
+    map's counter-realized operands are hoisted to ``ops`` (once per
+    program, outside the panel loop): XLA does not LICM the ~11 ms
+    per-visit W realization out of the fori_loop by itself."""
+    with jax.named_scope("krr.features"):
+        Xp = spec.block_fn(start, spec.block_rows, *bargs).astype(
+            spec.feature_dtype)
+        return spec.map.apply_with_operands(ops, Xp, Dimension.ROWWISE)
+
+
+# All contractions consume the (block_rows, sz) panel in place via
+# dot_general with an f32 preferred_element_type: bf16 panels contract
+# at MXU rate with exact-f32 accumulation and are never rounded back
+# (the _psd_gram hazard) nor upcast into a materialized f32 copy.
+# precision='highest' pins the f32/f64 feature case.
+def _prec(dtype):
+    return None if dtype == jnp.bfloat16 else "highest"
+
+
+def _gram(spec, lam, *bargs):
+    sz = spec.sz
+    ops = spec.map.hoistable_operands(spec.feature_dtype)
+
+    def body(p, G):
+        Zp = _chunk_Zp(spec, p * spec.block_rows, bargs, ops)
+        with jax.named_scope("krr.gram_product"):
+            blk = jax.lax.dot_general(
+                Zp, Zp, (((0,), (0,)), ((), ())),
+                precision=_prec(Zp.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            return G + blk
+
+    G = jax.lax.fori_loop(
+        0, spec.nb, body, jnp.zeros((sz, sz), jnp.float32)
+    )
+    return G + lam * jnp.eye(sz, dtype=jnp.float32)
+
+
+# The residual travels as (nb, block_rows, t): panels on the LEADING
+# (unsharded) axis, rows of each panel on the shardable middle axis.
+# A traced-index slice R3[p] then never touches the sharded
+# dimension, so GSPMD keeps it local — the (N, t) layout with a
+# traced-offset dynamic_slice cost a full all-gather of R per sweep
+# on the virtual mesh (compiled-HLO finding, round 4; the one-time
+# reshard into panel-major happens outside the sweep loop).
+
+
+def _zr(spec, lam, R3, Wc, *bargs):
+    ops = spec.map.hoistable_operands(spec.feature_dtype)
+
+    def body(p, acc):
+        Zp = _chunk_Zp(spec, p * spec.block_rows, bargs, ops)
+        with jax.named_scope("krr.zr_product"):
+            Rp = jax.lax.dynamic_index_in_dim(R3, p, 0, keepdims=False)
+            return acc + jax.lax.dot_general(
+                Zp, Rp, (((0,), (0,)), ((), ())),
+                precision=_prec(Zp.dtype),
+                preferred_element_type=jnp.float32,
+            )
+
+    acc0 = jnp.zeros((spec.sz, R3.shape[2]), jnp.float32)
+    return jax.lax.fori_loop(0, spec.nb, body, acc0) - lam * Wc
+
+
+def _apply_delta(spec, R3, delta, *bargs):
+    ops = spec.map.hoistable_operands(spec.feature_dtype)
+
+    def body(p, R3):
+        Zp = _chunk_Zp(spec, p * spec.block_rows, bargs, ops)
+        with jax.named_scope("krr.delta_product"):
+            upd = jax.lax.dot_general(
+                Zp, delta.astype(Zp.dtype), (((1,), (0,)), ((), ())),
+                precision=_prec(Zp.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            Rp = jax.lax.dynamic_index_in_dim(R3, p, 0, keepdims=False)
+            return jax.lax.dynamic_update_index_in_dim(
+                R3, Rp - upd, p, 0
+            )
+
+    return jax.lax.fori_loop(0, spec.nb, body, R3)
+
+
+def _program(body, *spec):
+    """``body`` as a ``jax.jit`` program under its name (device module
+    ``jit_gram``, ``jit_zr``, ``jit_apply_delta``).  With no ``spec``
+    the program is keyed by the static :class:`_ChunkSpec` it is called
+    with first; with one it closes over it and takes the operands only,
+    a program of its maker's that dies with it."""
+
+    def program(*args):
+        return body(*spec, *args)
+
+    program.__name__ = body.__name__.lstrip("_")
+    return jax.jit(program, static_argnums=() if spec else 0)
+
+
+# The process's three chunk programs, ``gram(spec, lam, *bargs)``,
+# ``zr(spec, lam, R, Wc, *bargs)`` and ``apply_delta(spec, R, delta,
+# *bargs)``: built once a spec and operand shapes, then served from
+# ``jax.jit``'s own cache.  λ is an f32 scalar operand, so a sweep over
+# λ is one executable.
+gram, zr, apply_delta = (_program(b) for b in (_gram, _zr, _apply_delta))
+
+
+class _ChunkProgram:
+    """A chunk program as its caller has it, whichever of the two kinds
+    it is: launched (``profiling.launch``) and lowered with the operands
+    alone, the static arguments it takes first, if any, already given."""
+
+    def __init__(self, program, *static):
+        self.program, self.static = program, static
+        self.__name__ = program.__name__
+
+    def __call__(self, *ops):
+        return profiling.launch(self.program, *self.static, *ops)
+
+    def lower(self, *ops):
+        return self.program.lower(*self.static, *ops)
+
+
+_CODE = (types.ModuleType, types.FunctionType, types.BuiltinFunctionType, type)
+
+
+def _global_names(code):
+    """The names ``code`` and the code nested in it may look up in the
+    module's namespace (attribute names among them: more, never fewer)."""
+    yield from code.co_names
+    for const in code.co_consts:
+        if isinstance(const, types.CodeType):
+            yield from _global_names(const)
+
+
+def _lives_with_its_module(fn, _seen=None) -> bool:
+    """May a process-wide cache be keyed by ``fn``?  Yes for a plain
+    function found under its qualified name in its module's namespace
+    (``fn.__globals__``: the module need not be in ``sys.modules``) that
+    has no closure and no default arguments, and reads nothing of that
+    namespace but modules, classes and functions, those of its own
+    namespace held to the same.  Such a function lives as long as the
+    namespace, which it holds anyway, so the cache keeps nothing else
+    alive; and a trace of it bakes in no array or number that the
+    caller may rebind between calls (``X = ...`` at the top of a script
+    or a notebook cell, read by ``block_fn`` and not passed to it)."""
+    if (not isinstance(fn, types.FunctionType) or fn.__closure__
+            or fn.__defaults__ or fn.__kwdefaults__):
+        return False
+    first, *rest = fn.__qualname__.split(".")
+    found = fn.__globals__.get(first)
+    for part in rest:
+        found = getattr(found, part, None)
+    if found is not fn:
+        return False
+    seen = set() if _seen is None else _seen
+    seen.add(fn)
+    for name in _global_names(fn.__code__):
+        if name not in fn.__globals__:
+            continue  # a builtin or an attribute
+        read = fn.__globals__[name]
+        if not isinstance(read, _CODE):
+            return False
+        if (isinstance(read, types.FunctionType)
+                and read.__globals__ is fn.__globals__ and read not in seen
+                and not _lives_with_its_module(read, seen)):
+            return False
+    return True
+
+
 def streaming_krr_chunk_programs(
-    maps, c, sz, nb, block_rows, t, lam, block_fn, feature_dtype
+    maps, c, nb, block_rows, block_fn, feature_dtype
 ):
-    """The three jitted per-chunk programs of the streaming-KRR sweep:
-    ``(gram(*bargs), zr(R, Wc, *bargs), apply_delta(R, delta, *bargs))``.
+    """``(gram, zr, apply_delta)``, the three jitted programs of chunk
+    ``c``'s sweep: ``gram(lam, *bargs)``, ``zr(lam, R, Wc, *bargs)``,
+    ``apply_delta(R, delta, *bargs)``; λ is an f32 scalar operand.  What
+    the trainer launches is what a test AOT-lowers (``.lower`` with the
+    same operands), on a virtual mesh or for a described chip, to read
+    the compiled HLO (``tests/test_collectives.py``,
+    ``tests/test_tpu_compile.py``).
 
-    Module-level (not a closure of :func:`streaming_kernel_ridge`) so
-    a test can AOT-lower the SAME programs on a virtual mesh and read
-    the collectives out of the compiled HLO
-    (``tests/test_collectives.py::TestStreamingKrrCommSchedule``).
+    **Built once a process.**  With a ``block_fn`` that lives with its
+    module (:func:`_lives_with_its_module`) the programs are this
+    module's ``gram``, ``zr`` and ``apply_delta``, keyed by a static
+    spec: the maps *by value* (their JSON: kind, sizes, seed, counters),
+    the chunk, ``nb``, ``block_rows``, ``feature_dtype`` and
+    ``block_fn``'s *identity*.  A later call with a new kernel, context
+    and map objects of equal value traces, lowers and compiles nothing;
+    a new seed, size or dtype is three new executables, a new λ none.
+    What ``jax.jit``'s cache then keeps alive is the first call's map
+    objects with their memoized shift vectors (``sketch/rft.py::shifts``,
+    ``::_turns``: s f32 numbers a map) and no array of the caller's.
+    ``block_fn`` is traced once a spec and operand shapes: whatever it
+    reads besides its arguments is read then and never again.
 
-    All contractions consume the (block_rows, sz) panel in place via
-    dot_general with an f32 preferred_element_type: bf16 panels contract
-    at MXU rate with exact-f32 accumulation and are never rounded back
-    (the _psd_gram hazard) nor upcast into a materialized f32 copy.
-    precision='highest' pins the f32/f64 feature case.
+    Any other ``block_fn`` (a closure, a ``functools.partial``, a bound
+    method, a lambda, a function with default arguments or one that
+    reads data from its module's namespace) gets programs of its own
+    that close over the spec and die with the call, as every call's did
+    before: a cache keyed by such a function would keep alive whatever
+    it closes over, and go on reading a global the caller has rebound.
+    They are built again every call, a few hundred milliseconds of host
+    work in which the device waits; pass data through ``block_args``
+    instead.
     """
-    lam_ = jnp.float32(lam)
-
-    def chunk_Zp(start, bargs, ops):
-        """(block_rows, sz) feature panel of chunk c, built in-graph.
-        Natural rowwise layout: every consumer contracts it with
-        ``dot_general`` directly — materializing a transpose (or an
-        astype-to-f32 copy) of the panel costs ~3 extra HBM passes per
-        visit, measured ~2.3 s/sweep-pass at the 10M×4096 shape.  The
-        map's counter-realized operands are hoisted to ``ops`` (once per
-        program, outside the panel loop): XLA does not LICM the ~11 ms
-        per-visit W realization out of the fori_loop by itself."""
-        with jax.named_scope("krr.features"):
-            Xp = block_fn(start, block_rows, *bargs).astype(feature_dtype)
-            return maps[c].apply_with_operands(ops, Xp, Dimension.ROWWISE)
-
-    def _prec(dtype):
-        return None if dtype == jnp.bfloat16 else "highest"
-
-    @jax.jit
-    def gram(*bargs):
-        ops = maps[c].hoistable_operands(feature_dtype)
-
-        def body(p, G):
-            Zp = chunk_Zp(p * block_rows, bargs, ops)
-            with jax.named_scope("krr.gram_product"):
-                blk = jax.lax.dot_general(
-                    Zp, Zp, (((0,), (0,)), ((), ())),
-                    precision=_prec(Zp.dtype),
-                    preferred_element_type=jnp.float32,
-                )
-                return G + blk
-
-        G = jax.lax.fori_loop(
-            0, nb, body, jnp.zeros((sz, sz), jnp.float32)
-        )
-        return G + lam_ * jnp.eye(sz, dtype=jnp.float32)
-
-    # The residual travels as (nb, block_rows, t): panels on the LEADING
-    # (unsharded) axis, rows of each panel on the shardable middle axis.
-    # A traced-index slice R3[p] then never touches the sharded
-    # dimension, so GSPMD keeps it local — the (N, t) layout with a
-    # traced-offset dynamic_slice cost a full all-gather of R per sweep
-    # on the virtual mesh (compiled-HLO finding, round 4; the one-time
-    # reshard into panel-major happens outside the sweep loop).
-
-    @jax.jit
-    def zr(R3, Wc, *bargs):
-        ops = maps[c].hoistable_operands(feature_dtype)
-
-        def body(p, acc):
-            Zp = chunk_Zp(p * block_rows, bargs, ops)
-            with jax.named_scope("krr.zr_product"):
-                Rp = jax.lax.dynamic_index_in_dim(R3, p, 0, keepdims=False)
-                return acc + jax.lax.dot_general(
-                    Zp, Rp, (((0,), (0,)), ((), ())),
-                    precision=_prec(Zp.dtype),
-                    preferred_element_type=jnp.float32,
-                )
-
-        acc0 = jnp.zeros((sz, t), jnp.float32)
-        return jax.lax.fori_loop(0, nb, body, acc0) - lam_ * Wc
-
-    @jax.jit
-    def apply_delta(R3, delta, *bargs):
-        ops = maps[c].hoistable_operands(feature_dtype)
-
-        def body(p, R3):
-            Zp = chunk_Zp(p * block_rows, bargs, ops)
-            with jax.named_scope("krr.delta_product"):
-                upd = jax.lax.dot_general(
-                    Zp, delta.astype(Zp.dtype), (((1,), (0,)), ((), ())),
-                    precision=_prec(Zp.dtype),
-                    preferred_element_type=jnp.float32,
-                )
-                Rp = jax.lax.dynamic_index_in_dim(R3, p, 0, keepdims=False)
-                return jax.lax.dynamic_update_index_in_dim(
-                    R3, Rp - upd, p, 0
-                )
-
-        return jax.lax.fori_loop(0, nb, body, R3)
-
-    return gram, zr, apply_delta
+    spec = _ChunkSpec(
+        maps if isinstance(maps, _Maps) else _Maps(maps), c, nb, block_rows,
+        jnp.dtype(feature_dtype), block_fn,
+    )
+    if _lives_with_its_module(block_fn):
+        return tuple(_ChunkProgram(p, spec) for p in (gram, zr, apply_delta))
+    return tuple(
+        _ChunkProgram(_program(b, spec)) for b in (_gram, _zr, _apply_delta))

@@ -31,6 +31,22 @@ __all__ = ["FeatureMapModel", "KernelModel", "load_model"]
 _SERIAL_VERSION = 2  # tracks sketch.base.SERIAL_VERSION (stream revision)
 
 
+class _Maps:
+    """Feature maps as a static argument of a trainer's programs
+    (``ml/admm.py``, ``ml/krr.py``): equal when their serialized forms
+    are (a map is a pure function of its JSON)."""
+
+    def __init__(self, maps):
+        self.maps = tuple(maps)
+        self.key = tuple(S.to_json() for S in self.maps)
+
+    def __hash__(self):
+        return hash(self.key)
+
+    def __eq__(self, other):
+        return isinstance(other, _Maps) and self.key == other.key
+
+
 def _json_info(info):
     """Best-effort JSON image of a model's ``info`` dict (the recovery /
     policy ledgers attached by the training entrypoints).  Non-JSON

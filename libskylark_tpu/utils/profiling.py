@@ -131,14 +131,16 @@ def note(fn, args, kw, lower=None) -> None:
     character that is no letter, digit or ``_`` made ``_``) and the
     call's signature, the arguments with every array leaf replaced by
     its ``ShapeDtypeStruct``.  A record holds no ``jax.Array``: what a
-    noted ``fn`` closes over is the caller's to keep small (the
-    streamed-KRR chunk programs keep their feature map and one scalar).
-    A later call with the same key replaces the function (a trainer that
-    builds its programs anew every call leaves one set behind, the
-    newest).  ``lower``, where given, makes the jitted function that
-    :func:`records` lowers in place of ``fn``: the same program without
-    the side effects of tracing ``fn`` (``plans.SketchPlan``).  Nothing
-    is noted under an enclosing trace: that call launches nothing."""
+    noted ``fn`` closes over, or takes as a static argument, is the
+    caller's to keep small (the streamed-KRR chunk programs' spec holds
+    the feature maps and ``block_fn``).  A later call with the same key
+    replaces the function (programs built anew every call, as the
+    streamed trainer's are for a closure ``block_fn``, leave one set
+    behind, the newest).  ``lower``, where given, makes the jitted
+    function that :func:`records` lowers in place of ``fn``: the same
+    program without the side effects of tracing ``fn``
+    (``plans.SketchPlan``).  Nothing is noted under an enclosing trace:
+    that call launches nothing."""
     leaves, tree = jax.tree.flatten((args, kw))
     if any(isinstance(x, jax.core.Tracer) for x in leaves):
         return

@@ -787,7 +787,7 @@ class TestStreamingKrrCommSchedule:
             return constrain_rows(base * 1e-3, mesh)
 
         progs = streaming_krr_chunk_programs(
-            maps, 0, sizes[0], N // BR, BR, T, 0.1, block_fn, jnp.float32
+            maps, 0, N // BR, BR, block_fn, jnp.float32
         )
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -797,7 +797,8 @@ class TestStreamingKrrCommSchedule:
             (N // BR, BR, T), jnp.float32, sharding=row_sh
         )
         W = jax.ShapeDtypeStruct((sizes[0], T), jnp.float32, sharding=rep_sh)
-        return progs, R, W
+        lam = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep_sh)
+        return progs, lam, R, W
 
     @staticmethod
     def _counts(jitted, *specs):
@@ -807,19 +808,19 @@ class TestStreamingKrrCommSchedule:
         return Counter(m.group(1) for m in _COLLECTIVE_RE.finditer(txt))
 
     def test_gram_one_allreduce_hoisted(self):
-        (gram, _, _), R, W = self._programs()
-        counts = self._counts(gram)
+        (gram, _, _), lam, R, W = self._programs()
+        counts = self._counts(gram, lam)
         assert counts == {"all-reduce": 1}, counts
 
     def test_zr_schedule(self):
         """Panel-major R (round 4): the traced-index panel slice stays
         off the sharded axis, so zr's only collective is the hoisted
         partial-contraction psum — the R all-gather is GONE."""
-        (_, zr, _), R, W = self._programs()
-        counts = self._counts(zr, R, W)
+        (_, zr, _), lam, R, W = self._programs()
+        counts = self._counts(zr, lam, R, W)
         assert counts == {"all-reduce": 1}, counts
 
     def test_apply_delta_schedule(self):
-        (_, _, apply_delta), R, W = self._programs()
+        (_, _, apply_delta), lam, R, W = self._programs()
         counts = self._counts(apply_delta, R, W)
         assert not counts, counts

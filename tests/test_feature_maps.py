@@ -444,6 +444,10 @@ class TestRFTEpilogue:
         )
 
 
+def _rows_of(start, rows, X):
+    return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
+
+
 class TestChunkProgramEpilogue:
     """What the streamed trainer's three programs lower to, by feature
     dtype (counted in the StableHLO text; no device needed)."""
@@ -457,18 +461,16 @@ class TestChunkProgramEpilogue:
         maps = [GaussianKernel(self.D, sigma=4.0).create_rft(
             self.SZ, "regular", SketchContext(seed=9))]
 
-        def block_fn(start, rows, X):
-            return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
-
         gram, zr, apply_delta = streaming_krr_chunk_programs(
-            maps, 0, self.SZ, self.NB, self.BR, self.T, 0.1, block_fn, dtype
+            maps, 0, self.NB, self.BR, _rows_of, dtype
         )
+        lam = jnp.float32(0.1)
         X = jnp.zeros((self.NB * self.BR, self.D), dtype)
         R = jnp.zeros((self.NB, self.BR, self.T), jnp.float32)
         W = jnp.zeros((self.SZ, self.T), jnp.float32)
         return {
-            "gram": gram.lower(X).as_text(),
-            "zr": zr.lower(R, W, X).as_text(),
+            "gram": gram.lower(lam, X).as_text(),
+            "zr": zr.lower(lam, R, W, X).as_text(),
             "apply_delta": apply_delta.lower(R, W, X).as_text(),
         }
 

@@ -56,14 +56,17 @@ def _block_fn(start, rows, X):
     return jax.lax.dynamic_slice_in_dim(X, start, rows, 0)
 
 
-def _krr_stream():
+def _krr_stream(closure=False):
     rng = np.random.default_rng(7)
     X = jnp.asarray(rng.standard_normal((ROWS, D)), F32)
     Y = jnp.asarray(rng.standard_normal((ROWS, T)), F32)
+    # a block_fn that closes over X gets programs of its call's own
+    block_fn = (lambda start, rows: _block_fn(start, rows, X)) if closure else _block_fn
     return ml.streaming_kernel_ridge(
-        ml.GaussianKernel(D, sigma=3.0), _block_fn, (ROWS, D), Y, 1.0, S,
+        ml.GaussianKernel(D, sigma=3.0), block_fn, (ROWS, D), Y, 1.0, S,
         SketchContext(seed=3), ml.KrrParams(max_split=2 * S, iter_lim=2),
-        block_rows=PANEL, feature_dtype=F32, block_args=(X,)).W
+        block_rows=PANEL, feature_dtype=F32,
+        block_args=() if closure else (X,)).W
 
 
 def _svd():
@@ -158,14 +161,18 @@ def test_records_lowers_a_plan_without_counting_a_trace(tmp_path):
     assert rec["scopes"] and plans.stats() == before
 
 
-def test_a_second_call_of_a_trainer_that_rebuilds_its_programs_adds_no_key(tmp_path):
-    _krr_stream()
+@pytest.mark.parametrize("closure", [True, False], ids=["rebuilt", "shared"])
+def test_a_second_call_of_a_trainer_adds_no_key(tmp_path, closure):
+    """Programs built anew every call leave one set behind, the newest;
+    the shared ones are keyed by their spec's value and stay."""
+    _krr_stream(closure)
     with profiling.trace(str(tmp_path)):
-        _krr_stream()
+        _krr_stream(closure)
         first = {id(n.fn) for n in profiling._NOTED.values()}
-        _krr_stream()
+        _krr_stream(closure)
         second = {id(n.fn) for n in profiling._NOTED.values()}
-    assert len(first) == len(second) == 3 and not first & second
+    assert len(first) == len(second) == 3
+    assert not first & second if closure else first == second
     assert len(profiling.records()) == 3
 
 

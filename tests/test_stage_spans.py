@@ -313,6 +313,46 @@ def test_a_second_block_admm_call_builds_no_program(tmp_path, monkeypatch, cache
     assert not {"lowerings", "traces", "compiles"} & set(ends["block_admm_train"])
 
 
+def test_a_second_krr_call_builds_no_chunk_program(tmp_path, monkeypatch):
+    """The streamed trainer's three chunk programs are module-level and
+    keyed by the maps' value: the cold call builds each once, under the
+    span that launches it, and a warm call's ``span_end``s carry no
+    build.  ``snapshot()`` sums them by span name, so the Gram program's
+    hit share is 1 - lowerings / calls."""
+    from libskylark_tpu.ml import krr
+
+    off = _krr_train()
+    for program in (krr.gram, krr.zr, krr.apply_delta):
+        program.clear_cache()  # the first call below is the cold one
+    monkeypatch.setenv("SKYLARK_TELEMETRY", "1")
+    telemetry.configure(str(tmp_path))
+    telemetry.reset()
+    try:
+        on = [_krr_train(), _krr_train()]
+        telemetry.flush()
+        with open(telemetry.ledger_path()) as fh:
+            events = [json.loads(line) for line in fh]
+        snap = telemetry.snapshot()
+    finally:
+        telemetry.close()
+        telemetry.configure(None)
+        telemetry.reset()
+    assert all(np.asarray(x).tobytes() == np.asarray(off).tobytes() for x in on)
+    # (`traces` also counts dispatches off `jax.jit`'s fast path)
+    built = {"lowerings", "compiles"}
+    for name, times in (("krr.gram", 1), ("krr.zr", 2), ("krr.apply_delta", 2)):
+        ends = [e["attrs"] for e in events
+                if e["kind"] == "span_end" and e["name"] == name]
+        assert len(ends) == 2 * times
+        assert ends[0]["lowerings"] == 1 and ends[0]["lower_s"] > 0
+        assert not any(built & set(end) for end in ends[1:]), name
+        per_name = snap["spans"][name]
+        assert per_name["calls"] == 2 * times and per_name["lowerings"] == 1
+    cold, warm = [e["attrs"] for e in events
+                  if e["kind"] == "span_end" and e["name"] == "krr_train"]
+    assert cold["lowerings"] >= 3 and not built & set(warm)
+
+
 def test_each_block_admm_stage_launches_one_program(tmp_path_factory):
     """The benchmark reads the factor and iterate programs' device time
     by the spans they are launched under."""

@@ -200,6 +200,12 @@ def test_jlt_apply_full_size(one_chip):
     assert "tpu_custom_call" not in text  # a plain MXU matmul
 
 
+def _krr_rows(start, rows, X):
+    """A ``block_fn`` that lives with its module: the streamed trainer's
+    shared chunk programs, the ones the benchmark's cell runs."""
+    return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
+
+
 def test_streaming_krr_sweep_step_full_size(one_chip):
     """One sweep step (``zr``) of the streaming-KRR chunk programs at
     the north-star panel: 131072x4096 -> 2048 bf16, 8 panels."""
@@ -214,10 +220,10 @@ def test_streaming_krr_sweep_step_full_size(one_chip):
         return jnp.roll(X0, start // rows, axis=0)
 
     _, zr, _ = streaming_krr_chunk_programs(
-        maps, 0, SZ, NB, BR, 1, 0.1, block_fn, BF16
+        maps, 0, NB, BR, block_fn, BF16
     )
-    compiled = _compile(zr, one_chip, ((NB, BR, 1), F32), ((SZ, 1), F32),
-                        ((BR, D), BF16))
+    compiled = _compile(zr, one_chip, ((), F32),
+                        ((NB, BR, 1), F32), ((SZ, 1), F32), ((BR, D), BF16))
     assert compiled.memory_analysis().temp_size_in_bytes < 12 << 30
 
 
@@ -235,14 +241,11 @@ def test_streaming_krr_feature_pass_is_one_output_fusion(one_chip, program):
     maps = [GaussianKernel(D, sigma=28.0).create_rft(
         SZ, "regular", SketchContext(seed=9))]
 
-    def block_fn(start, rows, X):
-        return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
-
-    progs = dict(zip(("gram", "zr", "apply_delta"), streaming_krr_chunk_programs(
-        maps, 0, SZ, NB, BR, T, 1.0, block_fn, BF16)))
-    X, R, W = ((NB * BR, D), BF16), ((NB, BR, T), F32), ((SZ, T), F32)
-    shapes = (X,) if program == "gram" else (R, W, X)
-    text = _text(progs[program], one_chip, *shapes)
+    progs = streaming_krr_chunk_programs(maps, 0, NB, BR, _krr_rows, BF16)
+    progs = dict(zip(("gram", "zr", "apply_delta"), progs))
+    lam, X, R, W = ((), F32), ((NB * BR, D), BF16), ((NB, BR, T), F32), ((SZ, T), F32)
+    shapes = {"gram": (lam, X), "zr": (lam, R, W, X), "apply_delta": (R, W, X)}
+    text = _text(progs[program], one_chip, *shapes[program])
 
     panel = rf"\[{BR},{SZ}\]"
     fused = [b for b in text.split("\n\n")  # a computation a paragraph
@@ -399,7 +402,9 @@ def test_block_admm_iterate_carries_its_scopes_and_the_parents_instructions(one_
 
 @pytest.mark.parametrize("program,product,counts", [
     ("gram", "krr.gram_product", (10, 2)),
-    ("zr", "krr.zr_product", (9, 2)),
+    # one more than the parent's 9: with λ an operand the epilogue
+    # ``acc - λ·Wc`` is a fusion (λ = 1 a constant folded it to a subtract)
+    ("zr", "krr.zr_product", (10, 2)),
     ("apply_delta", "krr.delta_product", (9, 2)),
 ])
 def test_streaming_krr_programs_carry_their_scopes_and_the_parents_instructions(
@@ -411,15 +416,12 @@ def test_streaming_krr_programs_carry_their_scopes_and_the_parents_instructions(
     maps = [GaussianKernel(D, sigma=28.0).create_rft(
         SZ, "regular", SketchContext(seed=9))]
 
-    def block_fn(start, rows, X):
-        return jax.lax.dynamic_slice_in_dim(X, start, rows, axis=0)
-
-    progs = dict(zip(("gram", "zr", "apply_delta"), streaming_krr_chunk_programs(
-        maps, 0, SZ, NB, BR, T, 1.0, block_fn, BF16)))
-    X, R, W = ((NB * BR, D), BF16), ((NB, BR, T), F32), ((SZ, T), F32)
-    shapes = (X,) if program == "gram" else (R, W, X)
-    text = _text(progs[program], one_chip, *shapes)
-    assert _counts(text) == counts  # the parent's (commit ce4deed)
+    progs = streaming_krr_chunk_programs(maps, 0, NB, BR, _krr_rows, BF16)
+    progs = dict(zip(("gram", "zr", "apply_delta"), progs))
+    lam, X, R, W = ((), F32), ((NB * BR, D), BF16), ((NB, BR, T), F32), ((SZ, T), F32)
+    shapes = {"gram": (lam, X), "zr": (lam, R, W, X), "apply_delta": (R, W, X)}
+    text = _text(progs[program], one_chip, *shapes[program])
+    assert _counts(text) == counts  # the loops' are the parent's (commit 9c37271)
     # the turns epilogue nests under the feature pass
     assert re.search(r'op_name="[^"]*krr\.features/[^"]*rft\.epilogue\.turns', text)
     assert {"krr.features", product} <= _scopes_that_own_an_operation(text)
