@@ -7,8 +7,31 @@ from __future__ import annotations
 
 import argparse
 import sys
+import time
 
 import numpy as np
+
+
+def _integer_arcs(path):
+    """``(vertices, u, v)`` of an arc list whose vertex names are all
+    integers: the distinct ids ascending and every arc's ends as positions
+    among them; None where a name is not an integer.  Self-loops are
+    dropped as the parser drops them for ``SimpleGraph``."""
+    from ..io.arclist import _chunk_lines, _parse_edge_block
+    from ..io.source import open_source
+
+    us: list[str] = []
+    vs: list[str] = []
+    for block in _chunk_lines(open_source(path), 8 << 20):
+        bu, bv = _parse_edge_block(block)
+        us.extend(bu)
+        vs.extend(bv)
+    try:
+        ids = np.array(us + vs, dtype=np.int64)
+    except (ValueError, OverflowError):
+        return None
+    vertices, at = np.unique(ids, return_inverse=True)
+    return vertices, at[: len(us)].astype(np.int32), at[len(us):].astype(np.int32)
 
 
 def main(argv=None) -> int:
@@ -28,10 +51,23 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     from ..core.context import SketchContext
-    from ..graph import ASEParams, approximate_ase, read_arc_list
+    from ..core.sparse import prepare
+    from ..graph import (
+        ASEParams, adjacency_from_edges, approximate_ase, read_arc_list)
 
-    G = read_arc_list(args.graphfile)
-    print(f"Read graph: {G.n} vertices, {G.volume // 2} edges")
+    # --sparse on an arc list of integers: the adjacency and the product's
+    # layout of it are built on the device, here, where the graph is made
+    arcs = _integer_arcs(args.graphfile) if args.sparse and not args.streamed else None
+    if arcs is not None:
+        vertices, u, v = arcs
+        A = adjacency_from_edges(u, v, len(vertices))
+        G = prepare(A, symmetric=True)
+        print(f"Read graph: {len(vertices)} vertices, {A.nse // 2} edges "
+              "(adjacency built on the device)")
+    else:
+        G = read_arc_list(args.graphfile)
+        vertices = G.vertices
+        print(f"Read graph: {G.n} vertices, {G.volume // 2} edges")
     if args.streamed:
         params = ASEParams(
             num_iterations=0, streamed=True, batch_edges=args.batch_edges
@@ -40,17 +76,24 @@ def main(argv=None) -> int:
         params = ASEParams(
             num_iterations=args.num_iterations, sparse=args.sparse
         )
-    X, lam = approximate_ase(
+    t0 = time.perf_counter()
+    (X, lam), info = approximate_ase(
         G,
         args.rank,
         SketchContext(seed=args.seed),
         params,
+        return_info=True,
     )
-    np.save(f"{args.prefix}.X.npy", np.asarray(X))
+    X = np.asarray(X)
+    seconds = time.perf_counter() - t0
+    np.save(f"{args.prefix}.X.npy", X)
     with open(f"{args.prefix}.index.txt", "w") as f:
-        for v in G.vertices:
+        for v in vertices:
             f.write(f"{v}\n")
-    print(f"Embeddings ({G.n}x{args.rank}) -> {args.prefix}.X.npy; "
+    if "products" in info:
+        print(f"{info['products']} products with the adjacency, "
+              f"{seconds:.3f} s the call")
+    print(f"Embeddings ({X.shape[0]}x{args.rank}) -> {args.prefix}.X.npy; "
           f"eigenvalues: {np.asarray(lam)}")
     return 0
 

@@ -3,7 +3,7 @@ embedding and seed-set local community detection."""
 
 from .ase import ASEParams, approximate_ase
 from .community import find_local_cluster, time_dependent_ppr
-from .graph import SimpleGraph, read_arc_list
+from .graph import SimpleGraph, adjacency_from_edges, read_arc_list
 from .stream import (
     adjacency_sketch_fold,
     ase_from_sketch,
@@ -17,6 +17,7 @@ from .stream import (
 __all__ = [
     "SimpleGraph",
     "read_arc_list",
+    "adjacency_from_edges",
     "ASEParams",
     "approximate_ase",
     "time_dependent_ppr",

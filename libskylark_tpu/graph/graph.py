@@ -15,9 +15,71 @@ interpreter time instead of seconds.
 
 from __future__ import annotations
 
-import numpy as np
+from functools import partial
 
-__all__ = ["SimpleGraph", "read_arc_list"]
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import sparse as jsparse
+
+__all__ = ["SimpleGraph", "read_arc_list", "adjacency_from_edges"]
+
+
+@jax.jit
+def _sort_pairs(a, b):
+    """(a, b) sorted by a, then b.  Two keys: ``a * n + b`` does not fit
+    32 bits.  One program for both sorts below (a sort of 10⁸ pairs takes
+    the TPU's compiler a minute to build, and a moment to run)."""
+    return lax.sort((a, b), num_keys=2)
+
+
+@partial(jax.jit, static_argnames=("n",))
+def _both_directions(u, v, *, n: int):
+    """(rows, cols): every arc in both directions; a self-loop or an arc
+    with an end outside ``[0, n)`` becomes ``(n, n)``, twice."""
+    off = (u == v) | (jnp.minimum(u, v) < 0) | (jnp.maximum(u, v) >= n)
+    u, v = jnp.where(off, n, u), jnp.where(off, n, v)
+    return jnp.concatenate([u, v]), jnp.concatenate([v, u])
+
+
+@partial(jax.jit, static_argnames=("n",))
+def _first_of_each(rows, cols, *, n: int):
+    """Sorted pairs with every copy after the first made ``(n, n)``, and
+    how many pairs inside the matrix are left."""
+    again = jnp.concatenate([jnp.zeros((1,), bool),
+                             (rows[1:] == rows[:-1]) & (cols[1:] == cols[:-1])])
+    rows, cols = jnp.where(again, n, rows), jnp.where(again, n, cols)
+    return rows, cols, jnp.sum(rows < n)
+
+
+@partial(jax.jit, static_argnames=("nnz",))
+def _indices(rows, cols, *, nnz: int):
+    return jnp.stack([rows[:nnz], cols[:nnz]], axis=1)
+
+
+def adjacency_from_edges(u, v, n: int, dtype=jnp.float32):
+    """The adjacency matrix of the simple undirected graph on ``n``
+    vertices with the arcs ``(u[i], v[i])`` (integer arrays, ids in
+    ``[0, n)``): an ``n × n`` BCOO of ones, symmetric, self-loops dropped
+    and duplicate edges merged (in either direction), ``int32`` indices
+    sorted by row and column and marked so.
+
+    What ``SimpleGraph(zip(u, v)).adjacency_bcoo()`` builds through a
+    Python dict and NumPy on the host, for vertices that are already
+    integers, in jitted programs that never leave the device: both
+    directions of every arc are sorted, the repeats moved to ``(n, n)``,
+    and a second sort puts those behind the rest.  The number of nonzeros
+    is read once in between: it is a shape."""
+    u, v = jnp.asarray(u, jnp.int32), jnp.asarray(v, jnp.int32)
+    n = int(n)
+    rows, cols = _sort_pairs(*_both_directions(u, v, n=n))
+    rows, cols, nnz = _first_of_each(rows, cols, n=n)
+    idx = _indices(*_sort_pairs(rows, cols), nnz=int(nnz))
+    return jsparse.BCOO(
+        (jnp.ones((idx.shape[0],), dtype), idx), shape=(n, n),
+        indices_sorted=True, unique_indices=True,
+    )
 
 
 class SimpleGraph:
@@ -142,9 +204,6 @@ class SimpleGraph:
 
     def adjacency_bcoo(self, dtype=None):
         """Sparse BCOO adjacency."""
-        import jax.numpy as jnp
-        from jax.experimental import sparse as jsparse
-
         dtype = dtype or jnp.asarray(0.0).dtype
         rows = np.repeat(np.arange(self.n), self.degrees)
         idx = np.stack([rows, self.indices], axis=1).astype(np.int32)

@@ -27,6 +27,7 @@ from .. import guard, telemetry
 from ..core.context import SketchContext
 from ..core.matrices import gaussian_matrix
 from ..core.params import Params
+from ..core.sparse import Prepared, edge_chunks, spmm
 from ..parallel.mesh import fully_replicated
 from ..resilient.chunked import ChunkedSolver
 from ..sketch.base import Dimension
@@ -99,6 +100,21 @@ def _sketch_size(k: int, params: SVDParams, n: int, m: int | None = None):
     return k, max(s, k)
 
 
+def _is_sparse(A) -> bool:
+    """A BCOO, or one prepared for its products (``core.sparse.prepare``)."""
+    return hasattr(A, "todense") or isinstance(A, Prepared)
+
+
+def _times(A, X, *, transpose: bool = False):
+    """``A·X`` (``Aᵀ·X``) inside the programs below: a prepared sparse
+    operand goes through the chunked product (``core.sparse.spmm``); a
+    dense one and a plain BCOO (``bcoo_dot_general``) are the parent's
+    expression to the letter."""
+    if isinstance(A, Prepared):
+        return spmm(A, X, transpose=transpose)
+    return A.T @ X if transpose else A @ X
+
+
 @jax.jit
 def _project(A, Q):
     """``Aᵀ·Q`` at full precision, A's rows contracted where they lie.  One
@@ -127,7 +143,7 @@ def _chunk(st, A, num_iters, niter, *, orthogonalize: bool):
 
     def body(c):
         with jax.named_scope("svd.sweep_products"):
-            Y = A @ (A.T @ c["Y"])
+            Y = _times(A, _times(A, c["Y"], transpose=True))
         return dict(it=c["it"] + 1, Y=_orth(Y) if orthogonalize else Y)
 
     return lax.while_loop(cond, body, st)
@@ -311,11 +327,46 @@ def _guarded_svd(A, rank, context, params):
     return (Uf[:, :rank], svf[:rank], Vtf[:rank].T), report
 
 
+@jax.jit
+def _sym_sketch(A, Wt):
+    """``A·Ωᵀ`` with the realized ``Ωᵀ`` (n × s) an argument: the product
+    of ``JLT.apply(A, ROWWISE)`` as one program that no sketch's seed is
+    part of."""
+    return _times(A, Wt)
+
+
+@partial(jax.jit, static_argnames=("k", "orthogonalize"))
+def _ritz(A, Y, *, k: int, orthogonalize: bool):
+    """Rayleigh-Ritz on the span of ``Y`` (≙ ``nla/svd.hpp:360-380``):
+    ``T = Qᵀ·A·Q`` symmetrized, its eigenpairs sorted by |λ|, the first
+    ``k``.  Pinned at highest: T's error lands directly in the
+    eigenvalues and in V's orthogonality (a BCOO product has no
+    precision to pin: it is f32 sums)."""
+    Q = _orth(Y) if orthogonalize else Y
+    AQ = _times(A, Q) if _is_sparse(A) else jnp.dot(
+        A, Q, precision="highest"
+    )
+    # Qᵀ stands as an array before the product, as it did op by op: folded
+    # into the dot, XLA:CPU sums T in another order and the dense result
+    # is no longer the eager recurrence's to the bit
+    Qt = lax.optimization_barrier(Q.T)
+    T = fully_replicated(jnp.dot(Qt, AQ, precision="highest"))
+    T = (T + T.T) / 2
+    lam, W = jnp.linalg.eigh(T)
+    order = jnp.argsort(-jnp.abs(lam))
+    lam = lam[order][:k]
+    V = jnp.dot(Q, W, precision="highest")[:, order[:k]]
+    return V, lam
+
+
 def approximate_symmetric_svd(
     A,
     rank: int,
     context: SketchContext,
     params: SVDParams | None = None,
+    *,
+    return_info: bool = False,
+    stage: str = "symsvd",
 ):
     """Randomized eigendecomposition of symmetric A: ``(V, lam)`` with
     ``A ≈ V @ diag(lam) @ V.T`` (eigenvalues sorted by |lam| descending).
@@ -323,30 +374,53 @@ def approximate_symmetric_svd(
     ≙ ``ApproximateSymmetricSVD`` (``nla/svd.hpp:321-392``): explicit
     Gaussian test matrix, subspace iteration, Schur-Rayleigh-Ritz step
     (the reference's ``HermitianEig`` on the compressed ``QᵀAQ``).
+
+    Three cached programs, each under a span ``<stage>.sketch``,
+    ``<stage>.power``, ``<stage>.ritz`` (an entry that has stages of its
+    own, ``approximate_ase``, gives its own name): ``_sym_sketch``, the
+    sweep segment ``_chunk`` of :func:`approximate_svd` with the whole
+    budget, ``_ritz``.  A (dense, a BCOO, or one prepared by
+    ``core.sparse.prepare``, whose products are ``core.sparse.spmm``:
+    the route for a graph too large for ``bcoo_dot_general``) and the
+    panel are arguments of all three, so a warm call traces and lowers
+    nothing.  ``return_info=True`` returns
+    ``((V, lam), info)``: ``products`` (products with A the call ran:
+    the sketch's, two a sweep, the Ritz step's), ``iterations``, ``nnz``
+    and ``edge_chunks`` (steps a product walks a prepared operand's
+    nonzeros in; the entries of a dense A, and 0, as for a plain BCOO).
     """
     params = params or SVDParams()
-    if not hasattr(A, "todense"):
+    sparse = _is_sparse(A)
+    if not sparse:
         A = jnp.asarray(A)
     n = A.shape[0]
     k, s = _sketch_size(rank, params, n)
+    niter = max(params.num_iterations, 0)
+    orthogonalize = not params.skip_qr
+    dtype = A.dtype if jnp.issubdtype(A.dtype, jnp.floating) else jnp.float32
 
-    omega = JLT(n, s, context)
-    Y = omega.apply(A, Dimension.ROWWISE)  # A·Omegaᵀ (symmetric A)
-    Y = power_iteration(A, Y, params.num_iterations, not params.skip_qr)
-    Q = Y if (params.num_iterations > 0 and not params.skip_qr) else _orth(Y)
-
-    # Rayleigh-Ritz on the subspace (≙ nla/svd.hpp:360-380); pinned —
-    # T's error lands directly in the eigenvalues and V's orthogonality.
-    AQ = A @ Q if hasattr(A, "todense") else jnp.dot(
-        A, Q, precision="highest"
-    )
-    T = fully_replicated(jnp.dot(Q.T, AQ, precision="highest"))
-    T = (T + T.T) / 2
-    lam, W = jnp.linalg.eigh(T)
-    order = jnp.argsort(-jnp.abs(lam))
-    lam = lam[order][:k]
-    V = jnp.dot(Q, W, precision="highest")[:, order[:k]]
-    return V, lam
+    with telemetry.span(f"{stage}.sketch"):
+        # A·Omegaᵀ (symmetric A): Omega realized as JLT.apply realizes it
+        Wt = JLT(n, s, context).realize(dtype).T
+        Y = profiling.launch(_sym_sketch, A, Wt)
+    with telemetry.span(f"{stage}.power"):
+        if niter:
+            st = dict(it=jnp.zeros((), jnp.int32), Y=Y)
+            Y = profiling.launch(
+                _chunk, st, A, niter, niter, orthogonalize=orthogonalize
+            )["Y"]
+    with telemetry.span(f"{stage}.ritz"):
+        out = profiling.launch(
+            _ritz, A, Y, k=k, orthogonalize=not (niter and orthogonalize)
+        )
+    if return_info:
+        return out, {
+            "products": 2 + 2 * niter,
+            "iterations": niter,
+            "nnz": A.nse if sparse else A.size,
+            "edge_chunks": edge_chunks(A, s) if isinstance(A, Prepared) else 0,
+        }
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -425,3 +425,70 @@ def test_streaming_krr_programs_carry_their_scopes_and_the_parents_instructions(
     # the turns epilogue nests under the feature pass
     assert re.search(r'op_name="[^"]*krr\.features/[^"]*rft\.epilogue\.turns', text)
     assert {"krr.features", product} <= _scopes_that_own_an_operation(text)
+
+
+# -- the sparse-times-panel product: no nnz x s buffer ------------------------
+
+
+def _prepared_shapes(n, nnz, one_chip):
+    """A ``core.sparse.Prepared`` of abstract arrays at the size of an
+    ``n``-vertex graph with ``nnz`` nonzeros: the column blocks and
+    bucket counts ``prepare`` gives a graph of that size (a tenth more
+    slots than nonzeros; rows spread over a dozen piece counts)."""
+    from libskylark_tpu.core import sparse
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blocks = -(-n // sparse.TABLE_ROWS)
+    counts = [c for c in sparse._counts(4096) if c >= 2][:24]
+    rows = [n // len(counts)] * len(counts)
+    rows[0] += n - sum(rows)
+    want = 1.1 * nnz / blocks / sparse.PIECE            # pieces a block
+    scale = want / sum(r * k for r, k in zip(rows, counts))
+    buckets = tuple((r, max(int(round(k * scale)), 1)) for r, k in zip(rows, counts))
+    pieces = sum(r * k for r, k in buckets)
+    return sparse.Prepared(
+        cols=(shaped((sparse.PIECE, pieces), I32),) * blocks,
+        vals=(shaped((sparse.PIECE, pieces), F32),) * blocks,
+        place=(shaped((n,), I32),) * blocks,
+        shape=(n, n), nse=nnz, buckets=(buckets,) * blocks, symmetric=True)
+
+
+def test_sparse_product_holds_a_chunk_of_gathered_rows_and_no_more(one_chip):
+    """The sweep segment of ``approximate_ase`` at the benchmark cell's
+    size (``graph_se_orkut_f32``: its vertices, twice its edges, s = 16,
+    the columns in two tables): ``bcoo_dot_general`` would hold every
+    nonzero's row of the panel at once (nnz x s x 4 bytes, gigabytes);
+    the chunked product holds a chunk's gathered rows, one block's
+    pieces' sums (an s-row for every eight slots: what still grows with
+    nnz, a seventh of it a block) and the panels."""
+    import json
+
+    from libskylark_tpu.core import sparse
+    from libskylark_tpu.linalg import svd
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs", "graph_se_orkut_f32.json")) as f:
+        z = json.load(f)
+    n, nnz, s = z["vertices"], 2 * z["edges"], z["s"]
+    A = _prepared_shapes(n, nnz, one_chip)
+    Y = jax.ShapeDtypeStruct((n, s), F32, sharding=one_chip)
+    st = dict(it=jax.ShapeDtypeStruct((), I32, sharding=one_chip), Y=Y)
+    with jax.enable_x64(False):
+        compiled = svd._chunk.lower(st, A, 2, 2, orthogonalize=True).compile()
+    mem = compiled.memory_analysis()
+    panel = n * s * 4
+    pieces = max(c.shape[1] for c in A.cols) * s * 4    # a block's pieces' sums
+    chunk = sparse.CHUNK_BYTES                           # a step's gathered rows
+    everything = nnz * s * 4                             # what this PR is for
+    assert len(A.cols) == 2
+    # 1.05 GB here with even buckets; 1.46 GB on the chip with the cell's own (PERF.md section 5)
+    assert mem.temp_size_in_bytes < pieces + 10 * chunk + 4 * panel < everything / 3
+    assert mem.argument_size_in_bytes < 1.2 * nnz * 8 + n * 4 * len(A.cols) + 2 * panel
+    text = compiled.as_text()
+    assert "sparse.product" in text and "svd.gram_orth" in text
+    slots = max(c.shape[1] for c in A.cols) * sparse.PIECE
+    for big in (nnz, slots):                             # no array of a row a nonzero
+        assert not re.search(rf"f32\[{big},{s}\]", text)
+        assert not re.search(rf"f32\[{s},{big}\]", text)
