@@ -16,39 +16,52 @@ dtype), no setting names it.  All of it lies under the named scope
 The operand is a :class:`Prepared`: built once from the BCOO by
 :func:`prepare`, outside any timed call, and handed to the programs as
 an argument like any array.  It holds the nonzeros in the order a TPU
-multiplies them fast in, every gather from a table small enough for the
-chip's faster gather.  A plain BCOO is not taken: it keeps going through
+multiplies them fast in, every gather from a table small enough for one
+of the chip's faster gathers.  A plain BCOO is not taken: it keeps going through
 ``A @ Y`` where the callers had it (``linalg/svd.py``,
 ``sketch/dense.py``, ``solvers/krylov.py``).
 
 What a product holds besides the operand and the panels: a chunk's
-gathered rows, and the pieces' sums of one block of columns, one
-``s``-row for every ``PIECE`` slots.  The second is the larger and grows
-with nnz: a seventh of the ``nnz × s`` of the block's own nonzeros
-(0.63 GB a block, where ``nnz × s`` is 9.0 GB, at 1.4 × 10⁸ nonzeros in
-two blocks and s = 16).
+gathered rows, and the pieces' sums of a table, one ``s``-row for every
+``PIECE`` slots.  The second is the larger and grows with nnz: an eighth
+of ``slots × s``, 0.51 GB for the largest of three tables where
+``nnz × s`` is 9.0 GB, at 1.4 × 10⁸ nonzeros and s = 16 (the TPU compiler
+may keep more than one table's at once: docs/performance.md has its
+counts).
 
-``TABLE_ROWS``, ``PIECE`` and ``CHUNK_BYTES`` are constants measured on
-one chip at one width, a v5e gathering 16-column f32 rows (64 bytes: a
-row of a table of 524 288 to 1 572 864 rows in 6.5 ns, of 2 097 152 rows
-and more in 22.6 ns; PERF.md section 6, PR 37).  The layout is built
-before any panel is seen, so it cannot follow the panel's width: a wider
-panel, another dtype or another chip gets these tables, at a cost nobody
-has measured.
+``TABLE_ROWS``, ``HOT_ROWS``, ``PIECE``, ``CHUNK_BYTES`` and the three
+rates ``prepare`` weighs a hot table with are constants measured on one
+chip at one width, a v5e gathering 16-column f32 rows (64 bytes: a row of
+a table of at most 196 608 rows in 2.7 ns, of
+524 288 to 1 572 864 rows in 6.5 ns, of 2 097 152 rows and more in
+22.6 ns; PERF.md section 6, PR 37 and 38).  The layout is built before
+any panel is seen, so it cannot follow the panel's width: a wider panel,
+another dtype or another chip gets these tables, at a cost nobody has
+measured.
 
 The layout.  The columns are cut into the fewest equal blocks of at most
-``TABLE_ROWS``; a block's rows of ``Y`` are the table its nonzeros
-gather from.  Within a block every row's nonzeros are cut into *pieces*
-of ``PIECE`` slots (the last padded with a slot that reads a zero row),
-rows are sorted by their number of pieces and grouped into buckets of
-equal count (counts are rounded up to 1 ... 8, 10, 12, 14, 16, 20, ...:
-four to the octave, a seventh more slots than nonzeros at a mean degree
-of 76 in two blocks), and a bucket's pieces are laid out piece-major.
-So the whole block is one ``(PIECE, pieces)`` array of local column
-indices and one of values; the product gathers ``PIECE`` panels of rows
-from the table and adds them up (a piece's sum), a bucket's row sums are
-one dense ``reshape(count, rows, s).sum(0)``, and the rows go back to
-their places by one gather a block.
+``TABLE_ROWS``; a block's rows of ``Y`` are the table its nonzeros gather
+from.  Where it pays, the ``HOT_ROWS`` columns with the most nonzeros are
+taken out of their blocks into a *hot table* before them, ``Y[hot]``,
+small enough for the chip's fastest gather: a graph's hubs are few and
+hold a third of its nonzeros and more.  ``prepare`` decides that from
+the operand's own column counts and no setting does (:func:`_hot_columns`
+has the rule): an operand of at most ``HOT_ROWS`` columns, or one whose
+counts are flat, keeps column blocks alone, the layout it had before
+there was a hot table, to the bit.  Within a table every row's nonzeros
+are cut into *pieces* of ``PIECE`` slots (the last padded with a slot
+that reads the zero row), rows are sorted by their number of pieces and
+grouped into buckets of equal count (counts are rounded up to 1 ... 8,
+10, 12, 14, 16, 20, ...: four to the octave, a sixth more slots than
+nonzeros at a mean degree of 76 in three tables), and a bucket's pieces
+are laid out piece-major.  So the whole table is one ``(PIECE, pieces)``
+array of local column indices and one of values; the product gathers
+``PIECE`` panels of rows from the table and adds them up (a piece's
+sum), ``CHUNK_BYTES`` of gathered rows a step in one loop a table (the
+last step starts where a whole step still fits, so no second copy of the
+step stands behind the loop for the pieces left over), a bucket's row
+sums are one dense ``reshape(count, rows, s).sum(0)``, and the rows go
+back to their places by one gather a table.
 """
 
 from __future__ import annotations
@@ -62,7 +75,7 @@ import numpy as np
 from jax import lax
 
 __all__ = ["spmm", "prepare", "Prepared", "edge_chunks",
-           "CHUNK_BYTES", "PIECE", "TABLE_ROWS", "SCOPE"]
+           "CHUNK_BYTES", "PIECE", "TABLE_ROWS", "HOT_ROWS", "SCOPE"]
 
 SCOPE = "sparse.product"
 
@@ -74,6 +87,18 @@ PIECE = 8
 # Rows of Y that one block of columns gathers from: the largest table
 # (of 16 f32 columns) that a v5e still gathers from at 6.5 ns a row.
 TABLE_ROWS = 3 << 19
+# Columns of the hot table: a v5e gathers from a table of so many rows
+# (and the zero row) at 2.7 ns a row, as from any of up to 196,608; from
+# 262,144 at 10.8.
+HOT_ROWS = 1 << 17
+# What ``prepare`` weighs a hot table with, ns a row of 16 f32 columns on
+# a v5e (docs/performance.md, "The sparse-times-panel product"): eight
+# gathers a step of a loop from a table of HOT_ROWS rows and from one of
+# up to TABLE_ROWS, and the gather that puts a table's rows back.
+HOT_NS, COLD_NS, PLACE_NS = 2.71, 6.45, 12.0
+# The hot table is built where what its nonzeros save is at least this
+# many times what the table costs.
+HOT_MARGIN = 2.0
 
 
 def _chunk(s: int, itemsize: int) -> int:
@@ -120,24 +145,30 @@ class Prepared:
     """A sparse matrix in the product's own layout (module docstring).
 
     ``cols[j]``, ``vals[j]``: the ``(PIECE, pieces_j)`` local column
-    indices (``table rows`` for a padding slot) and values of column
-    block j; ``place[j]``: for every row of the matrix, its position
-    among block j's bucketed rows.  Static: ``shape``, ``nse``,
-    ``buckets[j]`` (``(rows, count)`` of every bucket of block j, in
-    layout order), ``symmetric`` (the matrix equals its transpose, so
-    ``transpose=True`` is the same product)."""
+    indices (``table rows`` for a padding slot) and values of table j;
+    ``place[j]``: for every row of the matrix, its position among table
+    j's bucketed rows; ``hot``: the columns of the hot table, ascending
+    (table 0 is then ``Y[hot]``, the others the column blocks in order),
+    empty where there is none.  Static: ``shape``, ``nse``, ``hot_nse``
+    (nonzeros that gather from the hot table), ``buckets[j]`` (``(rows,
+    count)`` of every bucket of table j, in layout order), ``symmetric``
+    (the matrix equals its transpose, so ``transpose=True`` is the same
+    product)."""
 
     cols: tuple
     vals: tuple
     place: tuple
+    hot: jax.Array
     shape: tuple
     nse: int
+    hot_nse: int
     buckets: tuple
     symmetric: bool
 
     def tree_flatten(self):
-        return ((self.cols, self.vals, self.place),
-                (self.shape, self.nse, self.buckets, self.symmetric))
+        return ((self.cols, self.vals, self.place, self.hot),
+                (self.shape, self.nse, self.hot_nse, self.buckets,
+                 self.symmetric))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -147,6 +178,16 @@ class Prepared:
     def dtype(self):
         return self.vals[0].dtype
 
+    @property
+    def tables(self) -> int:
+        """Tables the nonzeros gather from, the hot one included."""
+        return len(self.cols)
+
+    @property
+    def hot_share(self) -> float:
+        """The share of the nonzeros that gather from the hot table."""
+        return self.hot_nse / self.nse if self.hot_nse else 0.0
+
 
 @jax.jit
 def _by_block_and_row(key, col, val):
@@ -154,8 +195,22 @@ def _by_block_and_row(key, col, val):
 
 
 @jax.jit
+def _keys(rows, cols, rank, m, n, table, tables):
+    """The sort key (``table · m + row``; past all ``tables`` for an
+    index past the shape, which pads a BCOO) and the local column of
+    every nonzero.  ``rank``: every column's row in the hot table or -1,
+    or None where there is no hot table."""
+    live = (rows < m) & (cols < n)
+    block, col = cols // table, cols % table
+    if rank is not None:
+        r = rank.at[cols].get(mode="clip")
+        block, col = jnp.where(r < 0, 1 + block, 0), jnp.where(r < 0, col, r)
+    return jnp.where(live, block * m + rows, tables * m), col
+
+
+@jax.jit
 def _fill(base, left, col, val, pad):
-    """The ``(PIECE, pieces)`` arrays of one block: slot w of a piece is
+    """The ``(PIECE, pieces)`` arrays of one table: slot w of a piece is
     nonzero ``base + w`` while ``w < left``, a padding slot after."""
     w = jnp.arange(PIECE, dtype=jnp.int32)[:, None]
     at = jnp.minimum(base[None, :] + w, col.shape[0] - 1)
@@ -164,31 +219,71 @@ def _fill(base, left, col, val, pad):
             jnp.where(live, val[at], jnp.zeros((), val.dtype)))
 
 
+def _hot_columns(A, symmetric: bool):
+    """The columns whose nonzeros gather from the hot table, ascending:
+    the ``HOT_ROWS`` of largest count, or none.  None where the columns
+    are one fast table as they are (``n <= HOT_ROWS``), and none unless
+    what the hot columns' nonzeros save, gathered at ``HOT_NS`` and not
+    ``COLD_NS``, is ``HOT_MARGIN`` times what one more table costs: its
+    rows put back (``m`` at ``PLACE_NS``) and its padding (up to a piece
+    a row, at ``HOT_NS``).  Flat counts leave the hot columns ``HOT_ROWS
+    / n`` of the nonzeros, which clears that only where n is near
+    ``HOT_ROWS`` or the rows are long; the hubs of a degree-skewed graph
+    hold a third of them and more.
+
+    The counts are the rows' for a symmetric matrix; else the nonzeros
+    are sorted by column with the layout's own sort program, at its
+    shapes (a sort of a new shape is a minute of the TPU compiler)."""
+    m, n = A.shape
+    none = np.zeros((0,), np.int32)
+    if n <= HOT_ROWS:
+        return none
+    by = A.indices[:, 0 if symmetric else 1]
+    if not (symmetric and A.indices_sorted):
+        by = _by_block_and_row(by, A.indices[:, 1], A.data)[0]
+    edge = jnp.searchsorted(by, jnp.arange(n + 1, dtype=jnp.int32), side="left")
+    count = np.diff(np.asarray(edge).astype(np.int64))
+    hot = np.argsort(-count, kind="stable")[:HOT_ROWS]
+    saved = count[hot].sum() * (COLD_NS - HOT_NS)
+    paid = m * (PLACE_NS + PIECE * HOT_NS)
+    return np.sort(hot).astype(np.int32) if saved >= HOT_MARGIN * paid else none
+
+
 def prepare(A, *, symmetric: bool = False) -> Prepared:
     """``A`` (a two-dimensional BCOO) in the product's layout.
     ``symmetric=True`` is the caller's word that ``A = Aᵀ`` (an
     undirected graph's adjacency): the one layout then serves both
     products; without it the prepared operand multiplies as ``A·Y``
     alone.  Costs a sort of the nonzeros (none for a BCOO sorted
-    by row with one block of columns), a search for every row's start and
+    by row with one table), a search for every row's start and
     two passes of scalar gathers over the nonzeros on the device, and the
-    bucketing of the row counts (``rows × blocks`` integers) on the host:
-    seconds at 10⁸ nonzeros, to be paid where the operand is made, never
-    inside a solve."""
+    bucketing of the row counts (``rows × tables`` integers) on the host;
+    with more than ``HOT_ROWS`` columns also their counts (a search; a
+    sort before it unless the matrix is symmetric and sorted by row) and,
+    where a hot table is built, one more scalar gather: seconds at 10⁸
+    nonzeros, to be paid where the operand is made, never inside a
+    solve."""
     if A.n_batch or A.n_dense or A.ndim != 2:
         raise ValueError(f"prepare takes a plain 2-D BCOO, got {A}")
     m, n = A.shape
+    if symmetric and m != n:
+        raise ValueError(f"a symmetric matrix is square, got {m}x{n}")
     nse = A.nse
     table = _table(n)
-    blocks = -(-n // table)
+    hot = _hot_columns(A, symmetric)
+    # a padding slot reads the row after the table's last
+    pads = [hot.size] * bool(hot.size) + [table] * -(-n // table)
+    blocks = len(pads)
     if blocks * m >= 2**31:
-        raise ValueError(f"{blocks} column blocks of {m} rows: past int32")
-    rows, cols = A.indices[:, 0], A.indices[:, 1]
-    live = (rows < m) & (cols < n)  # an index past the shape pads a BCOO
-    key = jnp.where(live, (cols // table) * m + rows, blocks * m)
-    col, val = cols % table, A.data
+        raise ValueError(f"{blocks} tables of {m} rows: past int32")
+    rank = None
+    if hot.size:
+        rank = np.full(n, -1, np.int32)
+        rank[hot] = np.arange(hot.size, dtype=np.int32)
+    key, col = _keys(A.indices[:, 0], A.indices[:, 1], rank, m, n, table, blocks)
+    val = A.data
     if blocks > 1 or not A.indices_sorted:
-        # (one block of a BCOO sorted by row is in the layout's order already)
+        # (one table of a BCOO sorted by row is in the layout's order already)
         key, col, val = _by_block_and_row(key, col, val)
     if not nse:  # nothing to point a slot at: one zero to read
         col, val = jnp.zeros((1,), col.dtype), jnp.zeros((1,), val.dtype)
@@ -219,13 +314,15 @@ def prepare(A, *, symmetric: bool = False) -> Prepared:
         base, left = np.concatenate(base), np.concatenate(left)
         cj, vj = _fill(jnp.asarray(base, jnp.int32),
                        jnp.asarray(np.clip(left, 0, PIECE), jnp.int32),
-                       col, val, jnp.int32(table))
+                       col, val, jnp.int32(pads[j]))
         out_cols.append(cj)
         out_vals.append(vj)
         out_place.append(jnp.asarray(place, jnp.int32))
         out_buckets.append(tuple(buckets))
     return Prepared(tuple(out_cols), tuple(out_vals), tuple(out_place),
-                    (m, n), int(nse), tuple(out_buckets), bool(symmetric))
+                    jnp.asarray(hot), (m, n), int(nse),
+                    int(deg[0].sum()) if hot.size else 0,
+                    tuple(out_buckets), bool(symmetric))
 
 
 def _prepared_product(A: Prepared, Y, acc):
@@ -233,13 +330,18 @@ def _prepared_product(A: Prepared, Y, acc):
     s = Y.shape[1]
     table = _table(n)
     step = _chunk(s, jnp.dtype(acc).itemsize) // PIECE
+    is_hot = bool(A.hot.shape[0])
     out = jnp.zeros((m, s), acc)
     for j, (cols, vals, place, buckets) in enumerate(
             zip(A.cols, A.vals, A.place, A.buckets)):
-        rows = min(table, n - j * table)
-        T = jnp.concatenate([
-            lax.slice_in_dim(Y, j * table, j * table + rows, axis=0),
-            jnp.zeros((table + 1 - rows, s), acc)])
+        # the table's rows of Y, then zero rows: the first is the padding
+        # slots' (the last column block may lack a few rows)
+        if is_hot and j == 0:
+            T, size = Y[A.hot], A.hot.shape[0]
+        else:
+            at = (j - is_hot) * table
+            T, size = lax.slice_in_dim(Y, at, min(at + table, n), axis=0), table
+        T = jnp.concatenate([T, jnp.zeros((size + 1 - T.shape[0], s), acc)])
         pieces = cols.shape[1]
 
         def fold(S, start, size, cols=cols, vals=vals, T=T):
@@ -250,12 +352,16 @@ def _prepared_product(A: Prepared, Y, acc):
             return lax.dynamic_update_slice_in_dim(S, part, start, axis=0)
 
         S = jnp.zeros((pieces, s), acc)
-        whole = pieces // step
-        if whole:
+        if pieces <= step:
+            S = fold(S, 0, pieces)
+        else:
+            # no remainder: the last step starts where a whole step still
+            # fits, and writes the pieces it shares with the one before
+            # again (the same bits: a piece's sum reads its own slots)
             S = lax.fori_loop(
-                0, whole, lambda i, S: fold(S, i * step, step), S)
-        if pieces % step:
-            S = fold(S, whole * step, pieces % step)
+                0, -(-pieces // step),
+                lambda i, S: fold(
+                    S, jnp.minimum(i * step, pieces - step), step), S)
         sums, at = [], 0
         for rows_b, k in buckets:
             sums.append(lax.slice_in_dim(S, at, at + rows_b * k, axis=0)

@@ -64,8 +64,9 @@ def approximate_ase(
     and not in every call, that BCOO prepared
     (``core.sparse.prepare(A, symmetric=True)``).  ``return_info=True``
     returns ``((X, lam), info)`` with ``approximate_symmetric_svd``'s
-    counts (``products``, ``iterations``, ``nnz``, ``edge_chunks``); the
-    streamed route has none to give.
+    counts (``products``, ``iterations``, ``nnz``, ``edge_chunks``, and
+    ``tables`` and ``hot_share`` where the prepared operand gathers from
+    a hot table); the streamed route has none to give.
     """
     params = params or ASEParams()
     if isinstance(G, SimpleGraph) and params.streamed:

@@ -387,7 +387,12 @@ def approximate_symmetric_svd(
     ``((V, lam), info)``: ``products`` (products with A the call ran:
     the sketch's, two a sweep, the Ritz step's), ``iterations``, ``nnz``
     and ``edge_chunks`` (steps a product walks a prepared operand's
-    nonzeros in; the entries of a dense A, and 0, as for a plain BCOO).
+    nonzeros in; the entries of a dense A, and 0, as for a plain BCOO);
+    for a prepared operand with a hot table also ``tables`` (the tables
+    its nonzeros gather from, the hot one among them) and ``hot_share``
+    (the share of the nonzeros that gather from the hot one).  Any other
+    operand's ``info`` is the four keys it was: ``A.tables`` and
+    ``A.hot_share`` (0.0) of a prepared operand say the same without.
     """
     params = params or SVDParams()
     sparse = _is_sparse(A)
@@ -414,12 +419,15 @@ def approximate_symmetric_svd(
             _ritz, A, Y, k=k, orthogonalize=not (niter and orthogonalize)
         )
     if return_info:
-        return out, {
+        info = {
             "products": 2 + 2 * niter,
             "iterations": niter,
             "nnz": A.nse if sparse else A.size,
             "edge_chunks": edge_chunks(A, s) if isinstance(A, Prepared) else 0,
         }
+        if isinstance(A, Prepared) and A.hot_nse:
+            info.update(tables=A.tables, hot_share=A.hot_share)
+        return out, info
     return out
 
 

@@ -14,6 +14,7 @@ import pytest
 from test_stage_spans import under_profiler  # the suite's one trace-and-read helper
 
 from libskylark_tpu import SketchContext
+from libskylark_tpu.core import sparse
 from libskylark_tpu.core.sparse import prepare
 from libskylark_tpu.graph import ASEParams, adjacency_from_edges, approximate_ase
 
@@ -25,9 +26,15 @@ STAGES = ["ase.sketch", "ase.power", "ase.ritz", "ase.embed"]
 def operand(form):
     rng = np.random.default_rng(21)
     u, v = rng.integers(0, 400, 6000), rng.integers(0, 400, 6000)
+    if form == "hot_table":  # 32 hubs, and fewer columns in the hot table than vertices
+        u, v = np.append(u, rng.integers(0, 32, 9000)), np.append(v, rng.integers(0, 400, 9000))
     A = adjacency_from_edges(u, v, 400)
-    return {"dense": A.todense, "bcoo": lambda: A,
-            "prepared": lambda: prepare(A, symmetric=True)}[form]()
+    with pytest.MonkeyPatch.context() as patch:
+        if form == "hot_table":
+            patch.setattr(sparse, "HOT_ROWS", 48)
+        return {"dense": A.todense, "bcoo": lambda: A,
+                "prepared": lambda: prepare(A, symmetric=True),
+                "hot_table": lambda: prepare(A, symmetric=True)}[form]()
 
 
 def embed(A, q):
@@ -35,7 +42,7 @@ def embed(A, q):
                            ASEParams(num_iterations=q, sparse=True), return_info=True)
 
 
-@pytest.mark.parametrize("form", ["dense", "prepared"])
+@pytest.mark.parametrize("form", ["dense", "prepared", "hot_table"])
 def test_the_four_stage_spans_open_once_a_call_in_order(form, tmp_path):
     A = operand(form)
     plain = embed(A, 2)
@@ -61,3 +68,10 @@ def test_info_counts_the_products_with_the_adjacency(q, products):
     (_, _), dense = embed(operand("dense"), q)
     assert dense == {"products": products, "iterations": q, "nnz": 400 * 400,
                      "edge_chunks": 0}
+    # an operand with a hot table says so; any other's info is what it was
+    assert set(info) == set(dense) and (A.tables, A.hot_share) == (1, 0.0)
+    H = operand("hot_table")
+    (_, _), hot = embed(H, q)
+    assert {k: hot[k] for k in dense} == {**info, "nnz": H.nse, "edge_chunks": 2}
+    assert hot["tables"] == H.tables == 2 and H.hot.shape == (48,)
+    assert hot["hot_share"] == H.hot_share == H.hot_nse / H.nse > 0.3

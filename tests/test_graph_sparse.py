@@ -15,6 +15,7 @@ import pytest
 from _builds import builds
 
 from libskylark_tpu import SketchContext
+from libskylark_tpu.core import sparse
 from libskylark_tpu.core.sparse import Prepared, prepare
 from libskylark_tpu.graph import (
     ASEParams, SimpleGraph, adjacency_from_edges, approximate_ase)
@@ -87,17 +88,31 @@ def planted():
         return np.asarray(u), np.asarray(v), PLANTED["vertices"]
 
 
+def prepared(A, hot_rows=None):
+    """``prepare(A, symmetric=True)``; with ``hot_rows``, as a graph of
+    more than ``HOT_ROWS`` vertices is prepared (the constant is read
+    where the layout is made; the product reads the layout)."""
+    with pytest.MonkeyPatch.context() as patch:
+        if hot_rows:
+            patch.setattr(sparse, "HOT_ROWS", hot_rows)
+        A = prepare(A, symmetric=True)
+    assert A.hot.shape == ((hot_rows,) if hot_rows else (0,))
+    return A
+
+
+@pytest.mark.parametrize("hot_rows", [None, 1024])
 @pytest.mark.parametrize("q", [0, 2])
-def test_the_sparse_route_agrees_with_the_entrys_plain_reference(planted, q):
+def test_the_sparse_route_agrees_with_the_entrys_plain_reference(planted, q, hot_rows):
     """The benchmark's own comparison at a small size: the same recurrence
     from the same Omega, the product and the orthonormalization each done
     another way.  Eight leading eigenvalues stand clear of the ninth, so
     f32 rounding (1e-7 a sum, amplified by |lambda_1| / gap, under 100) is
-    all that separates the two: 2e-5 leaves ten times of room."""
+    all that separates the two: 2e-5 leaves ten times of room.  With a
+    hot table a row's terms are summed in another grouping, no more."""
     u, v, n = planted
     k, s = 5, 10
     with jax.enable_x64(False):
-        A = prepare(adjacency_from_edges(u, v, n), symmetric=True)
+        A = prepared(adjacency_from_edges(u, v, n), hot_rows)
         (X, lam), info = approximate_ase(
             A, k, SketchContext(seed=17), ASEParams(num_iterations=q, sparse=True),
             return_info=True)
@@ -106,6 +121,12 @@ def test_the_sparse_route_agrees_with_the_entrys_plain_reference(planted, q):
             jnp.asarray(u), jnp.asarray(v), n, omega, k, q, 8192)
         errs = [float(e) for e in ENTRY.compare(ENTRY.pack(lam, X), ref, jnp.arange(0, n, 7))]
     assert info["products"] == 2 + 2 * q and info["nnz"] == A.nse
+    if hot_rows:  # a third of the 3,000 vertices holds six tenths of a rank law's nonzeros
+        assert info["tables"] == A.tables == 2 and len(info) == 6
+        assert info["hot_share"] == A.hot_share == A.hot_nse / A.nse and 0.5 < A.hot_share < 0.8
+    else:         # the four keys it had; the operand says the rest
+        assert set(info) == {"products", "iterations", "nnz", "edge_chunks"}
+        assert (A.tables, A.hot_share) == (1, 0.0)
     assert abs(ritz_values[k - 1]) > 1.5 * abs(ritz_values[k]) or q == 0
     assert max(errs) < 2e-5, errs
 
@@ -190,11 +211,14 @@ def test_where_the_ritz_program_orthonormalizes_the_last_bits_may_differ(params)
 # -- a warm call builds nothing -------------------------------------------------
 
 
-@pytest.mark.parametrize("form", ["dense", "prepared"])
+@pytest.mark.parametrize("form", ["dense", "prepared", "hot_table"])
 def test_a_warm_call_traces_and_lowers_nothing(planted, form):
     u, v, n = planted
-    A = adjacency_from_edges(u[:20000], v[:20000], n)
-    A = prepare(A, symmetric=True) if form == "prepared" else A.todense()
+    if form == "hot_table":  # (every arc: a hot table pays from a mean degree of 30 or so)
+        A = prepared(adjacency_from_edges(u, v, n), 1024)
+    else:
+        A = adjacency_from_edges(u[:20000], v[:20000], n)
+        A = prepared(A) if form == "prepared" else A.todense()
 
     def call(q):
         (X, lam), info = approximate_ase(

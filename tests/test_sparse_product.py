@@ -2,7 +2,9 @@
 against ``bcoo_dot_general``, for A·Y and Aᵀ·Y, a panel and a vector, an
 operand prepared from a BCOO in any order, chunk and table sizes that do
 and do not divide what they cut, rows and columns with no nonzero, rows
-far heavier than the rest; and what the layout of a prepared operand holds.
+far heavier than the rest; what the layout of a prepared operand holds;
+and the hot table: which operands get one, and that the others keep the
+layout they had.
 """
 
 import jax
@@ -38,12 +40,37 @@ def operand(seed, m, n, nnz, heavy=0, order="shuffled"):
     return A, dense
 
 
+def skewed(seed, n, draws, symmetric, order="shuffled", exponent=1.0):
+    """(BCOO, dense) of an n x n f32 matrix whose column counts follow a
+    rank law under random labels (both ends for ``symmetric``, which
+    gives A + Aᵀ; else the columns alone, the rows uniform)."""
+    rng = np.random.default_rng(seed)
+    w = (np.arange(n) + 1.0) ** -exponent
+    label = rng.permutation(n)
+    cols = label[rng.choice(n, draws, p=w / w.sum())]
+    rows = label[rng.choice(n, draws, p=w / w.sum())] if symmetric else rng.integers(0, n, draws)
+    dense = np.zeros((n, n), F32)
+    dense[rows, cols] = rng.normal(size=draws).astype(F32)
+    if symmetric:
+        dense = dense + dense.T
+    rows, cols = np.nonzero(dense)
+    at = rng.permutation(len(rows)) if order == "shuffled" else np.arange(len(rows))
+    idx = np.stack([rows[at], cols[at]], axis=1).astype(np.int32)
+    A = jsparse.BCOO((jnp.asarray(dense[rows[at], cols[at]]), jnp.asarray(idx)),
+                     shape=(n, n), indices_sorted=order == "sorted", unique_indices=True)
+    return A, dense
+
+
 @pytest.fixture
 def sizes(monkeypatch):
-    """Set the module's chunk and table sizes for one test."""
-    def set_(chunk_bytes, table_rows):
+    """Set the module's chunk and table sizes for one test (no hot table
+    unless its rows are given: every operand here has fewer columns than
+    the module's own ``HOT_ROWS``)."""
+    def set_(chunk_bytes, table_rows, hot_rows=None):
         monkeypatch.setattr(sp, "CHUNK_BYTES", chunk_bytes)
         monkeypatch.setattr(sp, "TABLE_ROWS", table_rows)
+        if hot_rows is not None:
+            monkeypatch.setattr(sp, "HOT_ROWS", hot_rows)
         sp.spmm.clear_cache()  # the sizes are read when a product is traced
     yield set_
     sp.spmm.clear_cache()
@@ -127,30 +154,213 @@ def test_padding_indices_of_a_bcoo_read_zero_and_add_nowhere(sizes):
     np.testing.assert_allclose(sp.spmm(sp.prepare(A), Y), dense @ Y, rtol=2e-5, atol=2e-5)
 
 
-def test_the_layout_holds_every_nonzero_once_in_buckets_of_equal_count(sizes):
-    sizes(1 << 12, 64)
-    A, dense = operand(9, 300, 200, 6000, 1500)
-    op = sp.prepare(A)
-    assert len(op.cols) == len(op.buckets) == -(-200 // 64)
-    table = sp._table(200)                                  # four equal blocks
-    assert table == 50
-    live = 0
+def nonzeros_of(op):
+    """(row, column, value) of every live slot of a prepared operand, read
+    back through the layout: a table's pieces lie bucket after bucket,
+    piece-major, ``place`` says where a row's sums come from, and a local
+    column is a row of ``hot`` or of the table's slice of the columns."""
+    m, n = op.shape
+    table, hot = sp._table(n), np.asarray(op.hot)
+    found = []
     for j, (cols, vals, place, buckets) in enumerate(
             zip(op.cols, op.vals, op.place, op.buckets)):
         cols, vals = np.asarray(cols), np.asarray(vals)
+        is_hot = bool(hot.size) and j == 0
+        rows_t = hot.size if is_hot else table
+        bucketed = np.concatenate([np.tile(lo + np.arange(r), k) for (r, k), lo in zip(
+            buckets, np.cumsum([0] + [r for r, _ in buckets[:-1]]))])
+        row_at = np.argsort(np.asarray(place))              # bucketed position -> row
+        pad = cols == rows_t
+        assert (vals[pad] == 0).all() and (cols[~pad] < rows_t).all()
+        w, piece = np.nonzero(~pad)
+        local = cols[w, piece]
+        col = hot[local] if is_hot else (j - bool(hot.size)) * table + local
+        found.append((row_at[bucketed[piece]], col, vals[w, piece]))
+    return [np.concatenate(part) for part in zip(*found)]
+
+
+LAYOUTS = {  # the operand, HOT_ROWS, tables
+    "column_blocks_alone": (lambda: operand(9, 300, 200, 6000, 1500), None, 4),
+    "hot_table_symmetric": (lambda: skewed(31, 200, 60000, True), 24, 5),
+    "hot_table_of_a_rectangle_s_columns": (lambda: skewed(32, 200, 60000, False), 24, 5),
+}
+
+
+@pytest.mark.parametrize("case", LAYOUTS)
+def test_the_layout_holds_every_nonzero_once_in_buckets_of_equal_count(case, sizes):
+    make, hot_rows, tables = LAYOUTS[case]
+    sizes(1 << 12, 64, hot_rows)
+    A, dense = make()
+    m, n = dense.shape
+    op = sp.prepare(A, symmetric=case == "hot_table_symmetric")
+    assert op.tables == len(op.cols) == len(op.buckets) == tables
+    table = sp._table(200)                                  # four equal blocks
+    assert table == 50
+    assert op.hot.shape == ((hot_rows,) if hot_rows else (0,))
+    for cols, vals, place, buckets in zip(op.cols, op.vals, op.place, op.buckets):
         assert cols.shape == vals.shape == (sp.PIECE, sum(r * k for r, k in buckets))
-        assert sum(r for r, _ in buckets) == 300            # every row in one bucket
+        assert sum(r for r, _ in buckets) == m              # every row in one bucket
         assert [k for _, k in buckets] == sorted({k for _, k in buckets})
         assert set(k for _, k in buckets) <= set(sp._counts(1 << 20))
-        assert sorted(np.asarray(place)) == list(range(300))
-        pad = cols == table
-        assert (vals[pad] == 0).all() and (cols[~pad] < table).all()
-        live += int((~pad).sum())
-        # a block's values are its columns' of the dense matrix
-        assert np.isclose(vals.sum(), dense[:, j * table:(j + 1) * table].sum(), atol=1e-3)
-    assert live == A.nse
+        assert sorted(np.asarray(place)) == list(range(m))
+    # every nonzero of the matrix in exactly one slot of one table
+    r, c, v = nonzeros_of(op)
+    assert r.size == A.nse == int((dense != 0).sum())
+    assert np.unique(r * n + c).size == r.size
+    np.testing.assert_array_equal(v, dense[r, c])
     slots = sum(c.size for c in op.cols)
-    assert slots <= 1.25 * A.nse + sp.PIECE * 300 * len(op.cols)
+    assert slots <= 1.25 * A.nse + sp.PIECE * m * len(op.cols)
+    if hot_rows:
+        hot = np.asarray(op.hot)
+        count = (dense != 0).sum(0)                         # the columns' own counts
+        assert (np.diff(hot) > 0).all()
+        assert count[hot].min() >= np.delete(count, hot).max()
+        assert op.hot_nse == int(count[hot].sum()) == int((np.isin(c, hot)).sum())
+        assert op.hot_share == op.hot_nse / A.nse > 0.2
+    else:
+        assert op.hot_nse == 0 and op.hot_share == 0.0
+
+
+HOT = {  # n, draws, symmetric, order, chunk bytes (of 5 f32 columns), table rows, hot rows
+    "symmetric_sorted": (200, 60000, True, "sorted", 1 << 12, 64, 24),
+    "symmetric_shuffled": (200, 60000, True, "shuffled", 1 << 12, 64, 24),
+    "columns_counted_not_rows": (200, 60000, False, "shuffled", 1 << 12, 64, 24),
+    "columns_counted_sorted_by_row": (200, 60000, False, "sorted", 1 << 12, 64, 24),
+    "one_cold_table_one_step": (300, 60000, True, "sorted", 1 << 26, 3 << 19, 40),
+    "hot_rows_no_power_of_two": (257, 40000, True, "shuffled", 1 << 11, 100, 37),
+}
+
+
+@pytest.mark.parametrize("seed", [41, 42])
+@pytest.mark.parametrize("case", HOT)
+def test_products_through_a_hot_table_match_the_dense_matrix(case, seed, sizes):
+    n, draws, symmetric, order, chunk_bytes, table, hot_rows = HOT[case]
+    sizes(chunk_bytes, table, hot_rows)
+    A, dense = skewed(seed, n, draws, symmetric, order)
+    op = sp.prepare(A, symmetric=symmetric)
+    assert op.hot.shape == (hot_rows,) and op.tables == 1 + -(-n // table)
+    assert 0.2 < op.hot_share < 1
+    Y = np.random.default_rng(seed + 1).normal(size=(n, 5)).astype(F32)
+    want = dense.astype(np.float64) @ Y
+    bound = 4e-7 * (np.abs(dense).astype(np.float64) @ np.abs(Y)).max() + 1e-7
+    got = sp.spmm(op, Y)
+    assert got.dtype == jnp.float32 and np.abs(np.asarray(got) - want).max() <= 16 * bound
+    y = sp.spmm(op, Y[:, 0])                                 # a vector
+    np.testing.assert_array_equal(y, sp.spmm(op, Y[:, :1])[:, 0])
+    assert np.abs(np.asarray(y) - want[:, 0]).max() <= 16 * bound
+    if symmetric:                                            # Aᵀ·Y from the one layout
+        np.testing.assert_array_equal(sp.spmm(op, Y, transpose=True), got)
+    else:                                                    # Aᵀ's own: its columns are A's rows, flat
+        assert np.abs(np.asarray(sp.spmm(sp.prepare(A.T), Y)) - dense.T.astype(np.float64) @ Y
+                      ).max() <= 16 * bound
+    np.testing.assert_allclose(jax.jit(sp.spmm)(op, Y), got, rtol=2e-5, atol=2e-5)
+
+
+def layout_crc(op):
+    import zlib
+
+    crc = 0
+    for a in (*op.cols, *op.vals, *op.place):
+        crc = zlib.crc32(np.ascontiguousarray(np.asarray(a)).tobytes(), crc)
+    return crc
+
+
+KEPT = {  # the operand, symmetric, HOT_ROWS, the crc32 of the layout at commit 92917cb
+    "random_columns_past_hot_rows": (lambda: operand(9, 300, 200, 6000, 1500), False, 24, 1551483926),
+    "flat_symmetric_past_hot_rows": (lambda: skewed(33, 200, 5000, True, exponent=0.0), True, 24, 2481639418),
+    "skewed_but_within_hot_rows": (lambda: skewed(31, 200, 60000, True), True, 200, None),
+    "skewed_with_the_modules_own_hot_rows": (lambda: skewed(31, 200, 60000, True), True, None, None),
+}
+
+
+@pytest.mark.parametrize("case", KEPT)
+def test_an_operand_the_hot_table_does_not_pay_for_keeps_the_layout_it_had(case, sizes):
+    """Flat column counts leave the hot columns ``HOT_ROWS / n`` of the
+    nonzeros, too few to pay for a table; ``n <= HOT_ROWS`` is one fast
+    table as it is.  Both get the layout of the commit before the hot
+    table, to the bit (its crc32 was taken there)."""
+    make, symmetric, hot_rows, crc = KEPT[case]
+    A, dense = make()
+    sizes(1 << 12, 64)                                       # HOT_ROWS past every operand here
+    had = sp.prepare(A, symmetric=symmetric)
+    sizes(1 << 12, 64, hot_rows)
+    op = sp.prepare(A, symmetric=symmetric)
+    assert op.hot.shape == (0,) and op.hot_nse == 0 and op.hot_share == 0.0
+    assert op.tables == had.tables == 4 and op.buckets == had.buckets
+    for a, b in zip((*op.cols, *op.vals, *op.place), (*had.cols, *had.vals, *had.place)):
+        np.testing.assert_array_equal(a, b)
+    if crc is not None:
+        assert layout_crc(op) == crc
+    else:                                                    # and skewed enough to get one when it pays
+        sizes(1 << 12, 64, 24)
+        assert sp.prepare(A, symmetric=symmetric).hot.shape == (24,)
+
+
+def test_the_hot_table_is_weighed_by_what_its_nonzeros_save(sizes):
+    """(ii) of the rule: nonzeros in the hot columns x (COLD_NS - HOT_NS)
+    against HOT_MARGIN x rows x (PLACE_NS + PIECE x HOT_NS)."""
+    sizes(1 << 12, 64, 24)
+    A, dense = skewed(35, 200, 60000, True)
+    count = np.sort((dense != 0).sum(0))[::-1]
+    saved = count[:24].sum() * (sp.COLD_NS - sp.HOT_NS)
+    paid = 200 * (sp.PLACE_NS + sp.PIECE * sp.HOT_NS)
+    assert saved > sp.HOT_MARGIN * paid                     # so it engages ...
+    assert sp.prepare(A, symmetric=True).hot.shape == (24,)
+    few = jsparse.BCOO.fromdense(jnp.asarray(np.where(                    # ... and with a tenth of the
+        np.random.default_rng(1).random(dense.shape) < 0.1, dense, 0)))   # nonzeros it does not
+    count = np.sort((np.asarray(few.todense()) != 0).sum(0))[::-1]
+    assert count[:24].sum() * (sp.COLD_NS - sp.HOT_NS) < sp.HOT_MARGIN * paid
+    assert sp.prepare(few).hot.shape == (0,)
+    assert (sp.HOT_ROWS, sp.TABLE_ROWS) == (24, 64)
+    with pytest.raises(ValueError, match="square"):
+        sp.prepare(jsparse.BCOO.fromdense(jnp.ones((3, 4))), symmetric=True)
+
+
+def test_the_clamped_last_step_gives_the_bits_of_steps_that_divide_the_pieces(sizes):
+    """100 rows of 16 nonzeros: 200 pieces in one table.  Steps of 8
+    pieces divide them; steps of 16, 32 and 64 do not, so the last starts
+    where a whole step still fits and sums the pieces it shares with the
+    step before again: the same bits, a piece's sum reads its own slots.
+    (One fold over all pieces, outside any loop, is another program:
+    XLA:CPU contracts its multiply-adds differently, so that one is
+    compared to rounding.)"""
+    rng = np.random.default_rng(38)
+    dense = np.zeros((100, 40), F32)
+    for r in range(100):
+        dense[r, rng.choice(40, 16, replace=False)] = rng.normal(size=16)
+    A = jsparse.BCOO.fromdense(jnp.asarray(dense))
+    Y = rng.normal(size=(40, 5)).astype(F32)
+    sizes(1 << 11, 64)
+    op = sp.prepare(A)
+    assert [c.shape for c in op.cols] == [(sp.PIECE, 200)] and op.buckets == (((100, 2),),)
+    assert sp._chunk(5, 4) // sp.PIECE == 8 and sp.edge_chunks(op, 5) == 25
+    divided = np.asarray(sp.spmm(op, Y))
+    for chunk_bytes, steps in ((1 << 12, 13), (1 << 13, 7), (1 << 14, 4)):
+        sizes(chunk_bytes, 64)
+        assert sp.edge_chunks(op, 5) == steps and 200 % (sp._chunk(5, 4) // sp.PIECE)
+        np.testing.assert_array_equal(np.asarray(sp.spmm(op, Y)), divided)
+    sizes(1 << 26, 64)
+    assert sp.edge_chunks(op, 5) == 1                        # fewer pieces than a step: one fold
+    np.testing.assert_allclose(sp.spmm(op, Y), divided, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("hot_rows", [None, 24])
+def test_every_step_size_gives_the_same_bits_table_by_table(hot_rows, sizes):
+    """Hardly a table's pieces are a multiple of a step: the last step of
+    almost every table is clamped, the hot one's too."""
+    A, dense = skewed(36, 200, 60000, True)
+    Y = np.random.default_rng(37).normal(size=(200, 5)).astype(F32)
+    sizes(1 << 11, 64, hot_rows)
+    op = sp.prepare(A, symmetric=True)
+    assert op.tables == (5 if hot_rows else 4)
+    first = np.asarray(sp.spmm(op, Y))
+    for chunk_bytes in (1 << 12, 3000, 1 << 13):             # 16, 16, 32 pieces a step
+        sizes(chunk_bytes, 64, hot_rows)
+        step = sp._chunk(5, 4) // sp.PIECE
+        assert all(c.shape[1] > step for c in op.cols)
+        assert sum(c.shape[1] % step > 0 for c in op.cols) >= op.tables - 1
+        assert sp.edge_chunks(op, 5) == sum(-(-c.shape[1] // step) for c in op.cols)
+        np.testing.assert_array_equal(np.asarray(sp.spmm(op, Y)), first)
 
 
 def test_piece_counts_are_four_to_the_octave():

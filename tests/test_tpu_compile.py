@@ -430,11 +430,13 @@ def test_streaming_krr_programs_carry_their_scopes_and_the_parents_instructions(
 # -- the sparse-times-panel product: no nnz x s buffer ------------------------
 
 
-def _prepared_shapes(n, nnz, one_chip):
+def _prepared_shapes(n, nnz, one_chip, hot_share=0.0, pad=1.1):
     """A ``core.sparse.Prepared`` of abstract arrays at the size of an
     ``n``-vertex graph with ``nnz`` nonzeros: the column blocks and
-    bucket counts ``prepare`` gives a graph of that size (a tenth more
-    slots than nonzeros; rows spread over a dozen piece counts)."""
+    bucket counts ``prepare`` gives a graph of that size (``pad`` slots
+    a nonzero; rows spread over a dozen piece counts), with
+    ``hot_share`` of the slots in a hot table before them where that is
+    given."""
     from libskylark_tpu.core import sparse
 
     def shaped(shape, dtype):
@@ -444,51 +446,95 @@ def _prepared_shapes(n, nnz, one_chip):
     counts = [c for c in sparse._counts(4096) if c >= 2][:24]
     rows = [n // len(counts)] * len(counts)
     rows[0] += n - sum(rows)
-    want = 1.1 * nnz / blocks / sparse.PIECE            # pieces a block
-    scale = want / sum(r * k for r, k in zip(rows, counts))
-    buckets = tuple((r, max(int(round(k * scale)), 1)) for r, k in zip(rows, counts))
-    pieces = sum(r * k for r, k in buckets)
+
+    def buckets_of(nonzeros):
+        want = pad * nonzeros / sparse.PIECE                # pieces a table
+        scale = want / sum(r * k for r, k in zip(rows, counts))
+        return tuple((r, max(int(round(k * scale)), 1)) for r, k in zip(rows, counts))
+
+    hot = sparse.HOT_ROWS if hot_share else 0
+    buckets = ((buckets_of(hot_share * nnz),) if hot else ()) + (
+        buckets_of((1 - hot_share) * nnz / blocks),) * blocks
+    pieces = [sum(r * k for r, k in b) for b in buckets]
     return sparse.Prepared(
-        cols=(shaped((sparse.PIECE, pieces), I32),) * blocks,
-        vals=(shaped((sparse.PIECE, pieces), F32),) * blocks,
-        place=(shaped((n,), I32),) * blocks,
-        shape=(n, n), nse=nnz, buckets=(buckets,) * blocks, symmetric=True)
+        cols=tuple(shaped((sparse.PIECE, p), I32) for p in pieces),
+        vals=tuple(shaped((sparse.PIECE, p), F32) for p in pieces),
+        place=(shaped((n,), I32),) * len(pieces), hot=shaped((hot,), I32),
+        shape=(n, n), nse=nnz, hot_nse=int(hot_share * nnz), buckets=buckets,
+        symmetric=True)
+
+
+# the cell's layout on the chip (PERF.md section 6, PR 38): 63.2, 50.2 and
+# 50.3 M slots for 140.67 M nonzeros, 0.392 of them in the hot table
+CELL_LAYOUT = dict(hot_share=0.386, pad=1.164)
+
+
+def _cell_sizes():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs", "graph_se_orkut_f32.json")) as f:
+        z = json.load(f)
+    return z["vertices"], 2 * z["edges"], z["s"]
+
+
+def _sweep_segment(A, one_chip):
+    """``linalg.svd._chunk`` lowered for two sweeps with ``A`` at s = 16."""
+    from libskylark_tpu.linalg import svd
+
+    Y = jax.ShapeDtypeStruct((A.shape[0], 16), F32, sharding=one_chip)
+    st = dict(it=jax.ShapeDtypeStruct((), I32, sharding=one_chip), Y=Y)
+    with jax.enable_x64(False):
+        return svd._chunk.lower(st, A, 2, 2, orthogonalize=True)
 
 
 def test_sparse_product_holds_a_chunk_of_gathered_rows_and_no_more(one_chip):
     """The sweep segment of ``approximate_ase`` at the benchmark cell's
     size (``graph_se_orkut_f32``: its vertices, twice its edges, s = 16,
-    the columns in two tables): ``bcoo_dot_general`` would hold every
+    the columns in a hot table of 131,072 rows and two of 921,733, with
+    63 M, 50 M and 50 M slots): ``bcoo_dot_general`` would hold every
     nonzero's row of the panel at once (nnz x s x 4 bytes, gigabytes);
-    the chunked product holds a chunk's gathered rows, one block's
+    the chunked product holds a chunk's gathered rows, a table's
     pieces' sums (an s-row for every eight slots: what still grows with
-    nnz, a seventh of it a block) and the panels."""
-    import json
-
+    nnz; the hot table's are the largest, a fifth under a column block's
+    before there was a hot table) and the panels."""
     from libskylark_tpu.core import sparse
-    from libskylark_tpu.linalg import svd
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "configs", "graph_se_orkut_f32.json")) as f:
-        z = json.load(f)
-    n, nnz, s = z["vertices"], 2 * z["edges"], z["s"]
-    A = _prepared_shapes(n, nnz, one_chip)
-    Y = jax.ShapeDtypeStruct((n, s), F32, sharding=one_chip)
-    st = dict(it=jax.ShapeDtypeStruct((), I32, sharding=one_chip), Y=Y)
-    with jax.enable_x64(False):
-        compiled = svd._chunk.lower(st, A, 2, 2, orthogonalize=True).compile()
+    n, nnz, s = _cell_sizes()
+    A = _prepared_shapes(n, nnz, one_chip, **CELL_LAYOUT)
+    assert A.tables == 3 and A.hot.shape == (sparse.HOT_ROWS,) == (131072,)
+    assert sparse._table(n) == 921733 and s == 16
+    slots = [c.shape[1] * sparse.PIECE for c in A.cols]
+    assert all(abs(x / on_chip - 1) < 0.05 for x, on_chip in zip(slots, (63.2e6, 50.2e6, 50.3e6)))
+    compiled = _sweep_segment(A, one_chip).compile()
     mem = compiled.memory_analysis()
     panel = n * s * 4
-    pieces = max(c.shape[1] for c in A.cols) * s * 4    # a block's pieces' sums
-    chunk = sparse.CHUNK_BYTES                           # a step's gathered rows
-    everything = nnz * s * 4                             # what this PR is for
-    assert len(A.cols) == 2
-    # 1.05 GB here with even buckets; 1.46 GB on the chip with the cell's own (PERF.md section 5)
+    pieces = max(slots) // sparse.PIECE * s * 4              # a table's pieces' sums
+    before = 1.1 * nnz / 2 / sparse.PIECE * s * 4            # a column block's, two tables
+    chunk = sparse.CHUNK_BYTES                               # a step's gathered rows
+    everything = nnz * s * 4                                 # what the product is for
+    assert pieces < 0.85 * before
     assert mem.temp_size_in_bytes < pieces + 10 * chunk + 4 * panel < everything / 3
-    assert mem.argument_size_in_bytes < 1.2 * nnz * 8 + n * 4 * len(A.cols) + 2 * panel
+    assert mem.argument_size_in_bytes < 1.2 * nnz * 8 + n * 4 * A.tables + 2 * panel
     text = compiled.as_text()
     assert "sparse.product" in text and "svd.gram_orth" in text
-    slots = max(c.shape[1] for c in A.cols) * sparse.PIECE
-    for big in (nnz, slots):                             # no array of a row a nonzero
+    for big in (nnz, *slots):                                # no array of a row a nonzero
         assert not re.search(rf"f32\[{big},{s}\]", text)
         assert not re.search(rf"f32\[{s},{big}\]", text)
+
+
+def test_three_tables_lower_to_fewer_gathers_than_two_did(one_chip):
+    """What the TPU compiler's time for the three ASE programs grows
+    with, counted where a CPU can: the gathers of the lowered sweep
+    segment at the cell's size.  Before the hot table every table's
+    eight gathers a step stood twice, in the loop and for the pieces
+    left over, and the rows' placement once: 2 x 17 a product, four
+    products in the segment (commit 92917cb).  Now a table's stand once
+    (the last step is clamped), and the hot table's rows are one more."""
+    n, nnz, s = _cell_sizes()
+
+    def gathers(A):
+        return len(re.findall(r'"stablehlo\.gather"\(', _sweep_segment(A, one_chip).as_text()))
+
+    assert gathers(_prepared_shapes(n, nnz, one_chip, **CELL_LAYOUT)) == 2 * (3 * 9 + 1) < 2 * 2 * 17
+    assert gathers(_prepared_shapes(n, nnz, one_chip)) == 2 * 2 * 9
