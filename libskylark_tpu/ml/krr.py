@@ -557,6 +557,11 @@ def streaming_kernel_ridge(
     default arguments or reads data from its module's namespace gets
     programs of the call's own, built again every call.
 
+    ``model.info``: ``feature_passes``, the panel passes over X the
+    call launched (a chunk's ``gram`` in sweep 0, its ``zr`` and
+    ``apply_delta`` every sweep: 1 + 2·sweeps for one chunk), and
+    ``feature_map``, the maps' ``sketch_type``.
+
     ``timer``: optional ``utils.PhaseTimer`` — sweep 0 (which absorbs
     the per-chunk program compiles and factorizations) lands in phase
     ``"sweep0"``, steady sweeps in ``"sweep"`` (the ADMM solver's
@@ -625,6 +630,7 @@ def streaming_kernel_ridge(
 
         # Without a caller's timer the phases only annotate the trace.
         timer = timer if timer is not None else PhaseTimer(sync=False)
+        passes = 0  # panel passes over X launched: one a chunk program
 
         # Sweep 0 is unconditional (factors must exist), matching
         # large_scale_kernel_ridge's loop structure where the first sweep
@@ -633,6 +639,7 @@ def streaming_kernel_ridge(
             with timer.phase("sweep0" if it == 0 else "sweep") as ph:
                 delsize = 0.0
                 for c, (gram, zr, apply_delta) in enumerate(programs):
+                    passes += 2 if it else 3
                     if it == 0:
                         with telemetry.span("krr.gram"):
                             G = gram(lam_, *block_args)
@@ -657,7 +664,10 @@ def streaming_kernel_ridge(
                 break
 
         W = jnp.concatenate(Ws, axis=0)
-        return FeatureMapModel(maps, W)
+        model = FeatureMapModel(maps, W)
+        model.info = {"feature_passes": passes,
+                      "feature_map": maps[0].sketch_type}
+        return model
 
 
 @dataclass(frozen=True)

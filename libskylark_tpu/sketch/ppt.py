@@ -112,24 +112,84 @@ class PPT(SketchTransform):
             and self.q <= _DFT_MAX_Q
         )
 
-    def _features(self, X):
+    # -- loop-invariant operands ---------------------------------------------
+
+    def hoistable_operands(self, dtype):
+        """What every apply realizes that does not depend on the input:
+        each level's CountSketch operands (``HashSketch.
+        hoistable_operands``), the constant's hashed coordinates and
+        signs, and on the bf16 DFT route the (cos, sin) tables, made
+        once a program and held as buffers — outside a streaming
+        consumer's panel loop, and never fused back into its transforms.
+        Memoized per dtype and route (``_memoized_operand``: skipped
+        mid-trace).  The route is ``_dft_wins`` at the smallest batch
+        that gate admits; :meth:`apply_with_operands` re-decides it by
+        the real batch, as :meth:`apply` does, and ignores or builds the
+        tables to match."""
+        dt = jnp.dtype(dtype)
+        if dt.type not in (jnp.bfloat16, jnp.float32):
+            return None
+        dft = self._dft_wins(dt, _DFT_MIN_BATCH)
+
+        def build():
+            with jax.named_scope("ppt.hash"):
+                cwt_ops = tuple(c.hoistable_operands(dt) for c in self._cwts)
+                consts = self._hash_consts(jnp.float32)
+            tables = None
+            if dft:
+                # Behind the barrier the tables are buffers: without it the
+                # TPU compiler fuses their cos and sin into every
+                # transform's convolution, where they are made tile by tile.
+                tables = jax.lax.optimization_barrier(self._dft_tables())
+            return cwt_ops, consts, tables
+
+        return self._memoized_operand(f"{dt.name}/{dft}", build)
+
+    def apply_with_operands(
+        self, ops, A, dim: Dimension | str = Dimension.COLUMNWISE
+    ):
+        """:meth:`apply` with the :meth:`hoistable_operands` ``ops``
+        (None: built here): the same route by the same gate, the same
+        bits."""
+        return self._apply(A, dim, ops)
+
+    def _operands(self, ops, dft: bool):
+        """(CWT operands, (idx, val), tables) from ``ops``, what is
+        missing built here; ``val`` is f32 (±1: exact in every dtype)."""
+        cwt_ops, consts, tables = ops or ((None,) * self.q, None, None)
+        if consts is None:
+            with jax.named_scope("ppt.hash"):
+                consts = self._hash_consts(jnp.float32)
+        if dft and tables is None:
+            tables = self._dft_tables()
+        return cwt_ops, consts, tables
+
+    def _features(self, X, ops=None):
         """Columnwise features for X (n, m) → (S, m) real."""
         dtype = X.dtype
         if self._dft_wins(dtype, X.shape[1]):
-            return self._features_dft(X)
+            return self._features_dft(X, ops=ops)
+        cwt_ops, (idx, val), _ = self._operands(ops, False)
         sqrt_g = jnp.asarray(np.sqrt(self.gamma), dtype)
         sqrt_c = jnp.asarray(np.sqrt(self.c), dtype)
-        idx, val = self._hash_consts(dtype)
         # Seed the frequency-domain product with level 0 (one multiply —
         # and one eager complex-ones allocation — fewer than starting
         # from ones).
         P = None
         for l, cwt in enumerate(self._cwts):
-            W = sqrt_g * cwt.apply(X, Dimension.COLUMNWISE)
-            W = W.at[idx[l], :].add(sqrt_c * val[l])
-            F = jnp.fft.fft(W, axis=0)
-            P = F if P is None else P * F
-        return jnp.real(jnp.fft.ifft(P, axis=0)).astype(dtype)
+            with jax.named_scope("ppt.hash"):
+                W = sqrt_g * cwt.apply_with_operands(
+                    cwt_ops[l], X, Dimension.COLUMNWISE)
+                W = W.at[idx[l], :].add(sqrt_c * val[l].astype(dtype))
+            with jax.named_scope("ppt.dft"):
+                F = jnp.fft.fft(W, axis=0)
+            if P is None:
+                P = F
+            else:
+                with jax.named_scope("ppt.product"):
+                    P = P * F
+        with jax.named_scope("ppt.inverse"):
+            return jnp.real(jnp.fft.ifft(P, axis=0)).astype(dtype)
 
     # -- bf16 matmul-DFT fast path (TPU) -----------------------------------
 
@@ -137,15 +197,16 @@ class PPT(SketchTransform):
         """(cos, sin) (S, S) DFT tables in bf16, built in-graph.  The
         index product j·k stays below 2^24 for S ≤ 2^12 (int32-exact,
         reduced mod S before the float conversion)."""
-        j = jnp.arange(self.s, dtype=jnp.int32)
-        jk = (j[:, None] * j[None, :]) % jnp.int32(self.s)
-        theta = jnp.float32(2.0 * np.pi / self.s) * jk.astype(jnp.float32)
-        return (
-            jnp.cos(theta).astype(jnp.bfloat16),
-            jnp.sin(theta).astype(jnp.bfloat16),
-        )
+        with jax.named_scope("ppt.dft"):
+            j = jnp.arange(self.s, dtype=jnp.int32)
+            jk = (j[:, None] * j[None, :]) % jnp.int32(self.s)
+            theta = jnp.float32(2.0 * np.pi / self.s) * jk.astype(jnp.float32)
+            return (
+                jnp.cos(theta).astype(jnp.bfloat16),
+                jnp.sin(theta).astype(jnp.bfloat16),
+            )
 
-    def _features_dft(self, X, rowwise: bool = False):
+    def _features_dft(self, X, rowwise: bool = False, ops=None):
         """bf16 features via explicit real-arithmetic DFT matmuls: each
         level's S-point transform is a (cos, sin) MXU matmul pair, the
         level products run as (Re, Im) f32 pairs, and the inverse
@@ -156,10 +217,9 @@ class PPT(SketchTransform):
         the minor axis) so rowwise applies skip two full-batch
         transposes — the DFT tables are symmetric, so the same (cos,
         sin) pair serves both orientations."""
-        C, Sn = self._dft_tables()
+        cwt_ops, (idx, val), (C, Sn) = self._operands(ops, True)
         sqrt_g = jnp.asarray(np.sqrt(self.gamma), jnp.bfloat16)
         sqrt_c = jnp.asarray(np.sqrt(self.c), jnp.float32)
-        idx, val = self._hash_consts(jnp.float32)
         dim = Dimension.ROWWISE if rowwise else Dimension.COLUMNWISE
 
         def mm(W, M):
@@ -177,18 +237,26 @@ class PPT(SketchTransform):
 
         Pr = Pi = None
         for l, cwt in enumerate(self._cwts):
-            W = sqrt_g * cwt.apply(X, dim)  # (m, S) rowwise / (S, m) col.
-            Wb = add_const(W, l).astype(jnp.bfloat16)
-            Re, Im = mm(Wb, C), -mm(Wb, Sn)
+            with jax.named_scope("ppt.hash"):
+                # (m, S) rowwise / (S, m) columnwise
+                W = sqrt_g * cwt.apply_with_operands(cwt_ops[l], X, dim)
+                Wb = add_const(W, l).astype(jnp.bfloat16)
+            with jax.named_scope("ppt.dft"):
+                Re, Im = mm(Wb, C), -mm(Wb, Sn)
             if Pr is None:
                 Pr, Pi = Re, Im
             else:
-                Pr, Pi = Pr * Re - Pi * Im, Pr * Im + Pi * Re
+                with jax.named_scope("ppt.product"):
+                    Pr, Pi = Pr * Re - Pi * Im, Pr * Im + Pi * Re
         # ifft real part: (1/S)·(C@Pr − Sn@Pi)  (e^{+iθ} = C + i·Sn).
-        Z = mm(Pr.astype(jnp.bfloat16), C) - mm(Pi.astype(jnp.bfloat16), Sn)
-        return (Z * jnp.float32(1.0 / self.s)).astype(jnp.bfloat16)
+        with jax.named_scope("ppt.inverse"):
+            Z = mm(Pr.astype(jnp.bfloat16), C) - mm(Pi.astype(jnp.bfloat16), Sn)
+            return (Z * jnp.float32(1.0 / self.s)).astype(jnp.bfloat16)
 
     def apply(self, A, dim: Dimension | str = Dimension.COLUMNWISE):
+        return self._apply(A, dim, None)
+
+    def _apply(self, A, dim, ops):
         dim = Dimension.of(dim)
         A = jnp.asarray(A)
         dtype = A.dtype if jnp.issubdtype(A.dtype, jnp.floating) else jnp.float32
@@ -198,14 +266,15 @@ class PPT(SketchTransform):
             X = A[:, None] if squeeze else A
             if X.shape[0] != self.n:
                 raise ValueError(f"columnwise apply needs {self.n} rows, got {A.shape}")
-            Z = self._features(X)
+            Z = self._features(X, ops)
             return Z[:, 0] if squeeze else Z
         X = A[None, :] if squeeze else A
         if X.shape[-1] != self.n:
             raise ValueError(f"rowwise apply needs {self.n} cols, got {A.shape}")
         if not squeeze and self._dft_wins(dtype, X.shape[0]):
-            return self._features_dft(X, rowwise=True)
-        return self._features(X.T).T if not squeeze else self._features(X.T)[:, 0]
+            return self._features_dft(X, rowwise=True, ops=ops)
+        Z = self._features(X.T, ops)
+        return Z.T if not squeeze else Z[:, 0]
 
     def _param_dict(self):
         return {"q": self.q, "c": self.c, "gamma": self.gamma}

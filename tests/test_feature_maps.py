@@ -499,6 +499,21 @@ class TestChunkProgramEpilogue:
         assert "stablehlo.convert" in body.splitlines()[-2]
         assert "return" in body.splitlines()[-1]
 
+    # crc32 of each program's StableHLO text at commit 0f41937 (x64 on,
+    # as the suite runs): PR 39's trainer counts its passes on the host
+    PARENT_TEXT = {
+        "bfloat16": {"gram": 392854805, "zr": 222862983, "apply_delta": 283477666},
+        "float32": {"gram": 1146523471, "zr": 324745983, "apply_delta": 2178169857},
+    }
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
+    def test_gaussian_programs_lower_to_the_parents_text(self, texts, program, dtype):
+        import zlib
+
+        got = zlib.crc32(texts[dtype][program].encode())
+        assert got == self.PARENT_TEXT[dtype][program]
+
     @pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
     def test_f32_features_hold_one_cosine(self, texts, program):
         text = texts["float32"][program]

@@ -538,3 +538,50 @@ def test_three_tables_lower_to_fewer_gathers_than_two_did(one_chip):
 
     assert gathers(_prepared_shapes(n, nnz, one_chip, **CELL_LAYOUT)) == 2 * (3 * 9 + 1) < 2 * 2 * 17
     assert gathers(_prepared_shapes(n, nnz, one_chip)) == 2 * 2 * 9
+
+
+# -- the polynomial kernel's TensorSketch map in the streamed trainer ---------
+
+_PPT_SCOPES = ("ppt.hash", "ppt.dft", "ppt.product", "ppt.inverse")
+
+
+@pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
+def test_streaming_krr_ppt_programs_carry_the_four_scopes(one_chip, as_tpu, program):
+    """The three chunk programs of ``krr_poly2_mnist8m_resident`` (65,536
+    x 784 -> 4096, q = 2, 32 panels) on the chip's route, the bf16
+    (cos, sin) DFT: the four ``ppt.*`` scopes ride in the metadata under
+    the feature pass, the hash, transforms and inverse own operations,
+    and the temporaries leave the 3.8 GB of resident arrays room."""
+    import sys
+
+    from libskylark_tpu.ml import PolynomialKernel
+    from libskylark_tpu.ml.krr import streaming_krr_chunk_programs
+    from libskylark_tpu.utils import profiling
+
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
+    import scope_reduce
+
+    D, SZ, NB, BR, T = 784, 4096, 32, 65_536, 10
+    M = PolynomialKernel(D, q=2, c=1.0, gamma=1.0 / D).create_rft(
+        SZ, "regular", SketchContext(seed=20261015))
+    assert M._dft_wins(jnp.dtype(BF16), BR)
+    progs = dict(zip(("gram", "zr", "apply_delta"),
+                     streaming_krr_chunk_programs([M], 0, NB, BR, _krr_rows, BF16)))
+    lam, X, R, W = ((), F32), ((NB * BR, D), BF16), ((NB, BR, T), F32), ((SZ, T), F32)
+    shapes = {"gram": (lam, X), "zr": (lam, R, W, X), "apply_delta": (R, W, X)}
+    compiled = _compile(progs[program], one_chip, *shapes[program])
+    text = compiled.as_text()
+    for scope in _PPT_SCOPES:
+        assert re.search(rf'op_name="[^"]*krr\.features/{re.escape(scope)}/', text), scope
+    rx = re.compile(r"^ppt\.")
+    owned = {scope_reduce.scope_of(scope_reduce.owner(entry), rx)
+             for entry in profiling.hlo_scopes(text).values()} - {None}
+    # the level product is fused into the transforms' operations: it owns none
+    assert owned == {"ppt.hash", "ppt.dft", "ppt.inverse"}
+    # the tables are made once a launch: one cosine and one sine in the
+    # program (unbarred, the compiler fused them into each of the six
+    # transforms' convolutions of the loop body)
+    assert len(re.findall(r" cosine\(", text)) == len(re.findall(r" sine\(", text)) == 1
+    # f32 (Re, Im) panels of 1 GB a level and the product's pair
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
