@@ -19,9 +19,12 @@ TPU cost (round 3, v5e, 131072×4096→1024 q=3): the f32 FFT path runs
 149 ms — ~50 ms in the three split-CWT matmuls, ~50 ms in the four c64
 FFTs (~12-14 ms each, axis layout immaterial; measured), the rest in
 complex products.  For **bf16** inputs the S-point DFT is instead done
-as explicit (S, S) cos/sin MXU matmuls in real arithmetic (complex64
-never materializes; ~1.4 ms per half-transform vs 12.5 ms per FFT),
-measured 101→~45 ms.  f32 keeps the exact-precision FFT: a split-matmul
+as explicit MXU matmuls in real arithmetic (complex64 never
+materializes; even as a full (S, S) cos/sin pair, ~1.4 ms per
+half-transform vs 12.5 ms per FFT).  A real input's spectrum is
+Hermitian, so S real numbers hold it: a forward transform is two
+(S, S/2) products and the inverse two (S/2, S), S² multiply-adds a row
+each.  f32 keeps the exact-precision FFT: a split-matmul
 DFT needs ≥8 bf16 passes (data split3 × matrix split2 per real part) and
 measures no faster than XLA's FFT.  ``jnp.fft.irfft`` is UNIMPLEMENTED
 on the TPU backend (probed) — only full complex ``fft``/``ifft`` and the
@@ -43,10 +46,11 @@ from .hash import CWT
 
 __all__ = ["PPT"]
 
-# bf16 matmul-DFT gate: the (S, S) cos+sin pair costs 2·S²·m MXU flops
-# per level vs ~6 HBM passes of (S, m) complex for the FFT; the matmul
-# wins for S up to several thousand and batches wide enough to amortize
-# building the two (S, S) tables in-graph.
+# bf16 matmul-DFT gate: a half-spectrum transform costs S²·m MXU
+# multiply-adds per level (two (S, S/2) tables) vs ~6 HBM passes of
+# (S, m) complex for the FFT; the matmul wins for S up to several
+# thousand and batches wide enough to amortize building the four
+# half-spectrum tables in-graph.
 _DFT_MAX_S = 1 << 12
 _DFT_MIN_BATCH = 4096
 _DFT_MAX_Q = 8  # bf16 table rounding compounds ~linearly in q; see _dft_wins
@@ -104,7 +108,7 @@ class PPT(SketchTransform):
             and 2 <= self.s <= _DFT_MAX_S
             and batch >= _DFT_MIN_BATCH
             # Each of the q forward transforms + the inverse rounds its
-            # (S, S) table to bf16 (~2^-8 relative per pass) and the
+            # tables to bf16 (~2^-8 relative per pass) and the
             # level products compound it, so worst-case feature error
             # grows ~linearly in q: measured ≤0.4% max-norm at q=3,
             # extrapolating past ~2% beyond q=8 — above the parity
@@ -118,7 +122,7 @@ class PPT(SketchTransform):
         """What every apply realizes that does not depend on the input:
         each level's CountSketch operands (``HashSketch.
         hoistable_operands``), the constant's hashed coordinates and
-        signs, and on the bf16 DFT route the (cos, sin) tables, made
+        signs, and on the bf16 DFT route the half-spectrum tables, made
         once a program and held as buffers — outside a streaming
         consumer's panel loop, and never fused back into its transforms.
         Memoized per dtype and route (``_memoized_operand``: skipped
@@ -194,42 +198,62 @@ class PPT(SketchTransform):
     # -- bf16 matmul-DFT fast path (TPU) -----------------------------------
 
     def _dft_tables(self):
-        """(cos, sin) (S, S) DFT tables in bf16, built in-graph.  The
+        """(Hc, Hs, G): the half-spectrum DFT tables in bf16, built
+        in-graph from one cosine and one sine of the (S, h) angles
+        2πjk/S, k < h = ⌈S/2⌉.  A real input's spectrum is Hermitian,
+        F[S−k] = conj F[k], so S real numbers hold it:
+
+        * ``Hc`` (S, h), cos 2πjk/S: the real parts of frequencies 0…h−1;
+        * ``Hs`` (S, h), −sin 2πjk/S: their imaginary parts, column 0
+          (frequency 0's, identically zero) carrying the real Nyquist
+          term's (−1)^j for even S, zero for odd S (no Nyquist term);
+        * ``G`` (2, h, S): the inverse, S · z = Re·G[0] + Im·G[1] —
+          ``Hc``ᵀ and ``Hs``ᵀ with row k times 2 for a conjugate pair
+          (row 0, frequency 0 and the Nyquist term, once).
+
+        Every entry is a bf16 cosine or sine, or twice one.  The
         index product j·k stays below 2^24 for S ≤ 2^12 (int32-exact,
         reduced mod S before the float conversion)."""
+        s, h = self.s, -(-self.s // 2)
         with jax.named_scope("ppt.dft"):
-            j = jnp.arange(self.s, dtype=jnp.int32)
-            jk = (j[:, None] * j[None, :]) % jnp.int32(self.s)
-            theta = jnp.float32(2.0 * np.pi / self.s) * jk.astype(jnp.float32)
-            return (
-                jnp.cos(theta).astype(jnp.bfloat16),
-                jnp.sin(theta).astype(jnp.bfloat16),
-            )
+            j = jnp.arange(s, dtype=jnp.int32)[:, None]
+            k = jnp.arange(h, dtype=jnp.int32)[None, :]
+            theta = jnp.float32(2.0 * np.pi / s) * ((j * k) % jnp.int32(s)).astype(jnp.float32)
+            nyquist = (1 - 2 * (j % 2)) * (1 - s % 2)
+            Hc = jnp.cos(theta).astype(jnp.bfloat16)
+            Hs = jnp.where(k == 0, nyquist, -jnp.sin(theta)).astype(jnp.bfloat16)
+            # made once: unbarred, the compiler makes the cosine and the
+            # sine again in the fusion that transposes them
+            Hc, Hs = jax.lax.optimization_barrier((Hc, Hs))
+            w = jnp.where(k == 0, 1, 2).astype(jnp.bfloat16)
+            return Hc, Hs, jnp.stack([(Hc * w).T, (Hs * w).T])
 
     def _features_dft(self, X, rowwise: bool = False, ops=None):
-        """bf16 features via explicit real-arithmetic DFT matmuls: each
-        level's S-point transform is a (cos, sin) MXU matmul pair, the
-        level products run as (Re, Im) f32 pairs, and the inverse
-        transform is one more pair — complex64 never materializes.
+        """bf16 features via explicit real-arithmetic DFT matmuls on the
+        half spectrum (:meth:`_dft_tables`): each level's S-point
+        transform is two (S, h) MXU matmuls, the level products run on
+        (Re, Im) f32 pairs of h columns, and the inverse is one matmul
+        contracting the stacked (2, h) spectrum — one (S, S) matmul's
+        work a transform, half the full spectrum's; complex64 never
+        materializes.
         Values match the FFT path to bf16 feature accuracy (the DFT
         tables round to bf16; inputs are already bf16).  ``rowwise``
         keeps the batch on the major axis ((m, S) layout, transform on
         the minor axis) so rowwise applies skip two full-batch
-        transposes — the DFT tables are symmetric, so the same (cos,
-        sin) pair serves both orientations."""
-        cwt_ops, (idx, val), (C, Sn) = self._operands(ops, True)
+        transposes: each product contracts the tables' leading axes with
+        the S (or frequency) axes of either layout."""
+        cwt_ops, (idx, val), (Hc, Hs, G) = self._operands(ops, True)
         sqrt_g = jnp.asarray(np.sqrt(self.gamma), jnp.bfloat16)
         sqrt_c = jnp.asarray(np.sqrt(self.c), jnp.float32)
         dim = Dimension.ROWWISE if rowwise else Dimension.COLUMNWISE
+        ax = 1 if rowwise else 0  # the S (then frequency) axis
 
         def mm(W, M):
-            # Contracts the S axis of W (axis 1 rowwise / 0 columnwise)
-            # with the symmetric (S, S) table, preserving W's layout.
-            args = (W, M) if rowwise else (M, W)
+            # Contracts W's axis ``ax`` with the table's axis 0,
+            # preserving W's layout.
+            args, dims = ((W, M), ((1,), (0,))) if rowwise else ((M, W), ((0,), (0,)))
             return jax.lax.dot_general(
-                *args, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+                *args, (dims, ((), ())), preferred_element_type=jnp.float32)
 
         def add_const(W, l):
             loc = (slice(None), idx[l]) if rowwise else (idx[l], slice(None))
@@ -242,16 +266,26 @@ class PPT(SketchTransform):
                 W = sqrt_g * cwt.apply_with_operands(cwt_ops[l], X, dim)
                 Wb = add_const(W, l).astype(jnp.bfloat16)
             with jax.named_scope("ppt.dft"):
-                Re, Im = mm(Wb, C), -mm(Wb, Sn)
+                Re, Im = mm(Wb, Hc), mm(Wb, Hs)
             if Pr is None:
                 Pr, Pi = Re, Im
             else:
                 with jax.named_scope("ppt.product"):
-                    Pr, Pi = Pr * Re - Pi * Im, Pr * Im + Pi * Re
-        # ifft real part: (1/S)·(C@Pr − Sn@Pi)  (e^{+iθ} = C + i·Sn).
+                    # column 0 holds two reals, frequency 0 and Nyquist
+                    real = jax.lax.broadcasted_iota(jnp.int32, Re.shape, ax) == 0
+                    Pr, Pi = (Pr * Re - jnp.where(real, 0.0, Pi * Im),
+                              jnp.where(real, Pi * Im, Pr * Im + Pi * Re))
+        # ifft real part: [Pr | Pi]/S · G, the conjugate halves in G; 1/S
+        # before the bf16 rounding (exact for S a power of two), where it
+        # fuses into the product.  Stacked on a major axis, the halves are
+        # written in place and read by one product of K = 2h.
         with jax.named_scope("ppt.inverse"):
-            Z = mm(Pr.astype(jnp.bfloat16), C) - mm(Pi.astype(jnp.bfloat16), Sn)
-            return (Z * jnp.float32(1.0 / self.s)).astype(jnp.bfloat16)
+            r = jnp.float32(1.0 / self.s)
+            P = jnp.stack([(Pr * r).astype(jnp.bfloat16), (Pi * r).astype(jnp.bfloat16)])
+            args, dims = ((P, G), ((0, 2), (0, 1))) if rowwise else ((G, P), ((0, 1), (0, 1)))
+            Z = jax.lax.dot_general(
+                *args, (dims, ((), ())), preferred_element_type=jnp.float32)
+            return Z.astype(jnp.bfloat16)
 
     def apply(self, A, dim: Dimension | str = Dimension.COLUMNWISE):
         return self._apply(A, dim, None)

@@ -739,30 +739,61 @@ class TestPPT:
         )
         assert Z.shape == (64, 4)
 
-    @pytest.mark.slow
-    def test_bf16_dft_matches_fft(self, rng, monkeypatch):
-        """The bf16 matmul-DFT fast path (sketch/ppt.py round 3) must
-        agree with the complex-FFT path to bf16 feature accuracy and
-        with the f64 exact path to ~1% of the feature scale."""
+    @pytest.mark.parametrize("dim", ["columnwise", "rowwise"])
+    @pytest.mark.parametrize("q", [1, 2, 3])
+    @pytest.mark.parametrize("s", [16, 64, 15])
+    def test_bf16_dft_matches_fft(self, rng, monkeypatch, s, q, dim):
+        """The bf16 matmul-DFT route must agree with the complex-FFT
+        route and with the f64 path to 2 % of the feature scale, for an
+        even and an odd S, one to three levels, both orientations."""
         import libskylark_tpu.sketch.ppt as pptmod
 
         monkeypatch.setattr(pptmod, "_DFT_MIN_BATCH", 8)
-        monkeypatch.setenv("SKYLARK_PPT_DFT", "1")  # CPU: force TPU path
-        n, s, m = 24, 16, 64
+        monkeypatch.setenv("SKYLARK_PPT_DFT", "1")  # CPU: force the chip's route
+        monkeypatch.delenv("SKYLARK_NO_PPT_DFT", raising=False)
+        n, m = 24, 64
         A = rng.standard_normal((n, m))
-        F = PPT(n, s, SketchContext(seed=7), q=3, c=0.7, gamma=1.3)
+        A = A if dim == "columnwise" else A.T
+        F = PPT(n, s, SketchContext(seed=7), q=q, c=0.7, gamma=1.3)
         A16 = jnp.asarray(A).astype(jnp.bfloat16)
-        Z_dft = F.apply(A16, "columnwise")
-        assert Z_dft.dtype == jnp.bfloat16
+        assert F._dft_wins(jnp.dtype(jnp.bfloat16), m)
+        Z_dft = F.apply(A16, dim)
+        assert Z_dft.dtype == jnp.bfloat16 and Z_dft.shape == ((s, m) if dim == "columnwise" else (m, s))
         monkeypatch.setenv("SKYLARK_NO_PPT_DFT", "1")
-        Z_fft = F.apply(A16, "columnwise")
-        Z64 = F.apply(jnp.asarray(A), "columnwise")
-        scale = float(jnp.max(jnp.abs(Z64)))
-        d_paths = float(
-            jnp.max(jnp.abs(Z_dft.astype(jnp.float64) - Z_fft.astype(jnp.float64)))
-        )
-        d_exact = float(
-            jnp.max(jnp.abs(Z_dft.astype(jnp.float64) - np.asarray(Z64)))
-        )
-        assert d_paths / scale < 0.02
-        assert d_exact / scale < 0.02
+        Z_fft = np.asarray(F.apply(A16, dim), np.float64)
+        Z64 = np.asarray(F.apply(jnp.asarray(A), dim))
+        Z_dft = np.asarray(Z_dft, np.float64)
+        scale = np.max(np.abs(Z64))
+        assert np.max(np.abs(Z_dft - Z_fft)) / scale < 0.02
+        assert np.max(np.abs(Z_dft - Z64)) / scale < 0.02
+
+    @pytest.mark.parametrize("s", [16, 15])
+    def test_half_spectrum_is_the_full_spectrum_at_the_kept_frequencies(self, rng, s):
+        """A level's spectrum on the DFT route, W·Hc and W·Hs, is the
+        full-spectrum (cos, sin) product with the same bf16 tables at
+        frequencies 0…⌈S/2⌉−1, the real Nyquist term in the imaginary
+        half's column 0 (zero for odd S); and the inverse tables give
+        the full inverse's real part of that spectrum.  Products of bf16
+        values summed in float64 are exact: the forward comparisons are
+        too."""
+        h = -(-s // 2)
+        Hc, Hs, G = (np.asarray(T, np.float64)
+                     for T in PPT(8, s, SketchContext(seed=7), q=1)._dft_tables())
+        j = np.arange(s)
+        theta = np.float32(2 * np.pi / s) * ((j[:, None] * j[None, :]) % s).astype(np.float32)
+        C, Sn = (np.asarray(jnp.asarray(f(theta)).astype(jnp.bfloat16), np.float64)
+                 for f in (np.cos, np.sin))
+        W = np.asarray(jnp.asarray(rng.standard_normal((32, s))).astype(jnp.bfloat16), np.float64)
+        Re, Im = W @ C, -(W @ Sn)
+        np.testing.assert_array_equal(W @ Hc, Re[:, :h])
+        np.testing.assert_array_equal((W @ Hs)[:, 1:], Im[:, 1:h])
+        np.testing.assert_array_equal((W @ Hs)[:, 0], Re[:, s // 2] if s % 2 == 0 else 0)
+        # the inverse: Re·C − Im·Sn over all S frequencies, here the kept
+        # ones with the conjugate halves folded into G (the bf16
+        # tables' conjugate columns agree but for entries of cos ±π/2,
+        # which round to ±4e-8, not 0)
+        P = np.concatenate([Re[:, :h], (W @ Hs)], axis=1)
+        full = Re @ C - Im @ Sn
+        np.testing.assert_allclose(P @ G.reshape(2 * h, s), full,
+                                   rtol=0, atol=1e-6 * np.max(np.abs(full)))
+        np.testing.assert_allclose(full / s, W, rtol=0, atol=1e-2 * np.max(np.abs(W)))

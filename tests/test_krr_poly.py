@@ -3,9 +3,9 @@ TensorSketch map (``sketch/ppt.py``) with its operands hoisted out of the
 chunk programs' panel loops.
 
 ``PPT.apply_with_operands`` is ``PPT.apply`` bit for bit on both routes
-(the complex FFT, and the bf16 (cos, sin) matmul DFT that the chip takes
-and ``SKYLARK_PPT_DFT=1`` forces here); the DFT route's (S, S) tables are
-built once a program, outside the loop; the trained model is a plain
+(the complex FFT, and the bf16 half-spectrum matmul DFT that the chip
+takes and ``SKYLARK_PPT_DFT=1`` forces here); the DFT route's (S, S/2)
+tables are built once a program, outside the loop; the trained model is a plain
 TensorSketch-plus-ridge that reads the map's draws as data; and
 ``model.info`` says how many panel passes the call made.  The DFT route
 is forced with ``monkeypatch`` on maps of their own seeds: the chunk
@@ -75,7 +75,8 @@ def test_operands_are_memoized_by_dtype_and_route(monkeypatch):
     assert fft is M.hoistable_operands(jnp.bfloat16) and fft[2] is None
     monkeypatch.setenv("SKYLARK_PPT_DFT", "1")
     dft = M.hoistable_operands(jnp.bfloat16)
-    assert dft[2][0].shape == (S, S) and dft[2][0].dtype == jnp.bfloat16
+    assert [T.shape for T in dft[2]] == [(S, S // 2)] * 2 + [(2, S // 2, S)]
+    assert all(T.dtype == jnp.bfloat16 for T in dft[2])
     assert len(dft[0]) == M.q and dft[0][0][0] == "sign"
     assert M.hoistable_operands(jnp.float64) is None
 
@@ -150,7 +151,7 @@ def dft_texts():
 @pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
 def test_the_tables_are_built_once_a_program_outside_the_panel_loop(dft_texts, program):
     lines = dft_texts[program].splitlines()
-    table = re.compile(rf"stablehlo\.(cosine|sine) .*tensor<{S}x{S}xf32>")
+    table = re.compile(rf"stablehlo\.(cosine|sine) .*tensor<{S}x{S // 2}xf32>")
     at = [i for i, ln in enumerate(lines) if table.search(ln)]
     assert len(at) == 2  # one cosine, one sine
     main = next(i for i, ln in enumerate(lines) if "func.func public @main" in ln)

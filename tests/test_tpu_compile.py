@@ -545,13 +545,31 @@ def test_three_tables_lower_to_fewer_gathers_than_two_did(one_chip):
 _PPT_SCOPES = ("ppt.hash", "ppt.dft", "ppt.product", "ppt.inverse")
 
 
+def _products(text, scope):
+    """(output dims but those of 1, contracted length) of each
+    convolution the compiled ``text`` holds under the named ``scope``: K
+    from M·K · K·N / M·N."""
+    dims = {m.group(1): [int(d) for d in m.group(2).split(",")]
+            for m in re.finditer(r"(%[\w.-]+) = \w+\[([\d,]+)\]", text)}
+    out = []
+    for m in re.finditer(r"(%[\w.-]+) = \w+\[[\d,]+\]\S* convolution\((%[\w.-]+), (%[\w.-]+)\)"
+                         r'.*op_name="[^"]*/' + re.escape(scope) + "/", text):
+        o, a, b = (dims[name] for name in m.groups())
+        out.append((tuple(d for d in o if d != 1),
+                    math.isqrt(math.prod(a) * math.prod(b) // math.prod(o))))
+    return sorted(out)
+
+
 @pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
 def test_streaming_krr_ppt_programs_carry_the_four_scopes(one_chip, as_tpu, program):
     """The three chunk programs of ``krr_poly2_mnist8m_resident`` (65,536
     x 784 -> 4096, q = 2, 32 panels) on the chip's route, the bf16
-    (cos, sin) DFT: the four ``ppt.*`` scopes ride in the metadata under
-    the feature pass, the hash, transforms and inverse own operations,
-    and the temporaries leave the 3.8 GB of resident arrays room."""
+    half-spectrum DFT: the four ``ppt.*`` scopes ride in the metadata
+    under the feature pass and own its operations; a level's transform
+    is two products of S/2 columns and the inverse one product of the
+    stacked halves, one S x S product's work each, where the full
+    spectrum took two; and the temporaries leave the 3.8 GB of resident
+    arrays room."""
     import sys
 
     from libskylark_tpu.ml import PolynomialKernel
@@ -577,11 +595,17 @@ def test_streaming_krr_ppt_programs_carry_the_four_scopes(one_chip, as_tpu, prog
     rx = re.compile(r"^ppt\.")
     owned = {scope_reduce.scope_of(scope_reduce.owner(entry), rx)
              for entry in profiling.hlo_scopes(text).values()} - {None}
-    # the level product is fused into the transforms' operations: it owns none
-    assert owned == {"ppt.hash", "ppt.dft", "ppt.inverse"}
+    # ppt.product owns the column-0 mask (S/2 predicates a panel); its
+    # arithmetic rides in the second level's transform, as it did there
+    assert owned == set(_PPT_SCOPES)
+    # q = 2 levels of two (65536, 2048) spectra halves, contracting S;
+    # the inverse contracts the stacked halves, S, into the panel
+    assert _products(text, "ppt.dft") == [((BR, SZ // 2), SZ)] * 4
+    assert _products(text, "ppt.inverse") == [((BR, SZ), SZ)]
     # the tables are made once a launch: one cosine and one sine in the
     # program (unbarred, the compiler fused them into each of the six
     # transforms' convolutions of the loop body)
     assert len(re.findall(r" cosine\(", text)) == len(re.findall(r" sine\(", text)) == 1
-    # f32 (Re, Im) panels of 1 GB a level and the product's pair
-    assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
+    # the f32 spectra are halves of 0.5 GB: 4.36-4.41 GB in all, where
+    # the full spectrum's (Re, Im) panels of 1 GB took 5.97-6.02 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
