@@ -24,7 +24,12 @@ materializes; even as a full (S, S) cos/sin pair, ~1.4 ms per
 half-transform vs 12.5 ms per FFT).  A real input's spectrum is
 Hermitian, so S real numbers hold it: a forward transform is two
 (S, S/2) products and the inverse two (S/2, S), S² multiply-adds a row
-each.  f32 keeps the exact-precision FFT: a split-matmul
+each.  A CountSketch has one ±1 a coordinate, so where its one-hot
+operands exist (n·S ≤ ``_ONEHOT_LIMIT``) the forward tables take it in:
+C_l·Hc is the (n, S/2) table of Hc's rows at the buckets, signed, and a
+level's spectrum is X·[C_l·Hc | C_l·Hs], 2nS multiply-adds a row where
+the hash and the transform took 2nS + S².  f32 keeps the
+exact-precision FFT: a split-matmul
 DFT needs ≥8 bf16 passes (data split3 × matrix split2 per real part) and
 measures no faster than XLA's FFT.  ``jnp.fft.irfft`` is UNIMPLEMENTED
 on the TPU backend (probed) — only full complex ``fft``/``ifft`` and the
@@ -118,33 +123,46 @@ class PPT(SketchTransform):
 
     # -- loop-invariant operands ---------------------------------------------
 
+    def _folds(self) -> bool:
+        """Whether the bf16 DFT route folds each level's CountSketch into
+        its forward tables: where the CountSketches have their one-hot
+        operands (``HashSketch.hoistable_operands``, n·S ≤
+        ``_ONEHOT_LIMIT``).  There a level's two folded tables hold the
+        bytes of the sign matrix they replace, and its S × S transform
+        is gone; above, the hash and the transform stay apart."""
+        return self.n * self.s <= CWT._ONEHOT_LIMIT
+
     def hoistable_operands(self, dtype):
         """What every apply realizes that does not depend on the input:
         each level's CountSketch operands (``HashSketch.
-        hoistable_operands``), the constant's hashed coordinates and
-        signs, and on the bf16 DFT route the half-spectrum tables, made
-        once a program and held as buffers — outside a streaming
-        consumer's panel loop, and never fused back into its transforms.
-        Memoized per dtype and route (``_memoized_operand``: skipped
-        mid-trace).  The route is ``_dft_wins`` at the smallest batch
-        that gate admits; :meth:`apply_with_operands` re-decides it by
-        the real batch, as :meth:`apply` does, and ignores or builds the
-        tables to match."""
+        hoistable_operands``; None where the tables fold them in), the
+        constant's hashed coordinates and signs, and on the bf16 DFT
+        route the tables (:meth:`_tables`), made once a program and held
+        as buffers — outside a streaming consumer's panel loop, and
+        never fused back into its transforms.  Memoized per dtype and
+        route (``_memoized_operand``: skipped mid-trace).  The route is
+        ``_dft_wins`` at the smallest batch that gate admits;
+        :meth:`apply_with_operands` re-decides it by the real batch, as
+        :meth:`apply` does, and ignores or builds the tables to match
+        (a thin batch's CountSketches then build their own operands)."""
         dt = jnp.dtype(dtype)
         if dt.type not in (jnp.bfloat16, jnp.float32):
             return None
         dft = self._dft_wins(dt, _DFT_MIN_BATCH)
+        fold = dft and self._folds()
 
         def build():
             with jax.named_scope("ppt.hash"):
-                cwt_ops = tuple(c.hoistable_operands(dt) for c in self._cwts)
+                cwt_ops = tuple(
+                    None if fold else c.hoistable_operands(dt) for c in self._cwts)
                 consts = self._hash_consts(jnp.float32)
             tables = None
             if dft:
                 # Behind the barrier the tables are buffers: without it the
-                # TPU compiler fuses their cos and sin into every
-                # transform's convolution, where they are made tile by tile.
-                tables = jax.lax.optimization_barrier(self._dft_tables())
+                # TPU compiler fuses their cos and sin, or the folded rows'
+                # gather, into every transform's convolution, where they
+                # are made tile by tile.
+                tables = jax.lax.optimization_barrier(self._tables(consts))
             return cwt_ops, consts, tables
 
         return self._memoized_operand(f"{dt.name}/{dft}", build)
@@ -165,7 +183,7 @@ class PPT(SketchTransform):
             with jax.named_scope("ppt.hash"):
                 consts = self._hash_consts(jnp.float32)
         if dft and tables is None:
-            tables = self._dft_tables()
+            tables = self._tables(consts)
         return cwt_ops, consts, tables
 
     def _features(self, X, ops=None):
@@ -228,25 +246,59 @@ class PPT(SketchTransform):
             w = jnp.where(k == 0, 1, 2).astype(jnp.bfloat16)
             return Hc, Hs, jnp.stack([(Hc * w).T, (Hs * w).T])
 
+    def _tables(self, consts):
+        """The bf16 DFT route's tables: :meth:`_dft_tables`' ``(Hc, Hs,
+        G)``, or where :meth:`_folds` ``(Tc, Ts, Rc, Rs, G)``, each
+        level's CountSketch (buckets b_l, signs v_l) and the constant's
+        coordinate (h_l, s_l of ``consts``) taken into its forward
+        tables:
+
+        * ``Tc[l]``, ``Ts[l]`` (n, h) bf16: v_l ⊙ Hc[b_l] and v_l ⊙
+          Hs[b_l], the rows of Hc and Hs at the level's buckets, signed
+          (exact: a signed bf16 entry), so X·Tc[l] is (X·C_l)·Hc summed
+          in f32;
+        * ``Rc``, ``Rs`` (q, h) f32: the constant's spectrum,
+          √c·s_l·Hc[h_l] and √c·s_l·Hs[h_l].
+
+        The rows' gathers and signs are ``ppt.hash``'s."""
+        Hc, Hs, G = self._dft_tables()
+        if not self._folds():
+            return Hc, Hs, G
+        idx, val = consts
+        with jax.named_scope("ppt.hash"):
+            signed = [(c.buckets(), c.values(jnp.bfloat16)[:, None]) for c in self._cwts]
+            Tc = tuple(v * Hc[b] for b, v in signed)
+            Ts = tuple(v * Hs[b] for b, v in signed)
+            r = jnp.float32(np.sqrt(self.c)) * val[:, None]
+            Rc = r * Hc[idx].astype(jnp.float32)
+            Rs = r * Hs[idx].astype(jnp.float32)
+        return Tc, Ts, Rc, Rs, G
+
     def _features_dft(self, X, rowwise: bool = False, ops=None):
         """bf16 features via explicit real-arithmetic DFT matmuls on the
-        half spectrum (:meth:`_dft_tables`): each level's S-point
-        transform is two (S, h) MXU matmuls, the level products run on
-        (Re, Im) f32 pairs of h columns, and the inverse is one matmul
-        contracting the stacked (2, h) spectrum — one (S, S) matmul's
-        work a transform, half the full spectrum's; complex64 never
+        half spectrum (:meth:`_tables`): each level's S-point transform
+        is two (S, h) MXU matmuls — or, where the tables fold the
+        CountSketch in, two (n, h) matmuls of X itself, √γ and the
+        constant's spectrum added to the f32 products — the level
+        products run on (Re, Im) f32 pairs of h columns, and the inverse
+        is one matmul contracting the stacked (2, h) spectrum — one
+        (S, S) matmul's work, half the full spectrum's; complex64 never
         materializes.
         Values match the FFT path to bf16 feature accuracy (the DFT
         tables round to bf16; inputs are already bf16).  ``rowwise``
         keeps the batch on the major axis ((m, S) layout, transform on
         the minor axis) so rowwise applies skip two full-batch
         transposes: each product contracts the tables' leading axes with
-        the S (or frequency) axes of either layout."""
-        cwt_ops, (idx, val), (Hc, Hs, G) = self._operands(ops, True)
-        sqrt_g = jnp.asarray(np.sqrt(self.gamma), jnp.bfloat16)
+        the n, S (or frequency) axes of either layout."""
+        cwt_ops, (idx, val), tables = self._operands(ops, True)
+        fold = self._folds()
+        if fold:
+            Tc, Ts, Rc, Rs, G = tables
+        else:
+            Hc, Hs, G = tables
         sqrt_c = jnp.asarray(np.sqrt(self.c), jnp.float32)
         dim = Dimension.ROWWISE if rowwise else Dimension.COLUMNWISE
-        ax = 1 if rowwise else 0  # the S (then frequency) axis
+        ax = 1 if rowwise else 0  # the n, S (then frequency) axis
 
         def mm(W, M):
             # Contracts W's axis ``ax`` with the table's axis 0,
@@ -255,18 +307,26 @@ class PPT(SketchTransform):
             return jax.lax.dot_general(
                 *args, (dims, ((), ())), preferred_element_type=jnp.float32)
 
-        def add_const(W, l):
+        def spectrum(l):
+            """Level l's (Re, Im) half spectrum, f32."""
+            if fold:
+                sqrt_g = jnp.asarray(np.sqrt(self.gamma), jnp.float32)
+                with jax.named_scope("ppt.dft"):
+                    # the constant's spectrum, the same for every input
+                    cr, ci = (R[l][None, :] if rowwise else R[l][:, None] for R in (Rc, Rs))
+                    return sqrt_g * mm(X, Tc[l]) + cr, sqrt_g * mm(X, Ts[l]) + ci
+            sqrt_g = jnp.asarray(np.sqrt(self.gamma), jnp.bfloat16)
             loc = (slice(None), idx[l]) if rowwise else (idx[l], slice(None))
-            return W.astype(jnp.float32).at[loc].add(sqrt_c * val[l])
-
-        Pr = Pi = None
-        for l, cwt in enumerate(self._cwts):
             with jax.named_scope("ppt.hash"):
                 # (m, S) rowwise / (S, m) columnwise
-                W = sqrt_g * cwt.apply_with_operands(cwt_ops[l], X, dim)
-                Wb = add_const(W, l).astype(jnp.bfloat16)
+                W = sqrt_g * self._cwts[l].apply_with_operands(cwt_ops[l], X, dim)
+                Wb = W.astype(jnp.float32).at[loc].add(sqrt_c * val[l]).astype(jnp.bfloat16)
             with jax.named_scope("ppt.dft"):
-                Re, Im = mm(Wb, Hc), mm(Wb, Hs)
+                return mm(Wb, Hc), mm(Wb, Hs)
+
+        Pr = Pi = None
+        for l in range(self.q):
+            Re, Im = spectrum(l)
             if Pr is None:
                 Pr, Pi = Re, Im
             else:

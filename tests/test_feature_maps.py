@@ -797,3 +797,112 @@ class TestPPT:
         np.testing.assert_allclose(P @ G.reshape(2 * h, s), full,
                                    rtol=0, atol=1e-6 * np.max(np.abs(full)))
         np.testing.assert_allclose(full / s, W, rtol=0, atol=1e-2 * np.max(np.abs(W)))
+
+    # -- the CountSketch folded into the forward tables ------------------------
+
+    @pytest.fixture
+    def dft_route(self, monkeypatch):
+        """The chip's bf16 DFT route, forced on the CPU for batches of 8."""
+        import libskylark_tpu.sketch.ppt as pptmod
+
+        monkeypatch.setattr(pptmod, "_DFT_MIN_BATCH", 8)
+        monkeypatch.setenv("SKYLARK_PPT_DFT", "1")
+        monkeypatch.delenv("SKYLARK_NO_PPT_DFT", raising=False)
+        return monkeypatch
+
+    @staticmethod
+    def _composed(F, X):
+        """The features of the rows of X (float64) on the DFT route's
+        tables without a rounding between them: each level's CountSketch
+        of √γ·x plus √c·s_l at bucket h_l (the map's draws read as
+        data), its half spectrum through ``Hc`` and ``Hs``, the levels'
+        product, and the inverse through ``G``."""
+        Hc, Hs, G = (np.asarray(T, np.float64) for T in F._dft_tables())
+        idx, val = (np.asarray(a) for a in F._hash_consts(jnp.float32))
+        Pr = Pi = None
+        for l, cwt in enumerate(F._cwts):
+            b = np.asarray(cwt.buckets())
+            v = np.asarray(cwt.values(jnp.float32), np.float64)
+            W = np.zeros((X.shape[0], F.s))
+            np.add.at(W.T, b, np.sqrt(F.gamma) * v[:, None] * X.T)
+            W[:, idx[l]] += np.sqrt(F.c) * val[l]
+            Re, Im = W @ Hc, W @ Hs
+            if Pr is None:
+                Pr, Pi = Re, Im
+            else:  # column 0 holds two reals, frequency 0 and Nyquist
+                real = np.arange(Re.shape[1]) == 0
+                Pr, Pi = (Pr * Re - np.where(real, 0.0, Pi * Im),
+                          np.where(real, Pi * Im, Pr * Im + Pi * Re))
+        return np.concatenate([Pr, Pi], axis=1) @ G.reshape(-1, F.s) / F.s
+
+    @pytest.mark.parametrize("q", [1, 3])
+    @pytest.mark.parametrize("s", [16, 15])
+    def test_folded_tables_are_the_signed_table_rows_at_the_buckets(self, dft_route, s, q):
+        """v_l ⊙ Hc[b_l] and v_l ⊙ Hs[b_l] bit for bit, the constant's
+        spectrum √c·s_l·Hc[h_l] and √c·s_l·Hs[h_l] in f32, and the
+        inverse's G as the unfolded route has it."""
+        F = PPT(24, s, SketchContext(seed=11), q=q, c=0.7, gamma=1.3)
+        assert F._folds()
+        consts = F._hash_consts(jnp.float32)
+        Tc, Ts, Rc, Rs, G = F._tables(consts)
+        Hc, Hs, G0 = (np.asarray(T) for T in F._dft_tables())
+        np.testing.assert_array_equal(np.asarray(G).view(np.uint16), G0.view(np.uint16))
+        for l, cwt in enumerate(F._cwts):
+            b, v = np.asarray(cwt.buckets()), np.asarray(cwt.values(jnp.bfloat16))
+            for T, H in ((Tc[l], Hc), (Ts[l], Hs)):
+                assert T.dtype == jnp.bfloat16 and T.shape == (24, -(-s // 2))
+                np.testing.assert_array_equal(np.asarray(T).view(np.uint16),
+                                              (v[:, None] * H[b]).view(np.uint16))
+        idx, val = (np.asarray(a) for a in consts)
+        r = np.float32(np.sqrt(0.7)) * val[:, None]
+        np.testing.assert_array_equal(np.asarray(Rc), r * Hc[idx].astype(np.float32))
+        np.testing.assert_array_equal(np.asarray(Rs), r * Hs[idx].astype(np.float32))
+
+    @pytest.mark.parametrize("dim", ["columnwise", "rowwise"])
+    @pytest.mark.parametrize("q", [1, 2, 3])
+    @pytest.mark.parametrize("s", [64, 15])
+    def test_folded_features_are_the_hash_then_the_half_spectrum(self, rng, dft_route, s, q, dim):
+        """The folded route's features are the CountSketch composed with
+        the half-spectrum DFT on the same bf16 tables, with no rounding
+        between them, to bf16 feature accuracy (the spectra's product
+        and the features round to bf16: ≤ 0.5 % of the scale read)."""
+        n, m = 24, 64
+        F = PPT(n, s, SketchContext(seed=12), q=q, c=0.7, gamma=1.3)
+        X = jnp.asarray(rng.standard_normal((m, n))).astype(jnp.bfloat16)
+        Z = F.apply(X if dim == "rowwise" else X.T, dim)
+        Z = np.asarray(Z if dim == "rowwise" else Z.T, np.float64)
+        want = self._composed(F, np.asarray(X, np.float64))
+        assert np.max(np.abs(Z - want)) / np.max(np.abs(want)) < 1e-2
+
+    @pytest.mark.parametrize("dim", ["columnwise", "rowwise"])
+    @pytest.mark.parametrize("fold", [True, False], ids=["fold", "unfold"])
+    def test_the_fold_engages_by_the_one_hot_shape(self, rng, dft_route, fold, dim):
+        """The tables fold the CountSketches in where their one-hot
+        operands exist (n·S ≤ ``_ONEHOT_LIMIT``), else the hash and the
+        transform stay apart; either way ``apply_with_operands`` is
+        ``apply`` bit for bit, and the features are the complex FFT
+        route's to 2 % of the feature scale."""
+        from libskylark_tpu.sketch.hash import HashSketch
+
+        assert PPT(1 << 15, 4096, SketchContext(seed=0), q=2)._folds()
+        assert not PPT((1 << 15) + 1, 4096, SketchContext(seed=0), q=2)._folds()
+        if not fold:  # this map's n·S over the limit
+            dft_route.setattr(HashSketch, "_ONEHOT_LIMIT", 24 * 64 - 1)
+        n, m, s = 24, 64, 64
+        F = PPT(n, s, SketchContext(seed=13), q=2, c=0.7, gamma=1.3)
+        assert F._folds() == fold
+        ops = F.hoistable_operands(jnp.bfloat16)
+        assert len(ops[2]) == (5 if fold else 3)
+        # folded into the tables, or (over the limit) no one-hot operands
+        assert ops[0] == (None, None)
+        A = jnp.asarray(rng.standard_normal((m, n))).astype(jnp.bfloat16)
+        A = A if dim == "rowwise" else A.T
+        Z = np.asarray(F.apply(A, dim))
+        np.testing.assert_array_equal(np.asarray(F.apply_with_operands(ops, A, dim)), Z)
+        hoisted = jax.jit(lambda A: F.apply_with_operands(F.hoistable_operands(jnp.bfloat16), A, dim))
+        np.testing.assert_array_equal(
+            np.asarray(hoisted(A)), np.asarray(jax.jit(lambda A: F.apply(A, dim))(A)))
+        dft_route.setenv("SKYLARK_NO_PPT_DFT", "1")
+        Z_fft = np.asarray(F.apply(A, dim), np.float64)
+        Z = Z.astype(np.float64)
+        assert np.max(np.abs(Z - Z_fft)) / np.max(np.abs(Z_fft)) < 0.02

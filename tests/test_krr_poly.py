@@ -75,9 +75,13 @@ def test_operands_are_memoized_by_dtype_and_route(monkeypatch):
     assert fft is M.hoistable_operands(jnp.bfloat16) and fft[2] is None
     monkeypatch.setenv("SKYLARK_PPT_DFT", "1")
     dft = M.hoistable_operands(jnp.bfloat16)
-    assert [T.shape for T in dft[2]] == [(S, S // 2)] * 2 + [(2, S // 2, S)]
-    assert all(T.dtype == jnp.bfloat16 for T in dft[2])
-    assert len(dft[0]) == M.q and dft[0][0][0] == "sign"
+    # the CountSketches folded into the forward tables: no sign matrices
+    assert M._folds() and dft[0] == (None,) * M.q
+    Tc, Ts, Rc, Rs, G = dft[2]
+    assert [T.shape for T in Tc + Ts] == [(12, S // 2)] * (2 * M.q)
+    assert all(T.dtype == jnp.bfloat16 for T in Tc + Ts + (G,))
+    assert Rc.shape == Rs.shape == (M.q, S // 2) and Rc.dtype == Rs.dtype == jnp.float32
+    assert G.shape == (2, S // 2, S)
     assert M.hoistable_operands(jnp.float64) is None
 
 
@@ -162,6 +166,11 @@ def test_the_tables_are_built_once_a_program_outside_the_panel_loop(dft_texts, p
 
 @pytest.mark.parametrize("program", ["gram", "zr", "apply_delta"])
 def test_the_programs_carry_the_four_scopes_under_the_feature_pass(dft_texts, program):
+    """The transforms, the level products and the inverse ride under the
+    feature pass; the hash, folded into the forward tables, is made with
+    them once a program, before the panel loop."""
     text = dft_texts[program]
-    for scope in SCOPES:
+    for scope in SCOPES[1:]:
         assert re.search(rf"krr\.features/{re.escape(scope)}/", text), scope
+    assert re.search(r'"jit\(\w+\)/ppt\.hash/', text)
+    assert not re.search(r"krr\.features/ppt\.hash/", text)

@@ -564,12 +564,14 @@ def _products(text, scope):
 def test_streaming_krr_ppt_programs_carry_the_four_scopes(one_chip, as_tpu, program):
     """The three chunk programs of ``krr_poly2_mnist8m_resident`` (65,536
     x 784 -> 4096, q = 2, 32 panels) on the chip's route, the bf16
-    half-spectrum DFT: the four ``ppt.*`` scopes ride in the metadata
-    under the feature pass and own its operations; a level's transform
-    is two products of S/2 columns and the inverse one product of the
-    stacked halves, one S x S product's work each, where the full
-    spectrum took two; and the temporaries leave the 3.8 GB of resident
-    arrays room."""
+    half-spectrum DFT with each level's CountSketch folded into its
+    forward tables: the four ``ppt.*`` scopes own the program's
+    operations, the transforms, level products and inverse under the
+    feature pass and the hash (the folded tables' rows) once a launch
+    before it; a level's transform is two products of the panel itself
+    by (d, S/2) tables, and the inverse one product of the stacked
+    halves, one S x S product's work; and the temporaries leave the
+    3.8 GB of resident arrays room."""
     import sys
 
     from libskylark_tpu.ml import PolynomialKernel
@@ -590,22 +592,26 @@ def test_streaming_krr_ppt_programs_carry_the_four_scopes(one_chip, as_tpu, prog
     shapes = {"gram": (lam, X), "zr": (lam, R, W, X), "apply_delta": (R, W, X)}
     compiled = _compile(progs[program], one_chip, *shapes[program])
     text = compiled.as_text()
-    for scope in _PPT_SCOPES:
+    for scope in _PPT_SCOPES[1:]:
         assert re.search(rf'op_name="[^"]*krr\.features/{re.escape(scope)}/', text), scope
+    # the hash is the folded tables' gathered, signed rows, made with them
+    assert re.search(r'op_name="jit\(\w+\)/jit\(\w+\)/ppt\.hash/', text)
+    assert not re.search(r'op_name="[^"]*krr\.features/ppt\.hash/', text)
     rx = re.compile(r"^ppt\.")
     owned = {scope_reduce.scope_of(scope_reduce.owner(entry), rx)
              for entry in profiling.hlo_scopes(text).values()} - {None}
     # ppt.product owns the column-0 mask (S/2 predicates a panel); its
     # arithmetic rides in the second level's transform, as it did there
     assert owned == set(_PPT_SCOPES)
-    # q = 2 levels of two (65536, 2048) spectra halves, contracting S;
-    # the inverse contracts the stacked halves, S, into the panel
-    assert _products(text, "ppt.dft") == [((BR, SZ // 2), SZ)] * 4
+    # q = 2 levels of two (65536, 2048) spectra halves, contracting the
+    # panel's d: the CountSketch rides in the tables; the inverse
+    # contracts the stacked halves, S, into the panel
+    assert _products(text, "ppt.dft") == [((BR, SZ // 2), D)] * 4
     assert _products(text, "ppt.inverse") == [((BR, SZ), SZ)]
     # the tables are made once a launch: one cosine and one sine in the
-    # program (unbarred, the compiler fused them into each of the six
+    # program (unbarred, the compiler fused them into each of the
     # transforms' convolutions of the loop body)
     assert len(re.findall(r" cosine\(", text)) == len(re.findall(r" sine\(", text)) == 1
-    # the f32 spectra are halves of 0.5 GB: 4.36-4.41 GB in all, where
-    # the full spectrum's (Re, Im) panels of 1 GB took 5.97-6.02 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+    # no hashed (65536, 4096) bf16 panel a level: 2.19-2.24 GB in all,
+    # where the hash, then the half spectrum, took 4.36-4.41 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.4e9
