@@ -44,18 +44,22 @@ routes share one step body.
 **A block's products.**  The recurrence above meets ``Z_j`` four times:
 ``Wbar_jᵀ Z_j`` (the objective's), ``Z_j·dsumᵀ`` (the right-hand
 side's), then after the solve ``o_j = Wi_jᵀ Z_j`` and ``ZtObar_j =
-Z_j·o_jᵀ``.  A remade block is a temporary that is written, read and
-dropped, and its reads set the pace, so that route reads it twice:
-``Z_j·dsumᵀ`` before the solve; after it ONE product whose small operand
-is ``Wbar_j`` and ``Wi_j`` side by side along k (both are known by then,
-``Wbar_j`` since the iteration began), the first k columns of the result
-the objective's, the rest ``o_j`` (summed over the blocks side by side
-and split once, after the loop); and ``ZtObar_j = Z_j Z_jᵀ Wi_j =
-G_j Wi_j`` from the block's Gram matrix, which ``admm_factor`` forms
-once a call for ``L_j`` and keeps beside it (``Gs``: s_j × s_j, no read
-of the block).  The cached route keeps the four products in the
-recurrence's order: its bits are pinned to ``ml/distributed.py``'s own
-copy of the step, and ``G_j Wi_j`` is another order of sums.
+Z_j·o_jᵀ``.  A block's reads set the pace (a remade block is a
+temporary that is written, read and dropped), so the step reads it
+twice on both routes: ``Z_j·dsumᵀ`` before the solve; after it ONE
+product whose small operand is ``Wbar_j`` and ``Wi_j`` side by side
+along k (both are known by then, ``Wbar_j`` since the iteration began),
+the first k columns of the result the objective's, the rest ``o_j``
+(summed over the blocks side by side and split once, after the loop);
+and ``ZtObar_j = Z_j Z_jᵀ Wi_j = G_j Wi_j`` from the block's Gram
+matrix, which ``admm_factor`` forms once a call for ``L_j`` and keeps
+beside it (``Gs``: s_j × s_j, no read of the block).
+
+**Split at the consensus sum.**  ``_step`` is ``_local_step`` (up to
+``Σ_partitions Wi`` and the loss's partial sum) then ``_merge_step`` in
+one trace.  ``ml/distributed.py`` puts its collective between the two
+(``admm_local``, ``admm_merge``), and runs ``admm_chunk`` where its
+world is one process: every trainer runs this one step.
 
 **Programs.**  Transform, factor and the iteration scan are module-level
 ``jax.jit`` programs keyed by shapes and a hashable ``_Spec`` (loss,
@@ -137,17 +141,19 @@ class _Spec:
 
 @dataclass
 class _PreparedRun:
-    """Everything ``train``/``chunked`` need that is NOT checkpointable
-    state: the programs' key, their operands (the realized feature blocks
-    or, remade, the partitioned X; the cached Cholesky factors; the
-    targets; ρ and λ) and the initial state tuple.  All of it is
-    deterministically rebuilt from (X, Y, maps, params) on resume — only
-    the state tuple rides the checkpoint."""
+    """Everything ``train``/``chunked`` (and a rank of
+    ``ml/distributed.py``) need that is NOT checkpointable state: the
+    programs' key, their operands (the realized feature blocks or,
+    remade, the partitioned X; the cached Cholesky factors and Gram
+    matrices; the targets; ρ and λ) and the initial state tuple.  All of
+    it is deterministically rebuilt from (X, Y, maps, params) or a rank's
+    streamed blocks on resume — only the state tuple rides the
+    checkpoint."""
 
     spec: _Spec
     feats: Any  # Zs [(P, s_j, n_i)] cached, X (n, d) remade
     Ls: list
-    Gs: list  # Z_j·Z_jᵀ (P, s_j, s_j) remade, none cached
+    Gs: list  # Z_j·Z_jᵀ (P, s_j, s_j)
     Yp: Any
     state0: tuple
     timer: PhaseTimer
@@ -288,10 +294,10 @@ def admm_transform(Xp, *, spec: _Spec):
 @partial(jax.jit, static_argnames=("spec", "dtype"))
 def admm_factor(feats, *, spec: _Spec, dtype):
     """``(Ls, Gs)``: the Cholesky factor of Z·Zᵀ + I per (partition,
-    block) (≙ Cache[j] = inv(Z·Zᵀ + I), BlockADMM.hpp:437-441).  Remade,
-    each block is made here for its Gram product and dropped, and the
-    Gram matrices are kept too (``Gs``; none cached): the iteration
-    takes ``ZtObar_j`` from them."""
+    block) (≙ Cache[j] = inv(Z·Zᵀ + I), BlockADMM.hpp:437-441), and the
+    Gram matrices Z·Zᵀ beside them: the iteration takes ``ZtObar_j``
+    from them.  Remade, each block is made here for its Gram product and
+    dropped."""
     Ls, Gs = [], []
     for j, s in enumerate(spec.sizes):
         if spec.cached:
@@ -302,21 +308,22 @@ def admm_factor(feats, *, spec: _Spec, dtype):
         with jax.named_scope("admm.factor"):
             G = _gram(Z, dtype)
             Ls.append(jnp.linalg.cholesky(G + jnp.eye(s, dtype=dtype)))
-            if not spec.cached:
-                Gs.append(G)
+            Gs.append(G)
     return Ls, Gs
 
 
-def _step(spec: _Spec, state, feats, Ls, Gs, Yp):
-    """One ADMM iteration.  ``feats``/``Ls``/``Gs``/``Yp`` are ARGUMENTS
-    of the programs, never closure captures: jit would embed closed-over
-    device arrays as constants in the serialized program."""
+def _local_step(spec: _Spec, state, feats, Ls, Gs, Yp):
+    """An iteration up to its consensus sum: ``(core, Σ_partitions Wi,
+    loss partial)``, ``core`` the state but ``Wbar`` and the objective.
+    The operands hold a rank's partitions; ``spec.P`` counts them all.
+    They are ARGUMENTS of the programs, never closure captures: jit would
+    embed closed-over device arrays as constants in the program."""
     loss, reg = get_loss(spec.loss), get_regularizer(spec.reg)
-    P, J = spec.P, len(spec.sizes)
+    J = len(spec.sizes)
     starts = np.cumsum((0,) + spec.sizes)
     D = int(starts[-1])
     Wbar, W, mu, O, Obar, nu, del_o, mu_ij, ZtObar, _ = state
-    k, dtype = Wbar.shape[1], Wbar.dtype
+    P, k, dtype = mu_ij.shape[0], Wbar.shape[1], Wbar.dtype
     rho, lam = jnp.asarray(spec.rho, dtype), jnp.asarray(spec.lam, dtype)
     if not spec.cached:
         # nothing of a block is loop-invariant: block 0 waits for the carry
@@ -328,13 +335,10 @@ def _step(spec: _Spec, state, feats, Ls, Gs, Yp):
         O = jax.vmap(lambda ob, y: loss.prox(ob, 1.0 / rho, y))(Obar, Yp)
         W = reg.prox(Wbar - mu, lam / rho)
 
-    if spec.cached:
-        wbar_out = sum_o = jnp.zeros_like(O)
-    else:
-        # Σ_j of (Wbar_jᵀZ_j, o_j) side by side along k, as the stacked
-        # product makes them, split once after the loop (two sums split a
-        # block at a time cost 1.1 % of a call: PERF.md section 6, PR 34)
-        outs = jnp.zeros((P, 2 * k) + O.shape[2:], dtype)
+    # Σ_j of (Wbar_jᵀZ_j, o_j) side by side along k, as the stacked
+    # product makes them, split once after the loop (two sums split a
+    # block at a time cost 1.1 % of a call: PERF.md section 6, PR 34)
+    outs = jnp.zeros((P, 2 * k) + O.shape[2:], dtype)
     Wi = jnp.zeros((P, D, k), dtype)
     mu_ij_new = mu_ij
     ZtObar_new = ZtObar
@@ -352,19 +356,12 @@ def _step(spec: _Spec, state, feats, Ls, Gs, Yp):
         with jax.named_scope("admm.block_solve"):
             Wij = _chol_solve(Ls[j], rhs)  # (P, sj, k)
         with jax.named_scope("admm.thin_products"):
-            if spec.cached:
-                # the recurrence's products, to the bit ml/distributed.py's
-                wbar_out = wbar_out + _thin("psn,sk->pkn", Z, Wbar[lo:hi])
-                o = _thin("psk,psn->pkn", Wij, Z)
-                zto = _thin("psn,pkn->psk", Z, o)
-                sum_o = sum_o + o
-            else:
-                # the block's second and last read: the objective's
-                # product rides with o_j's, and Z_j·o_jᵀ = G_j·Wi_j
-                both = jnp.concatenate(
-                    [jnp.broadcast_to(Wbar[lo:hi], Wij.shape), Wij], axis=2)
-                outs = outs + _thin("psk,psn->pkn", both, Z)
-                zto = jnp.einsum("psu,puk->psk", Gs[j], Wij, precision="highest")
+            # the block's second and last read: the objective's product
+            # rides with o_j's, and Z_j·o_jᵀ = G_j·Wi_j
+            both = jnp.concatenate(
+                [jnp.broadcast_to(Wbar[lo:hi], Wij.shape), Wij], axis=2)
+            outs = outs + _thin("psk,psn->pkn", both, Z)
+            zto = jnp.einsum("psu,puk->psk", Gs[j], Wij, precision="highest")
             Wi = Wi.at[:, lo:hi].set(Wij)
             mu_ij_new = mu_ij_new.at[:, lo:hi].add(Wij)
             ZtObar_new = ZtObar_new.at[:, lo:hi].set(zto)
@@ -373,21 +370,38 @@ def _step(spec: _Spec, state, feats, Ls, Gs, Yp):
             feats, outs = _after(feats, outs)
 
     with jax.named_scope("admm.tail"):
-        if not spec.cached:
-            wbar_out, sum_o = outs[:, :k], outs[:, k:]
+        wbar_out, sum_o = outs[:, :k], outs[:, k:]
         del_o = O - sum_o
         Obar = O - del_o / (J + 1.0)
         nu = nu + O - Obar
+        wi = jnp.sum(Wi, axis=0)
+        obj = jax.vmap(loss.evaluate)(wbar_out, Yp).sum()
+    return (W, mu, O, Obar, nu, del_o, mu_ij_new, ZtObar_new), wi, obj
+
+
+def _merge_step(spec: _Spec, core, wi, obj):
+    """The iteration's end from the consensus sum ``wi`` over every
+    partition and the loss's sum ``obj`` over every row."""
+    reg = get_regularizer(spec.reg)
+    W, mu, O, Obar, nu, del_o, mu_ij, ZtObar = core
+    lam = jnp.asarray(spec.lam, W.dtype)
+    with jax.named_scope("admm.tail"):
         # Consensus: sum over partitions (psum over ICI when sharded)
         # ≙ the MPI reduce of Wi (BlockADMM.hpp:574-578).
-        Wbar = (jnp.sum(Wi, axis=0) + W) / (P + 1.0)
+        Wbar = (wi + W) / (spec.P + 1.0)
         mu = mu + W - Wbar
-        obj = (jax.vmap(loss.evaluate)(wbar_out, Yp).sum()
-               + lam * reg.evaluate(Wbar))
-    return (Wbar, W, mu, O, Obar, nu, del_o, mu_ij_new, ZtObar_new, obj)
+        obj = obj + lam * reg.evaluate(Wbar)
+    return (Wbar, W, mu, O, Obar, nu, del_o, mu_ij, ZtObar, obj)
+
+
+def _step(spec: _Spec, state, feats, Ls, Gs, Yp):
+    """One ADMM iteration: both halves in one trace."""
+    return _merge_step(spec, *_local_step(spec, state, feats, Ls, Gs, Yp))
 
 
 admm_step = jax.jit(_step, static_argnames=("spec",))
+admm_local = jax.jit(_local_step, static_argnames=("spec",))
+admm_merge = jax.jit(_merge_step, static_argnames=("spec",))
 
 
 @partial(jax.jit, static_argnames=("spec", "maxiter"))
@@ -425,6 +439,38 @@ def admm_chunk(st, feats, Ls, Gs, Yp, *, spec: _Spec, maxiter: int, num_iters: i
 
 
 # -- the solver -------------------------------------------------------------
+
+
+def _code_targets(loss, Y, classes, regression: bool, P: int, dtype):
+    """``(Yp, classes, k)``: the targets of n rows in the programs'
+    layout over P partitions of them -- (P, k, n_i), or class indices
+    (P, n_i) for a loss that takes labels -- and k, the state's
+    columns."""
+    label_based = getattr(loss, "label_based", False)
+    if regression:
+        T = jnp.asarray(Y)
+        T = T[:, None] if T.ndim == 1 else T
+        k = T.shape[1]
+        return T.reshape(P, -1, k).transpose(0, 2, 1), classes, k
+    if isinstance(Y, jax.Array):
+        # labels on the device are coded there: no read of n
+        # labels to the host and no copy back
+        cls, classes = class_indices(Y, classes)
+        k = len(classes)
+        if label_based:
+            return cls.astype(dtype).reshape(P, -1), classes, k
+        T = jnp.where(cls[:, None] == jnp.arange(k), 1.0, -1.0).astype(dtype)
+    else:
+        T, classes = dummy_coding(Y, classes, dtype=dtype)
+        k = T.shape[1]
+        if label_based:
+            # Hinge/logistic take class indices (≙ the reference's
+            # crammed losses consuming the raw label vector).
+            cls = jnp.asarray(
+                np.searchsorted(np.asarray(classes), np.asarray(Y))
+            ).astype(dtype)
+            return cls.reshape(P, -1), classes, k
+    return T.reshape(P, -1, k).transpose(0, 2, 1), classes, k
 
 
 class BlockADMMSolver:
@@ -466,35 +512,8 @@ class BlockADMMSolver:
         # phase opens the stage span of its name.
         timer = PhaseTimer()
         with timer.phase("admm.labels") as ph:
-            label_based = getattr(self.loss, "label_based", False)
-            if regression:
-                T = jnp.asarray(Y)
-                T = T[:, None] if T.ndim == 1 else T
-                k = T.shape[1]
-                Yp = T.reshape(P, ni, k).transpose(0, 2, 1)
-            elif isinstance(Y, jax.Array):
-                # labels on the device are coded there: no read of n
-                # labels to the host and no copy back
-                cls, classes = class_indices(Y, classes)
-                k = len(classes)
-                if label_based:
-                    Yp = cls.astype(dtype).reshape(P, ni)
-                else:
-                    T = jnp.where(cls[:, None] == jnp.arange(k), 1.0, -1.0)
-                    Yp = T.astype(dtype).reshape(P, ni, k).transpose(0, 2, 1)
-            else:
-                T, classes = dummy_coding(Y, classes, dtype=dtype)
-                k = T.shape[1]
-                if label_based:
-                    # Hinge/logistic take class indices (≙ the reference's
-                    # crammed losses consuming the raw label vector).
-                    cls = jnp.asarray(
-                        np.searchsorted(np.asarray(classes), np.asarray(Y))
-                    ).astype(dtype)
-                    Yp = cls.reshape(P, ni)
-                else:
-                    Yp = T.reshape(P, ni, k).transpose(0, 2, 1)
-            ph.result = Yp
+            Yp, classes, k = ph.result = _code_targets(
+                self.loss, Y, classes, regression, P, dtype)
 
         sizes = [S.s for S in self.maps]
         D = int(sum(sizes))
@@ -542,7 +561,7 @@ class BlockADMMSolver:
             # its products before and after the solve, not made twice
             "feature_passes": 0 if run.spec.cached else 1,
             # reads of a block an iteration (the module docstring)
-            "block_reads": 4 if run.spec.cached else 2,
+            "block_reads": 2,
             "objective": model.history[-1] if model.history else None,
         }
         return model

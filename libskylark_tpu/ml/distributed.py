@@ -8,11 +8,13 @@ partition of the training set through
 handshake / epoch-fence contract, code 109/110/111 ladder), materializes
 its random-feature blocks batch-by-batch through
 :func:`~libskylark_tpu.plans.apply_rowwise_bucketed` (plan-compiled
-executables, bucket-ladder bounded), runs the local prox updates of
-:class:`~libskylark_tpu.ml.admm.BlockADMMSolver`'s step under the
-resilient ``init_state/step_chunk/extract_result`` contract, and merges
-consensus ONCE per outer iteration with a single
-:func:`~libskylark_tpu.parallel.collectives.cross_host_psum`.
+executables, bucket-ladder bounded), and runs
+:class:`~libskylark_tpu.ml.admm.BlockADMMSolver`'s cached route over
+them under the resilient ``init_state/step_chunk/extract_result``
+contract: the factors, the targets, the initial state and the step are
+``ml/admm.py``'s own, and consensus merges ONCE per outer iteration
+with a single :func:`~libskylark_tpu.parallel.collectives.cross_host_psum`
+between the step's two halves (``admm_local``, ``admm_merge``).
 
 Bitwise contracts (pinned by ``tests/test_distributed_train.py``):
 
@@ -20,7 +22,7 @@ Bitwise contracts (pinned by ``tests/test_distributed_train.py``):
   ``BlockADMMSolver.train`` bit-for-bit: the rowwise bucketed feature
   materialization equals ``_prepare``'s columnwise vmapped apply after
   the partition reshape, and with no collective to cross the iteration
-  runs as ONE fused jit tracing the exact jaxpr of the in-process step
+  runs ``admm_chunk``, the program ``BlockADMMSolver.chunked`` runs
   (the world>1 split compiles the two halves as separate XLA programs
   whose constant-folding rewrites can differ at the ULP level, so the
   split is reserved for real collectives — see
@@ -50,14 +52,11 @@ the run stays resumable and bit-for-bit.
 from __future__ import annotations
 
 import os
-from dataclasses import dataclass
-from typing import Any, Callable, Sequence
+from typing import Callable, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.scipy.linalg import solve_triangular
 
 from .. import guard, policy, telemetry
 from ..parallel.collectives import cross_host_psum
@@ -76,12 +75,22 @@ from ..streaming.elastic import (
     host_dir,
 )
 from ..streaming.repartition import resolve_resume
-from ..utils import compile_cache
+from ..sketch.rft import _is_narrow
+from ..utils import compile_cache, profiling
 from ..utils.exceptions import InvalidParameters
 from ..utils.timer import PhaseTimer
-from .admm import ADMMParams
-from .coding import dummy_coding
-from .model import FeatureMapModel
+from .admm import (
+    ADMMParams,
+    _code_targets,
+    _PreparedRun,
+    _Spec,
+    _zero_state,
+    admm_chunk,
+    admm_factor,
+    admm_local,
+    admm_merge,
+)
+from .model import FeatureMapModel, _Maps
 
 __all__ = [
     "KIND",
@@ -224,28 +233,6 @@ def stream_feature_blocks(
     return Z_rows, Y_rows, int(nbatches)
 
 
-@dataclass
-class _RankPrepared:
-    """Everything a rank's training loop needs that is NOT checkpointable
-    state — deterministically rebuilt from the streamed blocks on resume
-    (only the ``dict(it, inner, objs)`` state rides the checkpoint)."""
-
-    Zs: list
-    Ls: list
-    Yp: Any
-    state0: tuple
-    local_step: Callable
-    merge_step: Callable
-    timer: PhaseTimer
-    d: int
-    classes: Any
-    dtype: Any
-    P_local: int
-    P_total: int
-    D: int
-    k: int
-
-
 def prepare_rank_admm(
     loss,
     regularizer,
@@ -259,17 +246,12 @@ def prepare_rank_admm(
     classes=None,
     regression: bool = False,
     compute_dtype=None,
-) -> _RankPrepared:
-    """Build this rank's partitioned blocks, Cholesky factors, targets,
-    initial state, and the split step functions.
-
-    The ADMM step is split at its single cross-rank reduction:
-    ``local_step`` runs everything through the block loop and returns
-    ``(core..., Σ_local Wi, Σ_local loss)``; the caller psums the last
-    two; ``merge_step`` finishes ``Wbar/mu/obj`` from the merged sums.
-    At world=1 the concatenation of the two computes the exact op
-    sequence of :class:`BlockADMMSolver`'s fused step (bit-parity anchor
-    of the tier-1 suite).
+) -> _PreparedRun:
+    """This rank's run of ``BlockADMMSolver``'s cached route: its
+    partitions of the streamed blocks, their Cholesky factors and Gram
+    matrices (``admm_factor``), its targets and the initial state, with
+    the program key ``spec`` whose ``P`` counts the partitions of every
+    rank (the consensus denominator).
 
     ``compute_dtype`` (the policy precision rung) rounds the feature
     blocks through the low dtype before factoring — operand compression
@@ -282,8 +264,14 @@ def prepare_rank_admm(
     ni_p = validate_train_partition(partition, P_total)
     r0, r1 = partition.row_range(int(rank))
     P_local = (r1 - r0) // ni_p
+    # Narrow rows: the features keep their dtype, everything else is f32.
     dtype = Z_rows[0].dtype
-    d = int(maps[0].n)
+    dtype = jnp.dtype(jnp.float32) if _is_narrow(dtype) else dtype
+    spec = _Spec(
+        loss=loss.name, reg=reg.name, maps=_Maps(list(maps)), P=P_total,
+        scale_maps=bool(admm.scale_maps), cached=True, rho=float(admm.rho),
+        lam=float(admm.lam),
+    )
 
     timer = PhaseTimer()
     with timer.phase("transform") as ph:
@@ -296,131 +284,29 @@ def prepare_rank_admm(
         ]
         if compute_dtype is not None:
             cd = jnp.dtype(compute_dtype)
-            Zs = [Z.astype(cd).astype(dtype) for Z in Zs]
+            Zs = [Z.astype(cd).astype(Z.dtype) for Z in Zs]
         ph.result = Zs
 
-    label_based = getattr(loss, "label_based", False)
-    if regression:
-        T = jnp.asarray(Y_rows)
-        k = T.shape[1]
-        Yp = T.reshape(P_local, ni_p, k).transpose(0, 2, 1)
-    else:
-        Y = np.asarray(Y_rows)[:, 0]
-        if classes is None and partition.world_size > 1:
-            raise InvalidParameters(
-                "distributed classification needs the GLOBAL class set "
-                "passed explicitly (each rank only sees its own labels)"
-            )
-        T, classes = dummy_coding(Y, classes, dtype=dtype)
-        k = T.shape[1]
-        if label_based:
-            cls = jnp.asarray(
-                np.searchsorted(np.asarray(classes), np.asarray(Y))
-            ).astype(dtype)
-            Yp = cls.reshape(P_local, ni_p)
-        else:
-            Yp = T.reshape(P_local, ni_p, k).transpose(0, 2, 1)
+    if not regression and classes is None and partition.world_size > 1:
+        raise InvalidParameters(
+            "distributed classification needs the GLOBAL class set "
+            "passed explicitly (each rank only sees its own labels)"
+        )
+    Y = Y_rows if regression else np.asarray(Y_rows)[:, 0]
+    Yp, classes, k = _code_targets(loss, Y, classes, regression, P_local, dtype)
 
     with timer.phase("factor") as ph:
-        Ls = [
-            jnp.linalg.cholesky(
-                jnp.einsum("pst,put->psu", Z, Z, precision="highest")
-                + jnp.eye(Z.shape[1], dtype=dtype)
-            )
-            for Z in Zs
-        ]
-        ph.result = Ls
+        Ls, Gs = ph.result = admm_factor(Zs, spec=spec, dtype=dtype)
 
-    J = len(maps)
-    sizes = [int(S.s) for S in maps]
-    starts = np.cumsum([0] + sizes)
-    D = int(starts[-1])
-    rho = jnp.asarray(admm.rho, dtype)
-    lam = jnp.asarray(admm.lam, dtype)
-
-    def chol_solve(L, B):
-        Ysol = jax.vmap(lambda l, b: solve_triangular(l, b, lower=True))(L, B)
-        return jax.vmap(
-            lambda l, b: solve_triangular(l.T, b, lower=False)
-        )(L, Ysol)
-
-    # Zs/Ls/Yp enter as ARGUMENTS, not closure captures (jit would embed
-    # closed-over device arrays as program constants) — same discipline
-    # as the in-process trainer.
-    def local_step(state, Zs, Ls, Yp):
-        Wbar, W, mu, O, Obar, nu, del_o, mu_ij, ZtObar, _ = state
-        mu_ij = mu_ij - Wbar[None]
-        Obar = Obar - nu
-        O = jax.vmap(lambda ob, y: loss.prox(ob, 1.0 / rho, y))(Obar, Yp)
-        W = reg.prox(Wbar - mu, lam / rho)
-
-        sum_o = jnp.zeros_like(O)
-        wbar_out = jnp.zeros_like(O)
-        Wi = jnp.zeros((P_local, D, k), dtype)
-        mu_ij_new = mu_ij
-        ZtObar_new = ZtObar
-        dsum = del_o / (J + 1.0) + nu
-        for j in range(J):
-            lo, hi = int(starts[j]), int(starts[j + 1])
-            Z = Zs[j]
-            wbar_out = wbar_out + jnp.einsum("psn,sk->pkn", Z, Wbar[lo:hi])
-            rhs = (
-                Wbar[None, lo:hi]
-                - mu_ij[:, lo:hi]
-                + ZtObar[:, lo:hi]
-                + jnp.einsum("psn,pkn->psk", Z, dsum)
-            )
-            Wij = chol_solve(Ls[j], rhs)
-            o = jnp.einsum("psk,psn->pkn", Wij, Z)
-            Wi = Wi.at[:, lo:hi].set(Wij)
-            mu_ij_new = mu_ij_new.at[:, lo:hi].add(Wij)
-            ZtObar_new = ZtObar_new.at[:, lo:hi].set(
-                jnp.einsum("psn,pkn->psk", Z, o)
-            )
-            sum_o = sum_o + o
-
-        del_o = O - sum_o
-        Obar = O - del_o / (J + 1.0)
-        nu = nu + O - Obar
-        # The ONE cross-rank quantity: this rank's Σ_partitions Wi (and
-        # its local loss partial).  At world=1 the psum is a no-op and
-        # this is exactly the fused step's consensus sum.
-        wi_sum = jnp.sum(Wi, axis=0)
-        obj_local = jax.vmap(loss.evaluate)(wbar_out, Yp).sum()
-        return (
-            (W, mu, O, Obar, nu, del_o, mu_ij_new, ZtObar_new),
-            wi_sum,
-            obj_local,
-        )
-
-    def merge_step(core, wi_global, obj_global):
-        W, mu, O, Obar, nu, del_o, mu_ij, ZtObar = core
-        Wbar = (wi_global + W) / (P_total + 1.0)
-        mu = mu + W - Wbar
-        obj = obj_global + lam * reg.evaluate(Wbar)
-        return (Wbar, W, mu, O, Obar, nu, del_o, mu_ij, ZtObar, obj)
-
-    state0 = (
-        jnp.zeros((D, k), dtype),            # Wbar   (global)
-        jnp.zeros((D, k), dtype),            # W      (global)
-        jnp.zeros((D, k), dtype),            # mu     (global)
-        jnp.zeros((P_local, k, ni_p), dtype),  # O
-        jnp.zeros((P_local, k, ni_p), dtype),  # Obar
-        jnp.zeros((P_local, k, ni_p), dtype),  # nu
-        jnp.zeros((P_local, k, ni_p), dtype),  # del_o
-        jnp.zeros((P_local, D, k), dtype),   # mu_ij
-        jnp.zeros((P_local, D, k), dtype),   # ZtObar_ij
-        jnp.zeros((), dtype),                # obj
-    )
-    return _RankPrepared(
-        Zs=Zs, Ls=Ls, Yp=Yp, state0=state0, local_step=local_step,
-        merge_step=merge_step, timer=timer, d=d, classes=classes,
-        dtype=dtype, P_local=P_local, P_total=P_total, D=D, k=k,
+    state0 = _zero_state(D=sum(spec.sizes), k=int(k), P=P_local, ni=ni_p, dtype=dtype)
+    return _PreparedRun(
+        spec=spec, feats=Zs, Ls=Ls, Gs=Gs, Yp=Yp, state0=state0, timer=timer,
+        d=int(maps[0].n), classes=classes, dtype=dtype,
     )
 
 
 def rank_chunked_solver(
-    prep: _RankPrepared,
+    prep: _PreparedRun,
     maps: Sequence,
     admm: ADMMParams,
     *,
@@ -432,31 +318,25 @@ def rank_chunked_solver(
     ``BlockADMMSolver.chunked``'s, with per-partition leaves sized to
     this rank's share.
 
-    ``merge=None`` (world=1 / no collective) runs each outer iteration
-    as ONE jitted program — the fused ``local_step ∘ merge_step``
-    composition traces the exact jaxpr of ``BlockADMMSolver``'s step,
-    so the world=1 trainer is bitwise-identical to the in-process
-    ``train()``.  A callable ``merge`` (the distributed trainer passes
-    the watchdogged ``cross_host_psum``) runs the split schedule
-    ``jit(local_step) → merge → jit(merge_step)``: XLA compiles the two
-    halves as separate programs, whose value-changing rewrites (e.g.
-    divide-by-constant → multiply-by-reciprocal) may differ from the
-    fused program's at the ULP level — so cross-WORLD-SIZE bit-identity
-    is not promised, while within a world size every rank computes the
-    same bits and kill/resume reproduces the uninterrupted run
-    bit-for-bit (same programs, same blocks, same order).  Checkpoint
-    commits happen only AFTER a chunk's final merge completed
+    ``merge=None`` (world=1 / no collective) launches ``admm_chunk``,
+    the program ``BlockADMMSolver.chunked`` launches, so the world=1
+    trainer is bitwise-identical to the in-process ``train()``.  A
+    callable ``merge`` (the distributed trainer passes the watchdogged
+    ``cross_host_psum``) runs the split schedule ``admm_local → merge →
+    admm_merge`` an iteration: XLA compiles the two halves as separate
+    programs, whose value-changing rewrites (e.g. divide-by-constant →
+    multiply-by-reciprocal) may differ from the fused program's at the
+    ULP level — so cross-WORLD-SIZE bit-identity is not promised, while
+    within a world size every rank computes the same bits and
+    kill/resume reproduces the uninterrupted run bit-for-bit (same
+    programs, same blocks, same order).  All three are module-level
+    programs: a second solver at the same shapes builds nothing.
+    Checkpoint commits happen only AFTER a chunk's final merge completed
     collectively, so every rank durably holds the same chunk boundary
     on any kill — the lockstep resume is exact.
     """
     maxiter = int(admm.maxiter)
-    jit_local = jax.jit(prep.local_step)
-    jit_merge = jax.jit(prep.merge_step)
-    if merge is None:
-        @jax.jit
-        def jit_fused(st, Zs, Ls, Yp):
-            core, wi, obj = prep.local_step(st, Zs, Ls, Yp)
-            return prep.merge_step(core, wi, obj)
+    spec, operands = prep.spec, prep.operands
 
     def init_state():
         return dict(
@@ -468,26 +348,26 @@ def rank_chunked_solver(
     def step_chunk(st, num_iters: int):
         it = int(st["it"])
         stop = min(it + int(num_iters), maxiter)
-        # A restored checkpoint hands back host numpy leaves; the jits
-        # accept them, but the objs trace needs jnp's .at updates.
-        inner, objs = st["inner"], jnp.asarray(st["objs"])
-        done = 0
-        while it < stop:
-            if merge is None:
-                inner = jit_fused(inner, prep.Zs, prep.Ls, prep.Yp)
-            else:
-                core, wi, obj = jit_local(inner, prep.Zs, prep.Ls, prep.Yp)
+        if merge is None:
+            st = profiling.launch(
+                admm_chunk, st, *operands, spec=spec, maxiter=maxiter,
+                num_iters=int(num_iters))
+        else:
+            # A restored checkpoint hands back host numpy leaves; the
+            # programs accept them, but the objs trace needs jnp's .at.
+            inner, objs = st["inner"], jnp.asarray(st["objs"])
+            for i in range(it, stop):
+                core, wi, obj = profiling.launch(admm_local, spec, inner, *operands)
                 g = merge({"wi": wi, "obj": obj})
-                inner = jit_merge(
-                    core, jnp.asarray(g["wi"]), jnp.asarray(g["obj"])
-                )
-            objs = objs.at[it].set(inner[-1])
-            it += 1
-            done += 1
-        if done and telemetry.enabled():
-            telemetry.inc("train.iterations", done)
-            telemetry.inc("train.consensus", done)
-        return dict(it=jnp.asarray(it, jnp.int32), inner=inner, objs=objs)
+                inner = profiling.launch(
+                    admm_merge, spec, core, jnp.asarray(g["wi"]),
+                    jnp.asarray(g["obj"]))
+                objs = objs.at[i].set(inner[-1])
+            st = dict(it=jnp.asarray(stop, jnp.int32), inner=inner, objs=objs)
+        if stop > it and telemetry.enabled():
+            telemetry.inc("train.iterations", stop - it)
+            telemetry.inc("train.consensus", stop - it)
+        return st
 
     def extract_result(st):
         it = int(st["it"])
@@ -693,9 +573,9 @@ class DistributedBlockADMMTrainer:
                 if telemetry.enabled():
                     telemetry.inc("train.escalations")
 
-        # world=1: no collective → the fused single-jit step (bitwise
-        # parity with ``BlockADMMSolver.train``).  world>1: the split
-        # schedule with the watchdogged psum at the seam.
+        # world=1: no collective → ``admm_chunk`` (bitwise parity with
+        # ``BlockADMMSolver.train``).  world>1: the split schedule with
+        # the watchdogged psum at the seam.
         chunked = rank_chunked_solver(
             prep, self.maps, p,
             merge=(

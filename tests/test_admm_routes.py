@@ -1,18 +1,19 @@
 """BlockADMM's two routes: feature blocks cached for the run, or remade
 inside every iteration (``ADMMParams.cache_transforms``).
 
-- the remade route against the benchmark entry's plain reference
+- both routes against the benchmark entry's plain reference
   (``benchmarks/entries/admm_train.py``: the recurrence written out in
-  plain ``jax.numpy``, importing nothing of the library) for hinge and
-  squared loss: coefficients and the objective of every iteration;
-- remade against cached at the same size: the remade route reads each
-  block twice an iteration (the right-hand side's product; then the
-  objective's and ``o_j``'s in one, ``ZtObar_j`` from the block's Gram
-  matrix), the cached route four times, as the recurrence has it;
+  plain ``jax.numpy`` with its four products a block, importing nothing
+  of the library) for hinge and squared loss: coefficients and the
+  objective of every iteration;
+- remade against cached at the same size: both read each block twice an
+  iteration (the right-hand side's product; then the objective's and
+  ``o_j``'s in one, ``ZtObar_j`` from the block's Gram matrix), and
+  differ by the order of sums inside the feature GEMM;
 - ``None`` picks by bytes, from a memory figure the test supplies;
 - narrow rows keep an f32 state;
-- the cached route is bit for bit the distributed trainer's own copy of
-  the step (``ml/distributed.py``, untouched), at that suite's sizes;
+- a world-1 run of the distributed trainer (``ml/distributed.py``, which
+  launches this module's step) is ``BlockADMMSolver.train`` bit for bit;
 - labels already on the device are coded there, to the same model.
 """
 
@@ -97,11 +98,12 @@ def apart(model, W_ref, objs_ref):
 TOL = 1e-4
 
 
+@pytest.mark.parametrize("cache", [False, True], ids=["remade", "cached"])
 @pytest.mark.parametrize("loss", ["hinge", "squared"])
-def test_remade_route_runs_the_reference_recurrence(loss):
+def test_remade_route_runs_the_reference_recurrence(loss, cache):
     X, y = make_data()
     maps = make_maps()
-    model = train(loss, X, y, maps, cache_transforms=False)
+    model = train(loss, X, y, maps, cache_transforms=cache)
     W_ref, objs_ref = reference(loss, X, y, maps)
     dw, dobj = apart(model, W_ref, objs_ref)
     assert dw < TOL and dobj < TOL, (dw, dobj)
@@ -200,13 +202,13 @@ def test_narrow_routes_agree_to_f32_rounding():
     assert dw < 2e-5 and dobj < 2e-5, (dw, dobj)
 
 
-# -- two reads of a remade block against the cached route's four -------------
+# -- two reads of a block, remade or cached ------------------------------------
 
 LAYOUTS = {"P1": {}, "P4_scaled": {"data_partitions": 4, "scale_maps": True}}
 # Read on a CPU, coefficients / objective trace, hinge then squared:
-#   f64        P1 3.9e-15 / 3.4e-16, 3.2e-15 / 2.2e-16;  P4 scaled 7.5e-15 / 3.0e-16, 6.9e-15 / 2.6e-16
-#   f32        P1 5.3e-6 / 1.7e-7, 5.1e-6 / 1.2e-7;      P4 scaled 3.4e-6 / 1.1e-7, 3.6e-6 / 7.1e-8
-#   bf16 rows  P1 4.7e-6 / 1.7e-7, 4.5e-6 / 1.3e-7
+#   f64        P1 0 / 0, 0 / 0;                          P4 scaled 0 / 0, 0 / 0
+#   f32        P1 2.8e-6 / 8.6e-8, 2.5e-6 / 1.2e-7;      P4 scaled 2.8e-6 / 1.1e-7, 2.6e-6 / 6.5e-8
+#   bf16 rows  P1 3.4e-6 / 7.3e-8, 3.3e-6 / 1.3e-7
 
 
 @pytest.mark.parametrize("loss", ["hinge", "squared"])
@@ -216,14 +218,15 @@ LAYOUTS = {"P1": {}, "P4_scaled": {"data_partitions": 4, "scale_maps": True}}
     (jnp.bfloat16, 2e-5, "P1"),  # XLA:CPU has no batched bf16 x bf16 = f32 product for P = 4
 ], ids=["f64-P1", "f64-P4_scaled", "f32-P1", "f32-P4_scaled", "bf16_rows-P1"])
 def test_two_reads_of_a_block_train_the_four_read_model(loss, layout, dtype, tol):
-    """The remade route's schedule (product (2), solve, the stacked
-    product, ``G_j Wi_j``) against the cached route's four products:
-    another order of sums, the rounding of the state's dtype."""
+    """Both routes' schedule (product (2), solve, the stacked product,
+    ``G_j Wi_j``), the block made inside the iteration against the block
+    kept for the run: another order of sums in the feature GEMM, the
+    rounding of the state's dtype."""
     X, y = make_data(dtype)
     maps = make_maps()
     cached = train(loss, X, y, maps, cache_transforms=True, **LAYOUTS[layout])
     remade = train(loss, X, y, maps, cache_transforms=False, **LAYOUTS[layout])
-    assert cached.info["block_reads"] == 4 and remade.info["block_reads"] == 2
+    assert cached.info["block_reads"] == remade.info["block_reads"] == 2
     dw, dobj = apart(remade, np.asarray(cached.W, np.float64), np.asarray(cached.history))
     assert dw < tol and dobj < tol, (dw, dobj)
 
@@ -255,11 +258,24 @@ def test_gram_matrix_times_the_solve_is_the_blocks_last_product(dtype, tol):
         assert unread.dtype == read.dtype == run.dtype and d < tol, (j, d)
 
 
-def test_the_cached_route_keeps_no_gram_matrices():
+@pytest.mark.parametrize("cache", [True, False], ids=["cached", "remade"])
+def test_both_routes_keep_one_gram_matrix_a_block(cache):
+    """``G_j = Z_j Z_j'`` a block and partition (a remade block is made
+    inside the program, and to f32 rounding the one made here), and
+    ``L_j`` is the Cholesky factor of ``G_j + I``."""
     X, y = make_data()
-    solver = BlockADMMSolver("hinge", "l2", make_maps(), ADMMParams(cache_transforms=True))
+    solver = BlockADMMSolver("hinge", "l2", make_maps(), ADMMParams(cache_transforms=cache))
     run = solver._prepare(X, y, np.arange(K))
-    assert run.Gs == [] and len(run.Ls) == len(SIZES)
+    assert [G.shape for G in run.Gs] == [(1, s, s) for s in SIZES]
+    assert len(run.Ls) == len(SIZES)
+    for j, s in enumerate(SIZES):
+        Z = run.Zs[j] if cache else admm._block(run.spec, j, X)
+        G = admm._gram(Z, run.dtype)
+        assert run.Gs[j].dtype == run.dtype
+        assert float(jnp.linalg.norm(run.Gs[j] - G) / jnp.linalg.norm(G)) < 1e-6
+        np.testing.assert_array_equal(
+            np.asarray(run.Ls[j]),
+            np.asarray(jnp.linalg.cholesky(run.Gs[j] + jnp.eye(s, dtype=run.dtype))))
 
 
 def test_three_pieces_carry_an_f32_operand_through_a_bfloat16_product():
@@ -284,7 +300,7 @@ def test_three_pieces_carry_an_f32_operand_through_a_bfloat16_product():
         assert float(jnp.linalg.norm(o - o_exact) / jnp.linalg.norm(o_exact)) < 1e-6
 
 
-# -- the cached route is what it was ------------------------------------------
+# -- the distributed trainer runs this step -----------------------------------
 
 
 def bits(x):
@@ -294,9 +310,11 @@ def bits(x):
 @pytest.mark.parametrize("loss", ["squared", "hinge"])
 @pytest.mark.parametrize("cache", [True, None], ids=["cached", "by_bytes"])
 def test_cached_route_is_the_distributed_trainers_step_bit_for_bit(loss, cache):
-    """``ml/distributed.py`` keeps its own copy of the step, which this
-    PR did not touch: at world size 1 it is the in-process trainer's
-    model to the bit (the sizes of ``test_distributed_train.py``)."""
+    """A world-1 run of ``ml/distributed.py``'s trainer (streamed blocks,
+    ``admm_chunk``) is ``BlockADMMSolver.train`` (blocks made by
+    ``admm_transform`` or, by bytes, the route the CPU takes; the scan
+    ``admm_iterate``) to the bit, at ``test_distributed_train.py``'s
+    sizes."""
     n, d, batch = 32, 4, 4
     rng = np.random.default_rng(7)
     X, y = rng.standard_normal((n, d)), np.array([1.0, 2.0] * (n // 2))
@@ -315,8 +333,7 @@ def test_cached_route_is_the_distributed_trainers_step_bit_for_bit(loss, cache):
     ).train(source, part, regression=False)
     assert mine.info["transforms_cached"] == 1
     assert bits(mine.W) == bits(theirs.W)
-    if loss == "squared":  # the hinge objective is summed in another order there,
-        assert mine.history == theirs.history  # an ulp apart before this PR too
+    assert mine.history == theirs.history
 
 
 # -- labels coded where they live -----------------------------------------------

@@ -21,16 +21,17 @@ The load-bearing guarantees of ``ml/distributed.py``:
 
 import os
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _builds import builds
 
 from libskylark_tpu import SketchContext
-from libskylark_tpu.ml import ADMMParams, BlockADMMSolver
+from libskylark_tpu.ml import ADMMParams, BlockADMMSolver, admm
 from libskylark_tpu.ml.distributed import (
     DistributedBlockADMMTrainer,
     prepare_rank_admm,
+    rank_chunked_solver,
     stream_feature_blocks,
     validate_train_partition,
 )
@@ -256,21 +257,20 @@ class TestSimulatedTwoRank:
             )
 
         # Lockstep split schedule with the psum merged by hand — the
-        # exact program structure a real 2-process world runs.
-        jl = [jax.jit(p.local_step) for p in preps]
-        jm = [jax.jit(p.merge_step) for p in preps]
+        # exact programs a real 2-process world runs.
         states = [p.state0 for p in preps]
         hist = [[], []]
         for _ in range(params.maxiter):
             outs = [
-                jl[r](states[r], preps[r].Zs, preps[r].Ls, preps[r].Yp)
-                for r in (0, 1)
+                admm.admm_local(p.spec, st, *p.operands)
+                for p, st in zip(preps, states)
             ]
             wi_g = np.asarray(outs[0][1]) + np.asarray(outs[1][1])
             obj_g = np.asarray(outs[0][2]) + np.asarray(outs[1][2])
             for r in (0, 1):
-                states[r] = jm[r](
-                    outs[r][0], jnp.asarray(wi_g), jnp.asarray(obj_g)
+                states[r] = admm.admm_merge(
+                    preps[r].spec, outs[r][0], jnp.asarray(wi_g),
+                    jnp.asarray(obj_g)
                 )
                 hist[r].append(float(states[r][-1]))
 
@@ -288,6 +288,41 @@ class TestSimulatedTwoRank:
         np.testing.assert_allclose(
             hist[0], m_ref.history, rtol=1e-3, atol=1e-3
         )
+
+
+# ---------------------------------------------------------------------------
+# the rank solver's programs are the module's: built once a process
+# ---------------------------------------------------------------------------
+
+
+class TestRankProgramsBuiltOnce:
+    @pytest.mark.parametrize("merged", [False, True], ids=["world1", "split"])
+    def test_a_second_rank_solver_builds_nothing(self, merged):
+        """Two solvers on the same shapes: the second one's chunk traces
+        and lowers nothing, with no collective (``admm_chunk``) or with
+        one between the halves (``admm_local``, ``admm_merge``)."""
+        X, y = make_data()
+        maps, params = make_maps(), make_params()
+        part = RowPartition(nrows=N, batch_rows=BATCH, world_size=1)
+        Z_rows, Y_rows, _ = stream_feature_blocks(
+            source_of(X, y, part), maps, part, ElasticParams(prefetch=0),
+        )
+        prep = prepare_rank_admm(
+            "squared", "l2", maps, params, part, 0, Z_rows, Y_rows,
+            regression=True,
+        )
+        merge = (lambda tree: tree) if merged else None
+
+        def chunk():
+            solver = rank_chunked_solver(prep, maps, params, merge=merge)
+            return solver.step_chunk(solver.init_state(), 3)
+
+        first = chunk()
+        with builds() as seen:
+            second = chunk()
+        assert seen == []
+        assert int(second["it"]) == 3
+        assert bits(first["inner"][0]) == bits(second["inner"][0])
 
 
 # ---------------------------------------------------------------------------
