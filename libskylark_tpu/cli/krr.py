@@ -138,6 +138,8 @@ def main(argv=None) -> int:
         fn = (krr_mod.faster_kernel_ridge if args.regression
               else rlsc_mod.faster_kernel_rlsc)
         model = fn(kernel, Xj, yj, args.lam, args.numfeatures, ctx, params)
+    elif alg == "approximate" and is_sparse and args.kernel == "polynomial":
+        model = _sparse_tensorsketch(args, kernel, Xj, y, ctx, params)
     elif alg == "approximate":
         fn = (krr_mod.approximate_kernel_ridge if args.regression
               else rlsc_mod.approximate_kernel_rlsc)
@@ -178,6 +180,33 @@ def main(argv=None) -> int:
     print_policy_report(args)
     print_telemetry_report(args)
     return 0
+
+
+def _sparse_tensorsketch(args, kernel, X, y, ctx, params):
+    """-a 2 with the polynomial kernel on a sparse X: X laid out in row
+    panels (``core.sparse.panels``) and trained by the streamed trainer
+    (``ml.streaming_kernel_ridge``) with one TensorSketch of
+    --numfeatures features, a panel of rows at a time, so a set of any
+    number of rows is sketched a panel's bins at a time and X is never
+    densified.  Classification trains on the +-1 codes of the labels."""
+    from ..core import sparse
+    from ..ml import krr as krr_mod
+    from ..ml.coding import dummy_coding
+    from ..sketch.hash import HashSketch
+
+    n, d = X.shape
+    rows = krr_mod.panel_size(
+        n, max(HashSketch._DENSE_OUT_LIMIT // args.numfeatures, 1))
+    T, classes = (np.asarray(y), None) if args.regression else dummy_coding(y)
+    params.max_split = 2 * args.numfeatures  # one map, as -a 2 has it
+    model = krr_mod.streaming_kernel_ridge(
+        kernel, sparse.panel_rows, (n, d), T, args.lam, args.numfeatures, ctx,
+        params, block_rows=rows, feature_dtype=X.dtype,
+        block_args=(sparse.panels(X, rows),))
+    model.classes = classes
+    print(f"Sparse TensorSketch: {n // rows} panels of {rows} rows, "
+          f"{model.info['feature_nnz']} nonzeros fed to the map")
+    return model
 
 
 def _stream_main(args, is_sparse: bool) -> int:

@@ -38,6 +38,7 @@ __all__ = [
     "raw_bits",
     "window_bits",
     "sample",
+    "sample_at",
     "sample_window",
     "chi2_lanes",
     "DISTRIBUTIONS",
@@ -296,6 +297,29 @@ def sample(
     base+offset+num`` (``offset`` may be traced — see :func:`raw_bits`)."""
     hi, lo = raw_bits(seed, base, num, lane, offset=offset)
     return DISTRIBUTIONS[dist](hi, lo, dtype, **params)
+
+
+def sample_at(
+    dist: str,
+    seed: int,
+    base: int,
+    idx,
+    dtype=jnp.float32,
+    lane: int = 0,
+    **params: Any,
+):
+    """Values of a 1-D stream at the counters ``base + idx`` (``idx`` an
+    integer array of any shape, every entry below 2^32): element-wise the
+    same bits as :func:`sample`'s window at those counters, so a sketch
+    reads its draws at scattered coordinates (a sparse operand's column
+    ids) without realizing the whole stream or gathering from it."""
+    idx = jnp.asarray(idx)
+    b_hi, b_lo = _split64(base)
+    hi, lo = _add64(jnp.uint32(b_hi), jnp.uint32(b_lo),
+                    jnp.uint32(0), idx.astype(jnp.uint32).ravel())
+    out = threefry_2x32(_key(seed, lane), jnp.concatenate([hi, lo]))
+    n = idx.size
+    return DISTRIBUTIONS[dist](out[:n], out[n:], dtype, **params).reshape(idx.shape)
 
 
 def sample_window(

@@ -73,8 +73,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import sparse as jsparse
 
-__all__ = ["spmm", "prepare", "Prepared", "edge_chunks",
+__all__ = ["spmm", "prepare", "Prepared", "edge_chunks", "panels", "Panels",
+           "RowSlots", "row_slots", "panel_rows", "SLOT_MAX", "LINE_SLOTS",
            "CHUNK_BYTES", "PIECE", "TABLE_ROWS", "HOT_ROWS", "SCOPE"]
 
 SCOPE = "sparse.product"
@@ -403,3 +405,204 @@ def spmm(A, Y, *, transpose: bool = False):
     acc = jnp.promote_types(dtype, jnp.float32)
     with jax.named_scope(SCOPE):
         return _prepared_product(A, Y.astype(acc), acc).astype(dtype)
+
+
+# -- row slots and panels -------------------------------------------------------
+
+# The most slots a row holds in place; its further nonzeros go to overflow
+# rows.  The one-hot products that hash a row's slots
+# (``HashSketch._rows_dense_out``) were measured on a v5e at this width.
+SLOT_MAX = 128
+# What a line of slots costs besides its slots, in slots: its S bins.  A
+# v5e made 45,208 lines of 128 slots into S = 4096 f32 bins in 5.3 ms, of
+# which writing the bins at HBM rate is about 0.9 ms: a sixth, some 27
+# slots a line.  An overflow line's bins are read and added again.
+LINE_SLOTS = 32
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass(frozen=True)
+class RowSlots:
+    """An ``m × n`` sparse matrix as K slots a row.  ``data`` and
+    ``cols``, ``(m, K)``: row r's first nonzeros (at most K) in its first
+    slots, the other slots padding (value 0, column 0).  A row of more
+    than K nonzeros goes on in overflow rows: ``xdata`` and ``xcols``,
+    ``(E, K)``, hold K of them at a time, ``xrows`` ``(E,)`` the row each
+    belongs to, in ascending order (padding rows: the last row, value
+    0).  Padding adds nothing wherever it lands.  Static: ``shape``.
+    :func:`panels` makes them a panel at a time, :func:`row_slots` for a
+    whole BCOO."""
+
+    data: jax.Array
+    cols: jax.Array
+    xdata: jax.Array
+    xcols: jax.Array
+    xrows: jax.Array
+    shape: tuple
+
+    ndim = 2
+
+    def tree_flatten(self):
+        return (self.data, self.cols, self.xdata, self.xcols, self.xrows), (self.shape,)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+    @property
+    def dtype(self):
+        return self.data.dtype
+
+    def astype(self, dtype):
+        return RowSlots(self.data.astype(dtype), self.cols, self.xdata.astype(dtype),
+                        self.xcols, self.xrows, self.shape)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass(frozen=True)
+class Panels:
+    """A sparse matrix cut into row panels of one count of slots each, for
+    a consumer that walks them one at a time (:func:`panels`;
+    ``ml.streaming_kernel_ridge`` with :func:`panel_rows`): the arrays of
+    :class:`RowSlots` with a leading panel axis, ``data`` and ``cols``
+    ``(panels, rows, K)``, ``xdata`` and ``xcols`` ``(panels, E, K)``,
+    ``xrows`` ``(panels, E)`` (rows local to their panel).  Row r of
+    panel p is row ``p · rows + r`` of the matrix.  Static: ``shape``
+    and ``nse``, the nonzeros (padding not counted)."""
+
+    data: jax.Array
+    cols: jax.Array
+    xdata: jax.Array
+    xcols: jax.Array
+    xrows: jax.Array
+    shape: tuple
+    nse: int
+
+    def tree_flatten(self):
+        return ((self.data, self.cols, self.xdata, self.xcols, self.xrows),
+                (self.shape, self.nse))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+    @property
+    def rows(self) -> int:
+        """Rows of a panel."""
+        return self.data.shape[1]
+
+    def panel(self, p) -> RowSlots:
+        """Panel ``p`` (a traced index too) as :class:`RowSlots`."""
+        at = partial(lax.dynamic_index_in_dim, index=p, axis=0, keepdims=False)
+        return RowSlots(at(self.data), at(self.cols), at(self.xdata), at(self.xcols),
+                        at(self.xrows), (self.rows, self.shape[1]))
+
+
+def _triplets(A, shape):
+    """(row, col, val, (m, n)) of ``A`` on the host, sorted by row."""
+    if isinstance(A, jsparse.BCOO):
+        if A.n_batch or A.n_dense or A.ndim != 2:
+            raise ValueError(f"panels take a plain 2-D BCOO, got {A}")
+        shape, ordered = A.shape, A.indices_sorted
+        data, indices = A.data, A.indices
+    else:
+        (data, indices), ordered = A, False
+        if shape is None:
+            raise ValueError("host triplets need their shape")
+    m, n = (int(x) for x in shape)
+    idx, val = np.asarray(indices), np.asarray(data)
+    row, col = idx[:, 0].astype(np.int64), idx[:, 1].astype(np.int32)
+    live = (row < m) & (col < n)  # an index past the shape pads a BCOO
+    if not live.all():
+        row, col, val = row[live], col[live], val[live]
+    if not ordered and row.size and (np.diff(row) < 0).any():
+        order = np.argsort(row, kind="stable")
+        row, col, val = row[order], col[order], val[order]
+    return row, col, val, (m, n)
+
+
+def _slot_width(count, rows: int):
+    """(K, E): the slots a row holds in place (a multiple of 8, at most
+    ``SLOT_MAX``) and the overflow rows a panel (the most any panel needs,
+    rounded up to 8), chosen for the least work a panel: its ``rows + E``
+    lines of K slots and ``LINE_SLOTS`` for each line's bins, twice more
+    for an overflow line's; of equals, the widest K."""
+    top = min(SLOT_MAX, max(-(-int(count.max(initial=0)) // 8) * 8, 8))
+    per_panel = count.reshape(-1, rows)
+    best = None
+    for k in range(8, top + 1, 8):
+        extra = np.maximum(-(-per_panel // k) - 1, 0).sum(1)
+        e = -(-int(extra.max(initial=0)) // 8) * 8
+        work = (rows + e) * (k + LINE_SLOTS) + 2 * e * LINE_SLOTS
+        if best is None or work <= best[0]:
+            best = (work, k, e)
+    return best[1], best[2]
+
+
+def _lines(start, count, col, val, k, out):
+    """Slot j of line i is nonzero ``start[i] + j`` while ``j < count[i]``,
+    padding after; written into ``out``'s (values, columns) rows."""
+    j = np.arange(k)
+    live = j < count[:, None]
+    at = np.where(live, start[:, None] + j, 0)
+    out[0][...] = np.where(live, val[at], np.zeros((), val.dtype))
+    out[1][...] = np.where(live, col[at], 0)
+
+
+def panels(A, rows: int, *, shape=None) -> Panels:
+    """``A`` (``m × n``, ``m`` a multiple of ``rows``) as :class:`Panels`
+    of ``rows`` rows.  A row keeps its first K nonzeros in place and the
+    rest in overflow rows, K at a time; every panel holds ``rows + E``
+    lines of K slots, K and E chosen for the least work
+    (:func:`_slot_width`), so what a pass reads grows with the nonzeros
+    and not with the longest row.  ``A``: a plain 2-D BCOO, or the same
+    triplets on the host, ``(data, indices)`` numpy arrays with
+    ``shape``.  Laid out on the host (a sort by row unless the triplets
+    are sorted), the slots then put on the device: to be paid where the
+    operand is made, outside any timed call."""
+    row, col, val, (m, n) = _triplets(A, shape)
+    if rows < 1 or m % rows:
+        raise ValueError(f"{m} rows do not cut into panels of {rows}")
+    count = np.bincount(row, minlength=m)
+    start = np.concatenate([[0], np.cumsum(count)[:-1]])
+    if not col.size:  # every slot padding; something to read it from
+        col, val = np.zeros(1, np.int32), np.zeros(1, val.dtype)
+    k, e = _slot_width(count, rows)
+    nb = m // rows
+    data = np.empty((nb, rows, k), val.dtype)
+    cols = np.empty((nb, rows, k), np.int32)
+    xdata = np.zeros((nb, e, k), val.dtype)
+    xcols = np.zeros((nb, e, k), np.int32)
+    xrows = np.full((nb, e), rows - 1, np.int32)
+    for p in range(nb):
+        cnt, st = count[p * rows:(p + 1) * rows], start[p * rows:(p + 1) * rows]
+        _lines(st, cnt, col, val, k, (data[p], cols[p]))
+        pieces = np.maximum(-(-cnt // k) - 1, 0)
+        owner = np.repeat(np.arange(rows), pieces)
+        # the piece's place within its row: 1, 2, ... after the row's first K
+        nth = 1 + np.arange(owner.size) - np.repeat(np.cumsum(pieces) - pieces, pieces)
+        x = owner.size
+        _lines(st[owner] + nth * k, cnt[owner] - nth * k, col, val, k,
+              (xdata[p, :x], xcols[p, :x]))
+        xrows[p, :x] = owner
+    return Panels(jnp.asarray(data), jnp.asarray(cols), jnp.asarray(xdata),
+                  jnp.asarray(xcols), jnp.asarray(xrows), (m, n), int(count.sum()))
+
+
+def row_slots(A, *, shape=None) -> RowSlots:
+    """The whole of ``A`` (as :func:`panels` takes it) as one
+    :class:`RowSlots`."""
+    m = int((A.shape if shape is None else shape)[0])
+    return panels(A, m, shape=shape).panel(0)
+
+
+def panel_rows(start, rows, X: Panels) -> RowSlots:
+    """The ``block_fn`` of ``ml.streaming_kernel_ridge`` for a
+    :class:`Panels` ``X`` handed to it through ``block_args``: rows
+    ``start … start + rows`` (a traced ``start``, a multiple of ``rows``)
+    as the :class:`RowSlots` of panel ``start // rows``.  It lives with
+    its module, so the trainer's three programs are built once a
+    process."""
+    if rows != X.rows:
+        raise ValueError(f"panels of {X.rows} rows, asked for {rows}")
+    return X.panel(start // rows)

@@ -31,9 +31,11 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import sparse as jsparse
 from jax.scipy.linalg import cho_factor, cho_solve, solve_triangular
 
 from .. import guard, plans, telemetry
+from ..core import sparse
 from ..core.context import SketchContext
 from ..core.params import Params
 from ..core.precision import long_dot
@@ -523,6 +525,28 @@ def streaming_approximate_kernel_ridge(
     )
 
 
+def panel_size(n: int, block_rows: int) -> int:
+    """The rows of :func:`streaming_kernel_ridge`'s panels for ``n`` rows
+    and a request of ``block_rows``: the request where it divides n,
+    else the largest divisor of n not exceeding it (the panel size only
+    shapes memory, not results).  A degenerate divisor (n near-prime)
+    would turn the panel loops into per-row iteration: refused with an
+    actionable message instead."""
+    if n % block_rows == 0:
+        return block_rows
+    best = max(b for b in range(1, min(block_rows, n) + 1) if n % b == 0)
+    # best == n is always usable (the whole problem fits in ONE panel —
+    # nb=1 — the degenerate-divisor concern is moot); only error when a
+    # large n truly fractures into tiny panels.
+    if best < n and best < max(256, block_rows // 16):
+        raise ValueError(
+            f"n={n} has no usable panel divisor <= {block_rows} "
+            f"(best is {best}); pad n to a composite size or pass a "
+            "block_rows that divides it"
+        )
+    return best
+
+
 def streaming_kernel_ridge(
     kernel: Kernel,
     block_fn,
@@ -557,10 +581,21 @@ def streaming_kernel_ridge(
     default arguments or reads data from its module's namespace gets
     programs of the call's own, built again every call.
 
+    A sparse X: ``block_fn`` returns a BCOO panel or its rows in slots
+    (``core.sparse.RowSlots``), and the maps take it as it is (a
+    TensorSketch never densifies it; the operands hoisted for it are a
+    sparse input's).  The X of ``core.sparse.panels`` comes with its
+    ``block_fn``, ``core.sparse.panel_rows``, which lives with its
+    module: ``block_fn=panel_rows, block_args=(panels(X, block_rows),)``
+    with ``block_rows`` from :func:`panel_size`.
+
     ``model.info``: ``feature_passes``, the panel passes over X the
     call launched (a chunk's ``gram`` in sweep 0, its ``zr`` and
     ``apply_delta`` every sweep: 1 + 2·sweeps for one chunk), and
-    ``feature_map``, the maps' ``sketch_type``.
+    ``feature_map``, the maps' ``sketch_type``; with ``core.sparse.
+    Panels`` among ``block_args`` also ``feature_nnz``, the nonzeros
+    fed to the feature maps: nonzeros × the map's levels (a
+    TensorSketch's q CountSketches, else 1) × panel passes.
 
     ``timer``: optional ``utils.PhaseTimer`` — sweep 0 (which absorbs
     the per-chunk program compiles and factorizations) lands in phase
@@ -589,23 +624,7 @@ def streaming_kernel_ridge(
         compile_cache.place()
         params = params or KrrParams()
         n, d = shape
-        if n % block_rows:
-            # Largest divisor of n not exceeding the request: callers get a
-            # working panel size instead of a divisibility error (the panel
-            # size only shapes memory, not results).  A degenerate divisor
-            # (n near-prime) would turn the panel loops into per-row
-            # iteration — error out with an actionable message instead.
-            best = max(b for b in range(1, block_rows + 1) if n % b == 0)
-            # best == n is always usable (the whole problem fits in ONE
-            # panel — nb=1 — the degenerate-divisor concern is moot); only
-            # error when a large n truly fractures into tiny panels.
-            if best < n and best < max(256, block_rows // 16):
-                raise ValueError(
-                    f"n={n} has no usable panel divisor <= {block_rows} "
-                    f"(best is {best}); pad n to a composite size or pass a "
-                    "block_rows that divides it"
-                )
-            block_rows = best
+        block_rows = panel_size(n, block_rows)
         nb = n // block_rows
         Y2, _ = _as2d(Y)
         t = Y2.shape[1]
@@ -667,6 +686,9 @@ def streaming_kernel_ridge(
         model = FeatureMapModel(maps, W)
         model.info = {"feature_passes": passes,
                       "feature_map": maps[0].sketch_type}
+        nse = sum(a.nse for a in block_args if isinstance(a, sparse.Panels))
+        if nse:
+            model.info["feature_nnz"] = nse * getattr(maps[0], "q", 1) * passes
         return model
 
 
@@ -690,6 +712,16 @@ class _ChunkSpec:
     @property
     def sz(self):
         return self.map.s
+
+
+def _hoisted(spec, bargs):
+    """The map's operands hoisted out of the panel loop; for a sparse
+    panel (``block_fn`` gives a BCOO or ``core.sparse.RowSlots``, seen
+    from its shapes alone) a sparse input's."""
+    panel = jax.eval_shape(lambda *a: spec.block_fn(0, spec.block_rows, *a), *bargs)
+    if isinstance(panel, (jsparse.BCOO, sparse.RowSlots)):
+        return spec.map.hoistable_operands(spec.feature_dtype, sparse=True)
+    return spec.map.hoistable_operands(spec.feature_dtype)
 
 
 def _chunk_Zp(spec, start, bargs, ops):
@@ -718,7 +750,7 @@ def _prec(dtype):
 
 def _gram(spec, lam, *bargs):
     sz = spec.sz
-    ops = spec.map.hoistable_operands(spec.feature_dtype)
+    ops = _hoisted(spec, bargs)
 
     def body(p, G):
         Zp = _chunk_Zp(spec, p * spec.block_rows, bargs, ops)
@@ -746,7 +778,7 @@ def _gram(spec, lam, *bargs):
 
 
 def _zr(spec, lam, R3, Wc, *bargs):
-    ops = spec.map.hoistable_operands(spec.feature_dtype)
+    ops = _hoisted(spec, bargs)
 
     def body(p, acc):
         Zp = _chunk_Zp(spec, p * spec.block_rows, bargs, ops)
@@ -763,7 +795,7 @@ def _zr(spec, lam, R3, Wc, *bargs):
 
 
 def _apply_delta(spec, R3, delta, *bargs):
-    ops = spec.map.hoistable_operands(spec.feature_dtype)
+    ops = _hoisted(spec, bargs)
 
     def body(p, R3):
         Zp = _chunk_Zp(spec, p * spec.block_rows, bargs, ops)

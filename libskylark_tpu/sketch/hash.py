@@ -32,7 +32,8 @@ from jax.experimental import sparse as jsparse
 
 from ..core.context import SketchContext
 from ..core.precision import bf16_split3, f32_accumulable
-from ..core.random import sample
+from ..core.random import sample, sample_at
+from ..core.sparse import RowSlots, row_slots
 from . import pallas_window
 from .base import Dimension, SketchTransform, register_sketch
 
@@ -177,6 +178,21 @@ class HashSketch(SketchTransform):
             offset=offset,
         )
 
+    def buckets_at(self, idx):
+        """:meth:`buckets` at the flat-layout positions ``idx`` (an integer
+        array of any shape, ``h·N + i`` for hash h of coordinate i): the
+        same numbers, computed from their counters, so a sparse operand's
+        scattered coordinates are hashed without realizing or gathering
+        from an N-long array."""
+        return sample_at("uniform_int", self._seed, self._idx_base, idx,
+                         dtype=jnp.int32, low=0, high=self.s - 1)
+
+    def values_at(self, idx, dtype=jnp.float32):
+        """:meth:`values` at the flat-layout positions ``idx``, as
+        :meth:`buckets_at`."""
+        return sample_at(self.value_dist, self._seed, self._val_base, idx,
+                         dtype=dtype)
+
     # -- apply --------------------------------------------------------------
 
     def apply(
@@ -192,9 +208,14 @@ class HashSketch(SketchTransform):
         sort-free per-hash ``segment_sum`` — measured 1.2–1.6× the
         relabel+``sum_duplicates`` BCOO build at 1e7–1e8 nnz on v5e, and
         it never materializes the nnz·H relabeled triplets (whose lexsort
-        OOMed SJLT nnz=4 at 1e8 input nonzeros).  Dense inputs ignore the
-        flag (their output is already dense)."""
+        OOMed SJLT nnz=4 at 1e8 input nonzeros); rowwise, a concrete
+        BCOO's rows are laid out in slots and hashed by one-hot products
+        (:meth:`_apply_sparse_dense_out`).  ``core.sparse.RowSlots`` are
+        sketched rowwise, always into a dense result.  Dense inputs
+        ignore the flag (their output is already dense)."""
         dim = Dimension.of(dim)
+        if isinstance(A, RowSlots):
+            return self._apply_sparse_dense_out(A, dim)
         if not isinstance(A, jsparse.BCOO):
             A = jnp.asarray(A)
         if A.ndim == 1:
@@ -532,7 +553,7 @@ class HashSketch(SketchTransform):
         self, ops, A, dim: Dimension | str = Dimension.COLUMNWISE
     ):
         dim = Dimension.of(dim)
-        if ops is None or isinstance(A, jsparse.BCOO):
+        if ops is None or isinstance(A, (jsparse.BCOO, RowSlots)):
             return self.apply(A, dim)
         A = jnp.asarray(A)
         if A.ndim != 2:
@@ -584,11 +605,19 @@ class HashSketch(SketchTransform):
     # the BCOO path (or shard via parallel.collectives).
     _DENSE_OUT_LIMIT = 1 << 28
 
-    def _apply_sparse_dense_out(self, A: jsparse.BCOO, dim: Dimension):
-        """BCOO → dense: one flat ``segment_sum`` per hash function keyed
-        by the hashed destination — no concat, no sort, O(S·batch)
-        resident (the sharded P6 schedules in ``parallel/collectives.py``
-        use the same kernel per shard)."""
+    def _apply_sparse_dense_out(self, A, dim: Dimension):
+        """BCOO → dense.  Rowwise, the rows' slots (:class:`RowSlots`, as
+        ``core.sparse.panels`` makes them, or laid out here from a
+        concrete BCOO) are hashed by :meth:`_rows_dense_out`.  Columnwise,
+        and rowwise under a trace (where no layout can be read from the
+        triplets), one flat ``segment_sum`` per hash function keyed by
+        the hashed destination — no concat, no sort, O(S·batch) resident
+        (the sharded P6 schedules in ``parallel/collectives.py`` use the
+        same kernel per shard)."""
+        if isinstance(A, RowSlots):
+            if dim is not Dimension.ROWWISE:
+                raise ValueError("row slots are sketched rowwise")
+            return self._rows_dense_out(A)
         axis = 0 if dim is Dimension.COLUMNWISE else 1
         if A.shape[axis] != self.n:
             raise ValueError(
@@ -602,6 +631,8 @@ class HashSketch(SketchTransform):
                 f"elements, got {self.s}*{batch}; use the BCOO path or a "
                 "sharded schedule (parallel.collectives)"
             )
+        if axis == 1 and not isinstance(A.data, jax.core.Tracer):
+            return self._rows_dense_out(row_slots(A))
         dtype = (
             A.data.dtype
             if jnp.issubdtype(A.data.dtype, jnp.floating)
@@ -623,6 +654,79 @@ class HashSketch(SketchTransform):
             )
         shape = (self.s, batch) if axis == 0 else (batch, self.s)
         return out.reshape(shape)
+
+    def _rows_dense_out(self, X: RowSlots):
+        """Rowwise dense output of rows in slots: the (m, S) bins of the
+        in-place slots, plus the bins of the overflow rows added at their
+        rows (a scatter of E rows, in order).  Accumulated in f32 (or the
+        data's wider dtype) and returned in the data's dtype, as a dense
+        apply returns its product."""
+        m, n = X.shape
+        if n != self.n:
+            raise ValueError(f"rowwise apply needs A with {self.n} columns, got {X.shape}")
+        if self.s * m > self._DENSE_OUT_LIMIT:
+            raise ValueError(
+                f"dense_output needs S*batch <= {self._DENSE_OUT_LIMIT} "
+                f"elements, got {self.s}*{m}")
+        dtype = X.dtype if jnp.issubdtype(X.dtype, jnp.floating) else jnp.float32
+        out = self._slot_bins(X.data, X.cols, dtype)
+        if X.xdata.shape[0]:
+            extra = self._slot_bins(X.xdata, X.xcols, dtype)
+            out = out.at[X.xrows].add(extra, indices_are_sorted=True)
+        return out.astype(dtype)
+
+    def _slot_bins(self, data, cols, dtype):
+        """The (r, S) bins of r lines of K slots (``data``, ``cols``).
+        Every slot's bucket and value come from its column's counters
+        (:meth:`buckets_at`, :meth:`values_at`), never from an N-long
+        array; a padding slot holds the value 0 and adds nothing wherever
+        it lands.
+
+        The bins are products, not a scatter: a bucket b is (b // L, b %
+        L) with L = 128 lanes (S itself where 128 does not divide it), and
+        a line's (S/L, L) bins are the product of its slots' one-hot high
+        parts, times their values, with their one-hot low parts, so a
+        slot costs S/L + L one-hot entries and no S-wide compare.
+        ``_ROWS_TOGETHER`` lines share one product, block-diagonal in its
+        lines.  A v5e made a 45,208-row, 128-slot panel's S = 4096 bins
+        so in 5.3 ms, hashing included; XLA's scatter-add (a sort of the
+        slots, then a sorted scatter) took 57 ms."""
+        # bf16 values ride the MXU exactly; others keep their precision
+        op = dtype if dtype == jnp.bfloat16 else jnp.promote_types(dtype, jnp.float32)
+        acc = jnp.promote_types(dtype, jnp.float32)
+        lanes = 128 if self.s % 128 == 0 else self.s
+        high = self.s // lanes
+        tr = self._ROWS_TOGETHER
+        r, k = cols.shape
+        pad = -r % tr
+        cols = jnp.pad(cols, ((0, pad), (0, 0)))
+        data = jnp.pad(data, ((0, pad), (0, 0)))
+        # slot j of a block belongs to its line j // k; output row i of a
+        # block is (line i // high, high part i % high)
+        slot_row = jnp.arange(tr * k, dtype=jnp.int32) // k
+        out_row = jnp.arange(tr * high, dtype=jnp.int32)
+        out = None
+        for h in range(self.nnz):
+            at = cols + jnp.int32(h * self.n) if h else cols
+            b = self.buckets_at(at).reshape(-1, tr * k)
+            v = (self.values_at(at, acc) * data.astype(acc)).astype(op)
+            lhs = jnp.where(
+                (out_row[None, :, None] // high == slot_row[None, None, :])
+                & (out_row[None, :, None] % high == (b // lanes)[:, None, :]),
+                v.reshape(-1, 1, tr * k), jnp.zeros((), op))
+            rhs = (b % lanes)[..., None] == jnp.arange(lanes, dtype=jnp.int32)
+            part = jax.lax.dot_general(
+                lhs, rhs.astype(op), (((2,), (1,)), ((0,), (0,))),
+                precision=None if op == jnp.bfloat16 else jax.lax.Precision.HIGHEST,
+                preferred_element_type=acc)
+            out = part if out is None else out + part
+        return out.reshape(r + pad, self.s)[:r]
+
+    # Lines whose bins one product of :meth:`_slot_bins` makes together:
+    # a v5e made the url cell's 53 panels of 45,210 rows x 128 slots into
+    # S = 4096 bins, two levels each with the rest of a ``zr`` call, in
+    # 1.97 s two lines a product and 2.05 s four.
+    _ROWS_TOGETHER = 2
 
     def _apply_sparse(self, A: jsparse.BCOO, dim: Dimension):
         """BCOO → BCOO: relabel hashed indices per hash function, scale
@@ -680,6 +784,10 @@ class SJLT(HashSketch):
         v = super().values(dtype, start, num)
         return v / jnp.sqrt(jnp.asarray(float(self.nnz), dtype))
 
+    def values_at(self, idx, dtype=jnp.float32):
+        v = super().values_at(idx, dtype)
+        return v / jnp.sqrt(jnp.asarray(float(self.nnz), dtype))
+
     def _sign_scale(self):
         return 1.0 / float(np.sqrt(self.nnz))
 
@@ -727,6 +835,11 @@ class WZT(HashSketch):
             "rademacher", self._seed, self._pm_base + static, num,
             dtype=dtype, offset=offset,
         )
+        return pm * (1.0 / e) ** jnp.asarray(1.0 / self.p, dtype)
+
+    def values_at(self, idx, dtype=jnp.float32):
+        e = sample_at("exponential", self._seed, self._val_base, idx, dtype=dtype)
+        pm = sample_at("rademacher", self._seed, self._pm_base, idx, dtype=dtype)
         return pm * (1.0 / e) ** jnp.asarray(1.0 / self.p, dtype)
 
     def _param_dict(self):

@@ -43,9 +43,11 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import sparse as jsparse
 
 from ..core.context import SketchContext
 from ..core.random import sample
+from ..core.sparse import RowSlots, row_slots
 from .base import Dimension, SketchTransform, register_sketch
 from .hash import CWT
 
@@ -132,7 +134,7 @@ class PPT(SketchTransform):
         is gone; above, the hash and the transform stay apart."""
         return self.n * self.s <= CWT._ONEHOT_LIMIT
 
-    def hoistable_operands(self, dtype):
+    def hoistable_operands(self, dtype, *, sparse: bool = False):
         """What every apply realizes that does not depend on the input:
         each level's CountSketch operands (``HashSketch.
         hoistable_operands``; None where the tables fold them in), the
@@ -144,17 +146,22 @@ class PPT(SketchTransform):
         ``_dft_wins`` at the smallest batch that gate admits;
         :meth:`apply_with_operands` re-decides it by the real batch, as
         :meth:`apply` does, and ignores or builds the tables to match
-        (a thin batch's CountSketches then build their own operands)."""
+        (a thin batch's CountSketches then build their own operands).
+        ``sparse``: the operands for a sparse input (a BCOO or
+        ``core.sparse.RowSlots``), which hashes its nonzeros' columns
+        from their counters and takes the plain tables: no CountSketch
+        operands, no folded tables."""
         dt = jnp.dtype(dtype)
         if dt.type not in (jnp.bfloat16, jnp.float32):
             return None
         dft = self._dft_wins(dt, _DFT_MIN_BATCH)
-        fold = dft and self._folds()
+        fold = dft and self._folds() and not sparse
 
         def build():
             with jax.named_scope("ppt.hash"):
                 cwt_ops = tuple(
-                    None if fold else c.hoistable_operands(dt) for c in self._cwts)
+                    None if fold or sparse else c.hoistable_operands(dt)
+                    for c in self._cwts)
                 consts = self._hash_consts(jnp.float32)
             tables = None
             if dft:
@@ -162,10 +169,11 @@ class PPT(SketchTransform):
                 # TPU compiler fuses their cos and sin, or the folded rows'
                 # gather, into every transform's convolution, where they
                 # are made tile by tile.
-                tables = jax.lax.optimization_barrier(self._tables(consts))
+                tables = jax.lax.optimization_barrier(self._tables(consts, fold))
             return cwt_ops, consts, tables
 
-        return self._memoized_operand(f"{dt.name}/{dft}", build)
+        key = f"{dt.name}/{dft}" + ("/sparse" if sparse else "")
+        return self._memoized_operand(key, build)
 
     def apply_with_operands(
         self, ops, A, dim: Dimension | str = Dimension.COLUMNWISE
@@ -175,33 +183,52 @@ class PPT(SketchTransform):
         bits."""
         return self._apply(A, dim, ops)
 
-    def _operands(self, ops, dft: bool):
+    def _operands(self, ops, dft: bool, fold: bool):
         """(CWT operands, (idx, val), tables) from ``ops``, what is
-        missing built here; ``val`` is f32 (±1: exact in every dtype)."""
+        missing built here; ``val`` is f32 (±1: exact in every dtype).
+        ``fold``: whether the DFT route takes the folded tables; operands
+        hoisted for the other kind of input are refused."""
         cwt_ops, consts, tables = ops or ((None,) * self.q, None, None)
         if consts is None:
             with jax.named_scope("ppt.hash"):
                 consts = self._hash_consts(jnp.float32)
         if dft and tables is None:
-            tables = self._tables(consts)
+            tables = self._tables(consts, fold)
+        elif dft and len(tables) != (5 if fold else 3):
+            raise ValueError(
+                "operands hoisted for a " + ("sparse" if fold else "dense") + " input; "
+                f"take hoistable_operands(dtype, sparse={not fold})")
         return cwt_ops, consts, tables
 
-    def _features(self, X, ops=None):
-        """Columnwise features for X (n, m) → (S, m) real."""
+    def _countsketch(self, l, X, cwt_ops, dim):
+        """Level l's CountSketch of X: (S, m) columnwise, (m, S) rowwise,
+        in X's dtype.  A sparse X lands in dense bins, never densified
+        (``HashSketch``'s dense output: rows in slots hash each slot's
+        column from its counters)."""
+        if _is_sparse(X):
+            return self._cwts[l].apply(X, dim, dense_output=True)
+        return self._cwts[l].apply_with_operands(cwt_ops[l], X, dim)
+
+    def _features(self, X, ops=None, rowwise: bool = False):
+        """Columnwise features for X (n, m) → (S, m) real.  ``rowwise``:
+        X is a sparse (m, n), each level's CountSketch made rowwise and
+        transposed."""
         dtype = X.dtype
-        if self._dft_wins(dtype, X.shape[1]):
+        if not rowwise and self._dft_wins(dtype, X.shape[1]):
             return self._features_dft(X, ops=ops)
-        cwt_ops, (idx, val), _ = self._operands(ops, False)
+        cwt_ops, (idx, val), _ = self._operands(ops, False, False)
         sqrt_g = jnp.asarray(np.sqrt(self.gamma), dtype)
         sqrt_c = jnp.asarray(np.sqrt(self.c), dtype)
+        hash_scope = "ppt.scatter" if _is_sparse(X) else "ppt.hash"
+        dim = Dimension.ROWWISE if rowwise else Dimension.COLUMNWISE
         # Seed the frequency-domain product with level 0 (one multiply —
         # and one eager complex-ones allocation — fewer than starting
         # from ones).
         P = None
-        for l, cwt in enumerate(self._cwts):
-            with jax.named_scope("ppt.hash"):
-                W = sqrt_g * cwt.apply_with_operands(
-                    cwt_ops[l], X, Dimension.COLUMNWISE)
+        for l in range(self.q):
+            with jax.named_scope(hash_scope):
+                W = sqrt_g * self._countsketch(l, X, cwt_ops, dim)
+                W = W.T if rowwise else W
                 W = W.at[idx[l], :].add(sqrt_c * val[l].astype(dtype))
             with jax.named_scope("ppt.dft"):
                 F = jnp.fft.fft(W, axis=0)
@@ -246,9 +273,10 @@ class PPT(SketchTransform):
             w = jnp.where(k == 0, 1, 2).astype(jnp.bfloat16)
             return Hc, Hs, jnp.stack([(Hc * w).T, (Hs * w).T])
 
-    def _tables(self, consts):
+    def _tables(self, consts, fold: bool | None = None):
         """The bf16 DFT route's tables: :meth:`_dft_tables`' ``(Hc, Hs,
-        G)``, or where :meth:`_folds` ``(Tc, Ts, Rc, Rs, G)``, each
+        G)``, or where ``fold`` (by default :meth:`_folds`; a sparse input
+        never folds) ``(Tc, Ts, Rc, Rs, G)``, each
         level's CountSketch (buckets b_l, signs v_l) and the constant's
         coordinate (h_l, s_l of ``consts``) taken into its forward
         tables:
@@ -262,7 +290,7 @@ class PPT(SketchTransform):
 
         The rows' gathers and signs are ``ppt.hash``'s."""
         Hc, Hs, G = self._dft_tables()
-        if not self._folds():
+        if not (self._folds() if fold is None else fold):
             return Hc, Hs, G
         idx, val = consts
         with jax.named_scope("ppt.hash"):
@@ -289,9 +317,13 @@ class PPT(SketchTransform):
         keeps the batch on the major axis ((m, S) layout, transform on
         the minor axis) so rowwise applies skip two full-batch
         transposes: each product contracts the tables' leading axes with
-        the n, S (or frequency) axes of either layout."""
-        cwt_ops, (idx, val), tables = self._operands(ops, True)
-        fold = self._folds()
+        the n, S (or frequency) axes of either layout.  A sparse X
+        (:meth:`_apply_sparse`) takes the plain tables: each level's
+        CountSketch lands in dense bins (``ppt.scatter``) and is
+        transformed as a dense input's is."""
+        sparse = _is_sparse(X)
+        fold = self._folds() and not sparse
+        cwt_ops, (idx, val), tables = self._operands(ops, True, fold)
         if fold:
             Tc, Ts, Rc, Rs, G = tables
         else:
@@ -317,9 +349,9 @@ class PPT(SketchTransform):
                     return sqrt_g * mm(X, Tc[l]) + cr, sqrt_g * mm(X, Ts[l]) + ci
             sqrt_g = jnp.asarray(np.sqrt(self.gamma), jnp.bfloat16)
             loc = (slice(None), idx[l]) if rowwise else (idx[l], slice(None))
-            with jax.named_scope("ppt.hash"):
+            with jax.named_scope("ppt.scatter" if sparse else "ppt.hash"):
                 # (m, S) rowwise / (S, m) columnwise
-                W = sqrt_g * self._cwts[l].apply_with_operands(cwt_ops[l], X, dim)
+                W = sqrt_g * self._countsketch(l, X, cwt_ops, dim)
                 Wb = W.astype(jnp.float32).at[loc].add(sqrt_c * val[l]).astype(jnp.bfloat16)
             with jax.named_scope("ppt.dft"):
                 return mm(Wb, Hc), mm(Wb, Hs)
@@ -352,6 +384,8 @@ class PPT(SketchTransform):
 
     def _apply(self, A, dim, ops):
         dim = Dimension.of(dim)
+        if _is_sparse(A):
+            return self._apply_sparse(A, dim, ops)
         A = jnp.asarray(A)
         dtype = A.dtype if jnp.issubdtype(A.dtype, jnp.floating) else jnp.float32
         A = A.astype(dtype)
@@ -370,6 +404,35 @@ class PPT(SketchTransform):
         Z = self._features(X.T, ops)
         return Z.T if not squeeze else Z[:, 0]
 
+    def _apply_sparse(self, A, dim, ops):
+        """Features of a two-dimensional BCOO or of rows in slots
+        (``core.sparse.RowSlots``, rowwise), never densified: on either
+        route each level's CountSketch lands in dense (S, batch) or
+        (batch, S) bins, ``HashSketch``'s dense output under the scope
+        ``ppt.scatter``, and the bins are transformed as a dense input's
+        CountSketch is (the DFT route with its plain tables: the folded
+        ones would be rows gathered by column).  Rowwise, a concrete
+        BCOO's rows are laid out in slots once for all levels.  The route
+        is the dense one's gate, ``_dft_wins`` on the dtype and the
+        batch."""
+        if A.ndim != 2:
+            raise ValueError(f"PPT applies to a two-dimensional BCOO, got {A.shape}")
+        dtype = A.dtype if jnp.issubdtype(A.dtype, jnp.floating) else jnp.float32
+        A = A.astype(dtype)
+        if dim is Dimension.COLUMNWISE:
+            if isinstance(A, RowSlots):
+                raise ValueError("row slots are sketched rowwise")
+            if A.shape[0] != self.n:
+                raise ValueError(f"columnwise apply needs {self.n} rows, got {A.shape}")
+            return self._features(A, ops)
+        if A.shape[1] != self.n:
+            raise ValueError(f"rowwise apply needs {self.n} cols, got {A.shape}")
+        if isinstance(A, jsparse.BCOO) and not isinstance(A.data, jax.core.Tracer):
+            A = row_slots(A)
+        if self._dft_wins(dtype, A.shape[0]):
+            return self._features_dft(A, rowwise=True, ops=ops)
+        return self._features(A, ops, rowwise=True).T
+
     def _param_dict(self):
         return {"q": self.q, "c": self.c, "gamma": self.gamma}
 
@@ -378,3 +441,7 @@ class PPT(SketchTransform):
         return cls(
             d["N"], d["S"], context, q=d["q"], c=d["c"], gamma=d["gamma"]
         )
+
+
+def _is_sparse(X) -> bool:
+    return isinstance(X, (jsparse.BCOO, RowSlots))

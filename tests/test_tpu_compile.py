@@ -615,3 +615,42 @@ def test_streaming_krr_ppt_programs_carry_the_four_scopes(one_chip, as_tpu, prog
     # no hashed (65536, 4096) bf16 panel a level: 2.19-2.24 GB in all,
     # where the hash, then the half spectrum, took 4.36-4.41 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 2.4e9
+
+
+def test_streaming_krr_ppt_sparse_zr_program_at_the_url_cells_size(one_chip, as_tpu):
+    """The ``zr`` chunk program of ``krr_poly2_url_sparse_resident``
+    (45,210-row panels of 128 slots a row and 15,448 overflow rows, d =
+    3,231,961 -> 4096, q = 2, 53 panels) on the chip's route: each
+    level's CountSketch of the sparse panel is two one-hot products under
+    ``ppt.scatter``, the rows' and the overflow rows', and one scatter of
+    the overflow rows' bins at their rows, in order (no sort, no N-long
+    table, no (rows, d) array: the hash comes from the columns'
+    counters); the bins take the plain half-spectrum transforms; the
+    temporaries leave the resident panels (2.5 GB) room."""
+    from libskylark_tpu.core import sparse
+    from libskylark_tpu.ml import PolynomialKernel
+    from libskylark_tpu.ml.krr import streaming_krr_chunk_programs
+
+    D, SZ, NB, BR, SLOTS, E, T = 3_231_961, 4096, 53, 45_210, 128, 15_448, 2
+    M = PolynomialKernel(D, q=2, c=1.0, gamma=1 / 115.6).create_rft(
+        SZ, "regular", SketchContext(seed=20261019))
+    assert M._dft_wins(jnp.dtype(BF16), BR) and not M._folds()
+    _, zr, _ = streaming_krr_chunk_programs([M], 0, NB, BR, sparse.panel_rows, BF16)
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    P = sparse.Panels(shaped((NB, BR, SLOTS), BF16), shaped((NB, BR, SLOTS), I32),
+                      shaped((NB, E, SLOTS), BF16), shaped((NB, E, SLOTS), I32),
+                      shaped((NB, E), I32), (NB * BR, D), 277_058_644)
+    with jax.enable_x64(False):
+        compiled = zr.lower(shaped((), F32), shaped((NB, BR, T), F32),
+                            shaped((SZ, T), F32), P).compile()
+    text = compiled.as_text()
+    scatter_products = re.findall(
+        r' convolution\(.*op_name="[^"]*krr\.features/ppt\.scatter/', text)
+    assert len(scatter_products) == 4  # the rows' and the overflow rows', a level
+    scatters = re.findall(r" scatter\(.*", text)
+    assert len(scatters) == 2 and all("indices_are_sorted=true" in s for s in scatters)
+    assert " sort(" not in text
+    assert not re.search(rf"\[[\d,]*\b{D}\b", text)
+    assert _products(text, "ppt.dft") == [((BR, SZ // 2), SZ)] * 4
+    assert _products(text, "ppt.inverse") == [((BR, SZ), SZ)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
